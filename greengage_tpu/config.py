@@ -22,18 +22,6 @@ class Settings:
     # dense group-by path: used when the product of group-key domains
     # (dictionary sizes / bool) is at most this (scatter-free aggregation)
     dense_group_limit: int = 512
-    # fused single-pass dense aggregation (ops/fused_agg.py pallas kernel):
-    # one HBM pass for every aggregate of a small-domain GROUP BY; falls
-    # back to the XLA per-aggregate path on unsupported shapes or kernel
-    # compile failure (executor disables it for the retry)
-    fused_dense_agg: bool = True
-    fused_dense_min_rows: int = 1 << 16
-    # the kernel unrolls domain x accumulators reductions per grid step and
-    # keeps (accums, domain, 128)-lane scratch in VMEM: bound both so a
-    # wide dense domain never triggers multi-minute Mosaic compiles or
-    # VMEM exhaustion (the XLA path wins there anyway)
-    fused_dense_max_domain: int = 64
-    fused_dense_max_scratch_mb: int = 4
     # motion (gp_interconnect_queue_depth analog)
     motion_capacity_slack: float = 1.6  # per-destination bucket headroom
     motion_retry_tiers: int = 3         # capacity x4 per retry on overflow
@@ -237,7 +225,7 @@ class Settings:
     max_connections: int = 100
     # auth-handshake deadline for remote (TCP) peers: a connect that
     # never completes the challenge-response is closed, so a port-scan
-    # or wedged client cannot pin a handler thread forever (0 = off)
+    # or stalled client cannot pin a handler thread forever (0 = off)
     client_auth_deadline_s: float = 10.0
     # idle-read deadline between statements: a connection silent past
     # this is told idle_timeout and closed (0 = off, the default — BI
@@ -281,14 +269,6 @@ class Settings:
     brownout_exit_s: float = 5.0
     brownout_cache_factor: float = 0.5
     brownout_vmem_factor: float = 0.5
-    # persistent XLA compilation cache directory, applied at Database init
-    # (the warm-cache requirement in docs/PERF.md — a cold cache
-    # recompiles every query shape once per process). Empty = leave the
-    # process default; the GGTPU_XLA_CACHE env var overrides when set.
-    xla_cache_dir: str = "~/.cache/ggtpu_xla"
-    # jax's persistent cache never evicts (0.4.x), so init prunes the
-    # active platform subdir oldest-first past this bound; 0 = unbounded
-    xla_cache_limit_mb: int = 2048
     # plan-invariant validation (analysis/plancheck.py; the cdbmutate
     # checkPlan-before-dispatch analog): walk every planned statement and
     # raise a typed PlanInvariantError on Motion-placement / locality /

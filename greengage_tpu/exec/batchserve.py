@@ -60,9 +60,9 @@ from greengage_tpu.runtime.trace import TRACES, Trace
 # batch widths are small pow2s, not latencies: explicit buckets
 WIDTH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 
-# hard ceiling on a member's wait for its flush — a wedged pipeline must
+# hard ceiling on a member's wait for its flush — a stalled pipeline must
 # degrade to serial execution, never to a hung client connection
-_WEDGE_TIMEOUT_S = 600.0
+_STALL_TIMEOUT_S = 600.0
 
 
 class _Member:
@@ -204,7 +204,7 @@ class BatchServer:
             # statement context so `gg cancel` / timeouts / disconnects
             # take a queued member out immediately — its batch-mates are
             # untouched (the dispatcher masks it at demux)
-            hard = time.monotonic() + _WEDGE_TIMEOUT_S
+            hard = time.monotonic() + _STALL_TIMEOUT_S
             while not m.event.wait(0.02):
                 if ctx is not None:
                     ctx.check()
@@ -217,7 +217,7 @@ class BatchServer:
                 if time.monotonic() > hard:
                     if self._abandon(wkey, b, m):
                         return None   # window never flushed: run classic
-                    # flushed but the pipeline is wedged mid-batch —
+                    # flushed but the pipeline is stalled mid-batch —
                     # degrade to serial rather than hang the connection
                     return None
         finally:
@@ -265,7 +265,7 @@ class BatchServer:
         aborts the process from the C++ side), then release every member
         still parked in a window or staged batch — each degrades to the
         classic serial path on its own thread instead of waiting out the
-        wedge timeout against a dead pipeline."""
+        stall timeout against a dead pipeline."""
         self._stop.set()
         with self._cv:
             self._cv.notify_all()

@@ -205,7 +205,7 @@ class Executor:
         # version, evicted by recency against scan_cache_limit_mb
         self._stage_cache = store.blockcache.cache("stage")
         # compiled-program cache (the gang-reuse analog), REAL LRU:
-        # (statement signature, shape signature, fused_disabled) ->
+        # (statement signature, shape signature, batch width bucket) ->
         # CompileResult. The shape signature (Compiler.shape_signature)
         # captures everything the trace reads — bucketed capacities,
         # dictionary fingerprints, consts digest, param dtypes — so a
@@ -214,7 +214,7 @@ class Executor:
         # of recompiling. Bounded by the plan_cache_size GUC.
         #
         # _cache_mu guards ALL program-cache bookkeeping (_plan_cache,
-        # _cap_hints, _sig_memo, _fused_failed, _dyn_prune_cache): the
+        # _cap_hints, _sig_memo, _dyn_prune_cache): the
         # batch-serving stager mutates these concurrently with statement
         # threads (gg check races), and the old GIL-reliant try/KeyError
         # defenses only made lost updates quiet, not absent. RLock:
@@ -224,11 +224,6 @@ class Executor:
                                          "executor._cache_mu")
         self._plan_cache: OrderedDict = lockdebug.shared(
             OrderedDict(), "executor._plan_cache")
-        # statements whose fused pallas kernel failed to lower on this
-        # backend: later runs skip the pallas attempt entirely instead of
-        # paying a failed compile + XLA recompile every execution
-        self._fused_failed: set = set()
-        self.last_fused_error: str | None = None
         # runtime cardinality feedback (VERDICT r3 weak #3): the exact
         # counts the device reports for overflow-capable nodes (join
         # expansion totals, agg group counts, gather live rows) persist
@@ -332,8 +327,6 @@ class Executor:
             hints = dict(self._cap_hints.get(cache_key) or {})
             if hints:
                 self._cap_hints.move_to_end(cache_key)
-            fused_disabled = cache_key is not None \
-                and cache_key in self._fused_failed
         if not hints and cache_key is not None and self.multihost is None \
                 and self.feedback is not None:
             # persisted cap hints (feedback store): a restarted process
@@ -348,15 +341,14 @@ class Executor:
                 plan, consts, out_cols, cache_key, raw, instrument,
                 scan_cap_override, row_ranges, aux_tables, allow_spill,
                 deferred, no_direct, t0, snapshot, version,
-                hints, cap_overrides, pack_disabled, fused_disabled)
+                hints, cap_overrides, pack_disabled)
         finally:
             TRACKER.release()
 
     def _run_tiers(self, plan, consts, out_cols, cache_key, raw, instrument,
                    scan_cap_override, row_ranges, aux_tables, allow_spill,
                    deferred, no_direct, t0, snapshot, version,
-                   hints, cap_overrides, pack_disabled,
-                   fused_disabled) -> Result:
+                   hints, cap_overrides, pack_disabled) -> Result:
         last_err = None
         tier = 0
         attempts = 0
@@ -373,12 +365,9 @@ class Executor:
             # set while the previous attempt ran (user cancel, statement
             # timeout, runaway cleaner) terminates the statement here
             interrupt.check_interrupts()
-            # fused_disabled programs cache under their own key: a backend
-            # that can't lower the pallas kernel still gets gang reuse of
-            # the working XLA fallback program (advisor r3). Feedback
-            # hints are deterministic inputs folded into the shape
-            # signature (they size capacities); only RUNTIME overrides (an
-            # overflow retry in flight) disable caching.
+            # Feedback hints are deterministic inputs folded into the
+            # shape signature (they size capacities); only RUNTIME
+            # overrides (an overflow retry in flight) disable caching.
             ck = None
             sig_comp = None
             if cache_key is not None and cap_overrides == hints \
@@ -391,8 +380,7 @@ class Executor:
                 # session cache), so steady-state program-cache hits skip
                 # the whole-plan signature walk
                 mk = (cache_key, version, tier,
-                      tuple(sorted(cap_overrides.items())),
-                      fused_disabled, no_direct,
+                      tuple(sorted(cap_overrides.items())), no_direct,
                       Compiler.codegen_settings_sig(self.settings))
                 try:
                     sig, sig_comp = self._memo_signature(
@@ -402,7 +390,6 @@ class Executor:
                                          self.settings, tier=tier,
                                          cap_overrides=cap_overrides,
                                          multihost=self.multihost is not None,
-                                         fused_disabled=fused_disabled,
                                          no_direct=no_direct),
                         plan, snapshot)
                 except Exception:
@@ -415,7 +402,7 @@ class Executor:
                 if sig is not None:
                     # trailing 0 = the unbatched program; batched serving
                     # keys its width buckets in the same LRU (run_batch)
-                    ck = (cache_key, sig, fused_disabled, 0)
+                    ck = (cache_key, sig, 0)
             # fetch + recency bump in one _cache_mu section: a concurrent
             # statement's eviction can no longer interleave (the value
             # object stays alive once fetched either way)
@@ -446,15 +433,14 @@ class Executor:
                                         scan_cap_override=scan_cap_override,
                                         aux_tables=aux_tables,
                                         pack_disabled=pack_disabled,
-                                        fused_disabled=fused_disabled,
                                         no_direct=no_direct).compile(plan)
                 compile_ms = (time.monotonic() - t_comp) * 1e3
                 if ck is not None:
                     # keep the compiled SPMD program for repeated dispatch
                     # of the same statement shape; LRU-bounded (each entry
-                    # pins an XLA executable), with cap-hint / fused-failed
-                    # bookkeeping evicted alongside the last program of a
-                    # statement (unbounded-growth fix, ISSUE 5)
+                    # pins an XLA executable), with cap-hint bookkeeping
+                    # evicted alongside the last program of a statement
+                    # (unbounded-growth fix, ISSUE 5)
                     self._cache_program(ck, comp)
             limit = effective_limit_bytes(self.settings)
             if self.multihost is None:
@@ -586,45 +572,22 @@ class Executor:
                             "(fault injected: device_oom)")
                     flat = (comp.aot_fn or comp.device_fn)(*inputs)
                     # resolve async dispatch here so compute_ms is the
-                    # device program (and a deferred pallas failure still
-                    # lands in the retry logic below, not in device_get)
+                    # device program and a device failure surfaces at the
+                    # dispatch, not in device_get
                     jax.block_until_ready(flat)
             except Exception as e:
-                # a pallas lowering/compile failure on this backend must
-                # not fail the query: retry the SAME tier on the pure-XLA
-                # path and drop the poisoned cached program. Only programs
-                # that actually embed the fused kernel AND errors that
-                # carry pallas/Mosaic markers qualify — anything else
-                # (OOM, interconnect) is a genuine runtime error, and a
-                # transient one must not poison the fused memo.
-                if fused_disabled or not comp.uses_fused \
-                        or not self.settings.fused_dense_agg \
-                        or not _is_pallas_error(e):
-                    if memaccount.is_oom_error(e):
-                        # OOM forensics + demotion (memaccounting.c's
-                        # RESOURCE_EXHAUSTED dump): never a bare XLA
-                        # traceback for an allocator refusal
-                        return self._handle_oom(
-                            e, comp, plan, consts, out_cols, raw,
-                            instrument, allow_spill, deferred, tier)
-                    raise
-                fused_disabled = True
-                self.last_fused_error = f"{type(e).__name__}: {e}"
-                with self._cache_mu:
-                    if cache_key is not None:
-                        self._fused_failed.add(cache_key)
-                    if ck is not None:
-                        # plain pop, NOT _on_program_evicted: that would
-                        # discard the fused-failed memo just recorded; the
-                        # retry below immediately caches the unfused
-                        # program for this statement, re-tying the
-                        # bookkeeping to a live entry
-                        self._plan_cache.pop(ck, None)
-                continue
+                if memaccount.is_oom_error(e):
+                    # OOM forensics + demotion (memaccounting.c's
+                    # RESOURCE_EXHAUSTED dump): never a bare XLA
+                    # traceback for an allocator refusal
+                    return self._handle_oom(
+                        e, comp, plan, consts, out_cols, raw,
+                        instrument, allow_spill, deferred, tier)
+                raise
             t_fetch = time.monotonic()
             compute_ms = (t_fetch - t_compute) * 1e3
-            # ONE device->host fetch for every output (per-transfer latency
-            # through tunneled/remote device paths dwarfs per-byte cost)
+            # ONE device->host fetch for every output (small results pay
+            # per-transfer latency, not per-byte cost)
             with _trace.span("fetch", cat="device") as _sp_f:
                 flat = jax.device_get(list(flat))
             fetch_ms = (time.monotonic() - t_fetch) * 1e3
@@ -644,12 +607,16 @@ class Executor:
             overflow = [k for k, v in flags.items()
                         if not k.startswith("join_dup") and v.any()]
             if not overflow:
-                # cardinality feedback: persist the EXACT counts the
-                # device reported so the next compile of this statement
-                # (post-DML replan) sizes capacities right immediately;
-                # metrics are device-reduced, so multihost processes
+                # cardinality feedback: when this statement paid an
+                # overflow retry, persist the EXACT counts the device
+                # reported so its next compile (post-DML replan, restart)
+                # sizes capacities right immediately. A statement whose
+                # estimates sufficed records nothing: a hint would only
+                # re-size the program just cached and make the very next
+                # run compile again (minutes for a join on the TPU).
+                # Metrics are device-reduced, so multihost processes
                 # record identical hints and stay in lockstep
-                if cache_key is not None and comp.flag_caps:
+                if cache_key is not None and comp.flag_caps and attempts > 1:
                     with self._cache_mu:
                         rec = self._cap_hints.setdefault(cache_key, {})
                         self._cap_hints.move_to_end(cache_key)
@@ -692,10 +659,6 @@ class Executor:
                     "compute_ms": round(compute_ms, 2),
                     "fetch_ms": round(fetch_ms, 2),
                     "scan_io": scan_io,
-                    # True when the program embeds the fused pallas kernel
-                    # (bench reports this: a silent XLA fallback must not
-                    # masquerade as a pallas measurement)
-                    "fused_kernel": bool(comp.uses_fused),
                     "segments": self.nseg,
                     # FTS/topology version the dispatch was bound against
                     # (bumped by mesh re-formation and mirror promotion;
@@ -824,7 +787,7 @@ class Executor:
 
     def _cache_program(self, ck, comp) -> None:
         """Insert a compiled program into the bounded LRU; evictions
-        drop their statement's cap-hint / fused-failed bookkeeping via
+        drop their statement's cap-hint bookkeeping via
         _on_program_evicted (one policy for every caller)."""
         with self._cache_mu:
             self._plan_cache[ck] = comp
@@ -854,11 +817,8 @@ class Executor:
         version = snapshot.get("version", 0)
         with self._cache_mu:
             hints = dict(self._cap_hints.get(cache_key) or {})
-        # batched programs always disable the fused pallas kernel: the
-        # dense-agg kernel has no vmap batching rule, and a mid-batch
-        # lowering failure would cost every member a serial re-run
         mk = (cache_key, version, 0, tuple(sorted(hints.items())),
-              True, False, Compiler.codegen_settings_sig(self.settings),
+              False, Compiler.codegen_settings_sig(self.settings),
               "batch")
         try:
             sig, sig_comp = self._memo_signature(
@@ -866,13 +826,12 @@ class Executor:
                 lambda: Compiler(self.catalog, self.store, self.mesh,
                                  self.nseg, consts, self.settings,
                                  tier=0, cap_overrides=dict(hints),
-                                 fused_disabled=True,
                                  batch_width=bucket),
                 plan, snapshot)
         except Exception:
             counters.inc("program_cache_unsignable")
             raise BatchFallback("unsignable statement shape")
-        ck = (cache_key, sig, True, bucket)
+        ck = (cache_key, sig, bucket)
         with self._cache_mu:
             comp = self._plan_cache.get(ck)
             was_cached = comp is not None
@@ -889,7 +848,6 @@ class Executor:
                     sig_comp = Compiler(self.catalog, self.store, self.mesh,
                                         self.nseg, consts, self.settings,
                                         tier=0, cap_overrides=dict(hints),
-                                        fused_disabled=True,
                                         batch_width=bucket)
                 comp = sig_comp.compile(plan)
             counters.inc("compile_ms",
@@ -1030,10 +988,9 @@ class Executor:
             try:
                 comp.aot_fn = comp.device_fn.lower(*inputs).compile()
             except Exception:
-                # a shape/backend the AOT path can't lower (incl. pallas
-                # compile failures): latch off and fall back to the jit
-                # path, which re-raises real errors into the dispatch
-                # retry logic
+                # a shape/backend the AOT path can't lower: latch off and
+                # fall back to the jit path, which re-raises real errors
+                # at the dispatch
                 comp.mem_failed = True
                 return
             try:
@@ -1216,17 +1173,16 @@ class Executor:
 
     def _on_program_evicted(self, key) -> None:
         """A compiled program left the LRU: when it was the LAST program
-        of its statement, drop the statement's cap-hint and fused-failed
-        bookkeeping too — their lifetime is tied to the plan cache
-        (unbounded-growth fix, ISSUE 5)."""
+        of its statement, drop the statement's cap-hint bookkeeping too —
+        its lifetime is tied to the plan cache (unbounded-growth fix,
+        ISSUE 5)."""
         cache_key = key[0]
-        # callers hold _cache_mu (RLock): the membership scan, the
-        # cap-hint drop, and the fused-failed drop are one atomic step
+        # callers hold _cache_mu (RLock): the membership scan and the
+        # cap-hint drop are one atomic step
         with self._cache_mu:
             if any(k[0] == cache_key for k in list(self._plan_cache)):
                 return
             self._cap_hints.pop(cache_key, None)
-            self._fused_failed.discard(cache_key)
 
     def invalidate_table(self, table: str) -> None:
         """Drop compiled programs scanning ``table`` (DROP TABLE / DROP
@@ -1776,16 +1732,6 @@ class Executor:
             valids=out_valids,
             _order=[c.id for c in visible],
         )
-
-
-def _is_pallas_error(e: Exception) -> bool:
-    """Does this exception look like a pallas/Mosaic lowering or compile
-    failure (vs a genuine runtime error like OOM or a dead interconnect)?
-    Mosaic failures surface as XlaRuntimeError/JaxRuntimeError whose text
-    names Mosaic or the TPU custom call; pallas tracing failures name
-    pallas itself."""
-    s = f"{type(e).__name__}: {e}".lower()
-    return any(m in s for m in ("pallas", "mosaic", "tpu_custom_call"))
 
 
 def _pad(arr: np.ndarray, cap: int, fill=0) -> np.ndarray:

@@ -122,7 +122,7 @@ class BucketPipeline:
     def close(self) -> None:
         """Stop + join the stager, bounded; polls the statement's
         cancellation like PassPrefetcher.close so a dying statement never
-        sits out a wedged stage callable."""
+        sits out a hung stage callable."""
         with self._mu:
             self._stop = True
             self._mu.notify_all()

@@ -150,11 +150,6 @@ class Database:
         # and the block-cache registry reads scan_cache_limit_mb live
         self.store.settings = self.settings
         self.store.blockcache.settings = self.settings
-        # persistent XLA compilation cache (docs/PERF.md warm-cache note):
-        # wired HERE from the xla_cache_dir GUC instead of relying on the
-        # ambient environment; an explicit GGTPU_XLA_CACHE env (incl. "0"
-        # = off) still wins for operators who set it
-        self._apply_xla_cache_dir()
         # bound-plan LRU (plancache.c analog): (statement signature,
         # manifest version) -> (planned, consts, outs, exec_key, param
         # types). Literal-parameterized keys via sql/paramize.py; bounded
@@ -298,58 +293,6 @@ class Database:
             return 0.9
         # 2 buckets -> 0.75, 4 -> 0.625, >=8 -> floors at 0.5625
         return max(0.5, 0.5 + 0.5 / min(nb, 8))
-
-    def _apply_xla_cache_dir(self) -> None:
-        """Arm jax's persistent compilation cache from the xla_cache_dir
-        GUC (per-platform subdirs keep TPU/CPU AOT entries apart — mixed
-        entries trip feature-mismatch loads). No-op when the operator set
-        GGTPU_XLA_CACHE explicitly (the import-time default honored it)."""
-        if os.environ.get("GGTPU_XLA_CACHE") is not None:
-            return
-        path = (getattr(self.settings, "xla_cache_dir", "") or "").strip()
-        if not path:
-            return
-        plat = (os.environ.get("GGTPU_PLATFORM")
-                or os.environ.get("JAX_PLATFORMS") or "default")
-        full = os.path.join(os.path.expanduser(path), plat)
-        try:
-            import jax
-
-            if getattr(jax.config, "jax_compilation_cache_dir", None) != full:
-                jax.config.update("jax_compilation_cache_dir", full)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.2)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception as e:
-            self.settings_warnings.append(f"xla_cache_dir not applied: {e}")
-            return
-        self._prune_xla_cache(full)
-
-    def _prune_xla_cache(self, full: str) -> None:
-        """jax's persistent cache (0.4.x) never evicts, and with
-        min_entry_size=0 nearly every program persists — without a bound a
-        long-lived workstation grows ~/.cache/ggtpu_xla indefinitely.
-        Evict oldest-used first until under xla_cache_limit_mb."""
-        limit_mb = int(getattr(self.settings, "xla_cache_limit_mb", 2048))
-        if limit_mb <= 0:
-            return
-        try:
-            with os.scandir(full) as it:
-                ents = [(e.path, e.stat()) for e in it if e.is_file()]
-        except OSError:
-            return
-        total = sum(st.st_size for _p, st in ents)
-        if total <= limit_mb * (1 << 20):
-            return
-        ents.sort(key=lambda ps: max(ps[1].st_atime, ps[1].st_mtime))
-        for p, st in ents:
-            try:
-                os.unlink(p)
-            except OSError:
-                continue
-            total -= st.st_size
-            if total <= limit_mb * (1 << 20):
-                break
 
     @property
     def dtm(self):
@@ -994,7 +937,7 @@ class Database:
                            "the cluster is degraded")
         child = (
             "import os, sys, json\n"
-            "os.environ['GGTPU_PLATFORM'] = 'cpu'\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "flags = [f for f in os.environ.get('XLA_FLAGS', '').split()\n"
             "         if 'host_platform_device_count' not in f]\n"
             "flags.append('--xla_force_host_platform_device_count=%d')\n"
@@ -2621,8 +2564,6 @@ class Database:
             mline = self._memory_line(s.get("mem"))
             if mline:
                 text += "\n " + mline
-            if s.get("fused_kernel"):
-                text += "\n Fused dense-agg pallas kernel: yes"
             for t, (kept, total) in (s.get("zone_prune") or {}).items():
                 text += f"\n Zone-map prune {t}: {kept}/{total} blocks"
             for t, (kept, total) in (s.get("dynamic_prune") or {}).items():

@@ -417,9 +417,7 @@ def cmd_checkperf(args):
         shard = jax.device_put(
             jnp.ones((n, (mb << 18) // n), jnp.float32),
             NamedSharding(mesh, PartitionSpec("seg", None)))
-        from jax.experimental.shard_map import shard_map
-
-        f2 = jax.jit(shard_map(
+        f2 = jax.jit(jax.shard_map(
             lambda v: jax.lax.psum(v, "seg"), mesh=mesh,
             in_specs=PartitionSpec("seg", None),
             out_specs=PartitionSpec("seg", None)))
@@ -458,8 +456,9 @@ def cmd_checkperf(args):
 def _measure_device_primitives(n: int = 1 << 22) -> dict:
     """Measure the planner cost model's primitives (planner/cost.py
     CALIBRATION_DEFAULTS) on the live backend: random gather, scatter-add,
-    two-operand sort, HBM streaming, and the device->host relay. The ICI
-    constant needs >1 device; on a single chip it keeps its default."""
+    two-operand sort, HBM streaming, the device->host fetch, and — with
+    more than one device — an all_to_all over every device (on a single
+    chip the ICI constant keeps its default)."""
 
     import numpy as np
 
@@ -493,7 +492,7 @@ def _measure_device_primitives(n: int = 1 << 22) -> dict:
         lambda k, v: lax.sort((k, v), num_keys=1), key, val) * 1e9 / n / 2
     # one read + one write pass of 8B rows
     cal["ns_stream_byte"] = best_s(lambda k: k * 2, key) * 1e9 / (n * 16)
-    # device->host relay: fixed call floor from a tiny transfer, per-byte
+    # device->host fetch: fixed call floor from a tiny transfer, per-byte
     # from a big one
     small = jnp.ones((8,), jnp.int64)
     t0 = time.monotonic()
@@ -505,6 +504,24 @@ def _measure_device_primitives(n: int = 1 << 22) -> dict:
     big_s = time.monotonic() - t0
     per_byte = (big_s * 1e9 - cal["ns_host_call"]) / (n * 8)
     cal["ns_host_byte"] = max(per_byte, 1e-4)
+    devs = jax.devices()
+    if len(devs) > 1:
+        # redistribute shape: every device sends 1/ndev of its n/ndev
+        # int64 rows to each peer; priced per byte a device sends
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from greengage_tpu.parallel import make_mesh
+
+        nd = len(devs)
+        mesh = make_mesh(nd, devs)
+        rows = n // (nd * nd) * nd
+        x = jax.device_put(
+            jnp.ones((nd * rows,), jnp.int64), NamedSharding(mesh, P("seg")))
+        a2a = jax.shard_map(
+            lambda v: lax.all_to_all(v.reshape(nd, -1), "seg", 0, 0,
+                                     tiled=True).reshape(-1),
+            mesh=mesh, in_specs=P("seg"), out_specs=P("seg"))
+        cal["ns_ici_byte"] = best_s(a2a, x) * 1e9 / (rows * 8 * (nd - 1) / nd)
     return cal
 
 

@@ -6,7 +6,7 @@ IDENTITY).
 
 TPU-first translation: the REDUCE stage is where the data is big and it
 compiles to a distributed GROUP BY through the ordinary planner (dense /
-sort / fused-pallas aggregation, spill, multihost — everything applies).
+sort aggregation, spill, multihost — everything applies).
 MAP functions are arbitrary Python by spec, so they run on the host over
 the source's columns (the reference likewise runs mappers in per-segment
 interpreters, not in the scan kernel); mapped rows bulk-load into an

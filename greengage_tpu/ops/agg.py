@@ -145,9 +145,9 @@ def _run_aggs(aggs: list[AggSpec], sel, seg_sum, seg_minmax):
                 out_vals[spec.name] = avg
                 out_valid[spec.name] = cnt > 0
         elif spec.func in ("min", "max"):
-            # the identity must stay HOST-concrete (numpy, not jnp): under a
-            # jit trace jnp.array() yields a tracer, and the fused kernel
-            # needs ident.item() for pad/scratch-init constants
+            # the identity stays HOST-concrete (numpy, not jnp): under a
+            # jit trace jnp.array() yields a tracer, and reducers fill
+            # padding with it as a constant
             if vals.dtype.kind == "f":
                 ident = np.array(np.inf if spec.func == "min" else -np.inf,
                                  vals.dtype)

@@ -110,7 +110,7 @@ def local_segment_positions() -> tuple:
 
 class WorkerDied(ConnectionError):
     """A worker's control connection is gone OR silent past its deadline
-    (process death / network partition / wedged process): the statement
+    (process death / network partition / hung process): the statement
     channel cannot reach the full gang. ``process_id`` carries the peer
     the failure was observed on (None when unattributable) so mesh
     re-formation can name the lost worker."""

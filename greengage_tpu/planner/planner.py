@@ -458,9 +458,14 @@ class Planner:
             if self.force_multi_join or not self._build_unique(
                     node.right, node.right_keys):
                 node.multi = True
-                # duplicate fanout multiplies output rows; nudge the
-                # estimate so operators above size their tables for it
-                node.est_rows = max(node.est_rows, left.est_rows * 2.0)
+                if est is None:
+                    # no stats: duplicate fanout multiplies output rows;
+                    # nudge the max() guess so operators above size their
+                    # tables for it. The stats estimate (|L||R| x key
+                    # selectivity) already counts duplicate matches, and
+                    # doubling it at every join of a chain compounds into
+                    # capacities 10^4 x the rows that arrive.
+                    node.est_rows = max(node.est_rows, left.est_rows * 2.0)
         elif node.kind in ("semi", "anti") and node.residual is not None:
             # residual EXISTS correlation must test EVERY duplicate build
             # row (any-match): route through the CSR expansion. Stash the

@@ -13,9 +13,8 @@ centralizes the three primitives they all share:
   * ``RetryPolicy`` — retry-a-callable with retryable-error classification,
                       bounded by attempts and/or a deadline.
 
-This module is intentionally stdlib-only: ``bench.py`` loads it by file
-path from outside the package (the bench parent must never import jax),
-and the control channel uses it before any device runtime exists.
+This module is intentionally stdlib-only: the control channel uses it
+before any device runtime exists.
 """
 
 from __future__ import annotations
@@ -29,8 +28,8 @@ import time
 def _check_interrupts() -> None:
     """Interrupt poll that keeps this module stdlib-only: when the engine
     is loaded, retry sleeps are statement cancellation points (PR-4
-    discipline); when bench.py file-loads this module standalone, the
-    registry module is absent and this is a no-op."""
+    discipline); where the registry module is not loaded this is a
+    no-op."""
     mod = sys.modules.get("greengage_tpu.runtime.interrupt")
     if mod is not None:
         mod.check_interrupts()

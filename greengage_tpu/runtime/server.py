@@ -40,7 +40,7 @@ grow it:
     ``overload_accept`` fault point forces the shed path in tests.
   * ``client_auth_deadline_s`` bounds the TCP auth handshake and
     ``client_idle_timeout_s`` (optional) bounds idle reads between
-    statements, so a wedged peer cannot pin a handler forever.
+    statements, so a stalled peer cannot pin a handler forever.
   * ``max_frame_bytes`` bounds one request frame; an oversized line gets
     a typed ``frame_too_large`` error and the connection closes (the
     stream cannot be resynced past a partially-read line), so a

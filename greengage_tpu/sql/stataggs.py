@@ -7,7 +7,7 @@ reference ships as transition-function triples over float8 state arrays
 float8_regr_accum). The TPU-first translation is different in kind: each
 statistic is EXPANDED before binding into arithmetic over the engine's
 existing sum()/count() aggregates, so the two-phase partial/final
-machinery, the dense/sort/fused-pallas paths, spill, and multihost
+machinery, the dense/sort paths, spill, and multihost
 lockstep all apply with zero new executor state. The moment algebra (the
 same one float8_accum uses internally):
 

@@ -596,7 +596,7 @@ class Manifest:
         parked > GC_GRACE_S between prepare and commit) or a concurrent
         process's recover() may have removed the staged files, and a
         commit record must never reference deltas that no longer exist —
-        that would wedge every later compose. The expired committer gets
+        that would block every later compose. The expired committer gets
         a clean write-write conflict (tx aborts) instead."""
         for table, seq in handle.get("tables", {}).items():
             if not os.path.exists(self._delta_path(table, int(seq))):

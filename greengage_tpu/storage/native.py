@@ -2,9 +2,10 @@
 
 The native library (native/ggcodec.cpp) is the host-side performance path for
 distribution hashing and block encode/decode — the role the reference fills
-with C (src/backend/cdb/cdbhash.c, cdbappendonlystorageformat.c). If the .so
-is missing we build it with make; if that fails (no toolchain) the numpy
-fallbacks are bit-identical but slower.
+with C (src/backend/cdb/cdbhash.c, cdbappendonlystorageformat.c). The first
+use runs `make -C native` (a no-op when the .so is newer than its source,
+so a stale library is rebuilt); if the build fails (no toolchain) the numpy
+fallbacks are bit-identical but slower, and build_error() says why.
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ BLOCK_MAGIC = 0x47474232
 HDR_LEN = 32
 
 _lib = None
+_build_error: str | None = None
 _load_mu = threading.Lock()
 
 
@@ -44,15 +46,20 @@ def _load():
 
 
 def _load_locked():
-    global _lib
+    global _lib, _build_error
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO):
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True, capture_output=True)
-        except Exception:
-            pass
-    if os.path.exists(_SO):
+    try:
+        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                       capture_output=True, text=True)
+    except subprocess.CalledProcessError as e:
+        # the build ran and failed: whatever .so is there is stale
+        _build_error = (e.stderr or e.stdout or str(e)).strip()[-2000:]
+    except OSError:
+        pass   # no make on this host: a shipped .so is used as it is
+    if _build_error is None and not os.path.exists(_SO):
+        _build_error = f"{_SO} is missing and make is not available"
+    if _build_error is None:
         try:
             lib = ctypes.CDLL(_SO)
             lib.gg_hash_i64_batch.argtypes = [
@@ -69,14 +76,20 @@ def _load_locked():
             lib.gg_block_decode.restype = ctypes.c_int64
             _lib = lib
             return lib
-        except OSError:
-            pass
+        except OSError as e:
+            _build_error = str(e)
     _lib = False
     return False
 
 
 def have_native() -> bool:
     return bool(_load())
+
+
+def build_error() -> str | None:
+    """Why have_native() is false: the failed build's or load's message."""
+    _load()
+    return _build_error
 
 
 # ---------------------------------------------------------------------------

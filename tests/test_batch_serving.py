@@ -360,7 +360,7 @@ def test_fallback_routes_members_to_serial_path(db, monkeypatch):
 def test_stop_releases_waiting_members(db):
     """BatchServer.stop() (Database.close) must release members parked
     in open windows — each degrades to the classic serial path on its
-    own thread instead of waiting out the wedge timeout against a dead
+    own thread instead of waiting out the stall timeout against a dead
     pipeline — and statements issued after stop still serve classically."""
     oracle = {i: db.sql(_q(i)).rows() for i in (500, 501, 502)}
     db.sql("set batch_serving_enabled = on")
@@ -389,7 +389,7 @@ def test_stop_releases_waiting_members(db):
     plug.join(timeout=30)
     assert not any(t.is_alive() for t in ts)
     assert not errors, errors
-    # released promptly (classic re-run), nowhere near the wedge timeout
+    # released promptly (classic re-run), nowhere near the stall timeout
     assert time.monotonic() - t0 < 20
     for i in (501, 502):
         assert results[i] == oracle[i], i

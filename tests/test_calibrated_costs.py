@@ -147,8 +147,8 @@ def test_replicated_build_dwarfs_its_ici_cost():
     assert build_extra > 10 * ici
 
 
-def test_gather_charges_host_relay_floor():
-    # even a 1-row gather pays the ~65ms relay call (NOTES.md measurement)
+def test_gather_charges_host_fetch_floor():
+    # even a 1-row gather pays the fixed device->host call
     assert C.motion_cost("gather", 1, 8, 8) >= C.NS_HOST_CALL
 
 

@@ -26,7 +26,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CHILD = r"""
 import os, sys
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, sys.argv[2])
 from greengage_tpu.runtime.faultinject import faults
@@ -61,7 +61,7 @@ def _run_child_until(path, fault, wait_for, child=CHILD,
     predicate), then SIGKILL it — the genuine kill -9 the thread-level
     concurrency tests could not deliver."""
     env = dict(os.environ)
-    env["GGTPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env.update(extra_env or {})
     proc = subprocess.Popen(
@@ -217,7 +217,7 @@ def test_kill9_with_concurrent_writer_exactly_one_outcome(tmp_path):
 
 FOLD_CHILD = r"""
 import os, sys
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, sys.argv[2])
 from greengage_tpu.runtime.faultinject import faults
@@ -282,7 +282,7 @@ def test_kill9_mid_fold_loses_no_committed_rows(tmp_path, window):
 
 INTENT_CHILD = r"""
 import os, sys
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, sys.argv[2])
 from greengage_tpu.runtime.faultinject import faults
@@ -371,7 +371,7 @@ def test_kill9_mid_intent_resolve_both_windows(tmp_path, window):
 
 STREAM_CHILD = r"""
 import os, sys
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, sys.argv[2])
 from greengage_tpu.runtime.faultinject import faults

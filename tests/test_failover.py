@@ -392,7 +392,7 @@ def test_kill9_mid_stream_promoted_standby_resumes_exactly(tmp_path):
 
 STORM_CHILD = r"""
 import os, sys
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, sys.argv[2])
 import greengage_tpu
@@ -415,7 +415,7 @@ def test_storm_kill9_auto_promotion_exactly_once(tmp_path):
     sb = str(tmp_path / "sb")
     standby.init_standby(path, sb)
     env = dict(os.environ)
-    env["GGTPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     proc = subprocess.Popen(
         [sys.executable, "-c", STORM_CHILD, path, REPO],

@@ -1,5 +1,5 @@
 """Device-side result compaction before the Gather Motion (VERDICT r2 #9):
-a selective SELECT must ship ~actual rows through the device->host relay,
+a selective SELECT must ship ~actual rows through the device->host fetch,
 not the scan's padded capacity. Reference: Gather Motion semantics
 (src/backend/executor/nodeMotion.c:171) — tuples stream, padding doesn't.
 """

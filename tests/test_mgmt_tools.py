@@ -314,7 +314,7 @@ def test_pkg_missing_argument(clu, capsys):
 
 
 def test_start_stop_lifecycle(clu):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GGTPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
     env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -349,11 +349,11 @@ def test_gpconfig_persisted_settings(devices8, tmp_path, capsys):
                    "-c", "vmem_protect_limit_mb", "-v", "777"])
     assert rc == 0
     rc = cli.main(["config", "-d", path,
-                   "-c", "fused_dense_agg", "-v", "off"])
+                   "-c", "spill_prefetch", "-v", "off"])
     assert rc == 0
     d = greengage_tpu.connect(path=path, numsegments=2)
     assert d.settings.vmem_protect_limit_mb == 777
-    assert d.settings.fused_dense_agg is False
+    assert d.settings.spill_prefetch is False
     assert "777" in str(d.sql("show vmem_protect_limit_mb"))
     # listing marks persisted values
     capsys.readouterr()

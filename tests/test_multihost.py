@@ -19,7 +19,7 @@ COORD_SCRIPT = r"""
 import json, os, sys
 port, cport, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -106,7 +106,7 @@ def test_two_process_cluster(tmp_path):
     path = str(tmp_path / "cluster")
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -180,7 +180,7 @@ COORD_DEATH_SCRIPT = r"""
 import json, os, sys, time
 port, cport, path, mark = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -224,7 +224,7 @@ def test_worker_death_detected_and_degraded_service(tmp_path):
     mark = str(tmp_path / "mark")
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -297,7 +297,7 @@ COORD_MIRROR_DEATH_SCRIPT = r"""
 import glob, json, os, sys, time
 port, cport, path, mark = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -358,7 +358,7 @@ def test_worker_death_promotes_cross_host_mirrors(tmp_path):
 
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -801,7 +801,7 @@ COORD_HANG_REJOIN_SCRIPT = r"""
 import json, os, sys, time
 port, cport, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -880,7 +880,7 @@ def test_cluster_worker_hang_bounded_degrade_then_rejoin(tmp_path):
     path = str(tmp_path / "cluster")
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -1263,7 +1263,7 @@ COORD_BATCH_SCRIPT = r"""
 import json, os, sys, threading
 port, cport, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -1321,7 +1321,7 @@ def test_two_process_gang_batch_serving_canary(tmp_path):
     path = str(tmp_path / "cluster")
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -1370,7 +1370,7 @@ COORD_RUNAWAY_SCRIPT = r"""
 import json, os, sys
 port, cport, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -1418,7 +1418,7 @@ def test_cluster_runaway_aggregated_watermark_cancels_gangwide(tmp_path):
     path = str(tmp_path / "cluster")
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

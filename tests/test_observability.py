@@ -371,7 +371,7 @@ OBS_COORD_SCRIPT = r"""
 import json, os, sys
 port, cport, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["GGTPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.environ["GGTPU_REPO"])
 from greengage_tpu.parallel.multihost import init_multihost
@@ -405,7 +405,7 @@ def test_multihost_worker_spans_parent_under_dispatch(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.update({
-        "JAX_PLATFORMS": "cpu", "GGTPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "GGTPU_REPO": repo, "PYTHONPATH": repo,
     })

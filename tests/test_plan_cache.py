@@ -194,7 +194,7 @@ def test_zone_prune_resolves_param_values(devices8):
 
 def test_plan_cache_lru_and_hint_lifetime(db):
     """Satellites: real LRU (not FIFO) in both caches, bounded by the
-    plan_cache_size GUC; cap-hint/fused bookkeeping dies with the last
+    plan_cache_size GUC; cap-hint bookkeeping dies with the last
     program of its statement."""
     db.sql("set plan_cache_size = 2")
     db.sql("select count(*) from t where a > 1")          # shape A

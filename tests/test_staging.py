@@ -298,7 +298,6 @@ def test_staging_microbench_emits_headline(tmp_path):
     env.update({
         "GGTPU_MB_ROWS": "20000", "GGTPU_MB_COLS": "3",
         "GGTPU_MB_SEGS": "4", "GGTPU_MB_RUNS": "1",
-        "GGTPU_BENCH_PLATFORM": "cpu",
     })
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
