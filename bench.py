@@ -676,6 +676,51 @@ def microbench_feedback() -> None:
         shutil.rmtree(path, ignore_errors=True)
 
 
+def microbench_span_cost() -> None:
+    """What recording one span (begin + end, runtime/trace.py) costs on THIS
+    host and backend, in ns: bare, with the `gg:` profiler mirror and no
+    profiler session, and with the device-memory sampler on top (one
+    `memory_stats()` PJRT call a sample on a TPU; on the CPU backend the
+    sampler latches off and `sampler_live` says so). Pins no platform: run
+    it through `chiprun` for the numbers docs/OBSERVABILITY.md quotes."""
+    import jax
+
+    from greengage_tpu.runtime import memaccount
+    from greengage_tpu.runtime import trace as T
+
+    def per_span(name: str, traces: int = 5, spans: int = 4000) -> float:
+        best = float("inf")   # of three rounds; a trace holds MAX_SPANS
+        for _ in range(3):
+            t0 = time.perf_counter_ns()
+            for _ in range(traces):
+                tr = T.Trace(0, "probe")
+                for _ in range(spans):
+                    tr.end(tr.begin(name, cat="exec", n=1))
+            best = min(best, (time.perf_counter_ns() - t0) / (traces * spans))
+        return round(best, 1)
+
+    dev = jax.devices()[0]
+    sampler, live = T.MEM_SAMPLER, memaccount.sample_watermark() is not None
+    out = {"metric": "microbench_span_cost", "unit": "ns/span",
+           "platform": dev.platform, "device_kind": dev.device_kind,
+           "sampler_live": live}
+    try:
+        T.MEM_SAMPLER, T._ANNOTATION = None, False
+        out["bare"] = per_span("dispatch")
+        T._ANNOTATION = None   # resolves jax.profiler.TraceAnnotation anew
+        out["mirror_idle"] = per_span("dispatch")
+        T.MEM_SAMPLER = sampler
+        out["mirror_idle_unsampled_name"] = per_span("parse")
+        out["mirror_idle_sampled_name"] = per_span("dispatch")
+        t0 = time.perf_counter_ns()
+        for _ in range(5000):
+            sampler()
+        out["one_sample"] = round((time.perf_counter_ns() - t0) / 5000, 1)
+    finally:
+        T.MEM_SAMPLER, T._ANNOTATION = sampler, None
+    print(json.dumps(out), flush=True)
+
+
 def microbench(name: str) -> None:
     fn = globals().get("microbench_" + name)
     if fn is None:

@@ -40,12 +40,45 @@ from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.logger import (DEFAULT_BUCKETS_MB, counters,
                                           histograms)
 from greengage_tpu.runtime.runaway import TRACKER
+from greengage_tpu.storage import blockfile
 
 # per-statement I/O accounting reported in Result.stats["scan_io"] and the
 # EXPLAIN ANALYZE host-data-path lines (counter deltas, never wall clocks,
 # so tests can assert them deterministically)
 SCAN_COUNTERS = ("scan_files_read", "scan_bytes_decoded", "scan_cache_hit",
                  "scan_cache_miss", "scan_cache_evict")
+
+
+def _span_ms(sid) -> float | None:
+    """Duration of a closed span of the calling thread's trace."""
+    tr = _trace.TRACES.current()
+    spans = tr.subtree(sid) if tr is not None and sid is not None else []
+    return spans[0]["dur"] if spans else None
+
+
+def _stage_split(stage_sid) -> dict:
+    """Result.stats' split of one attempt's stage time: the durations of
+    the leaf spans under its `stage` span summed by kind, and what its
+    read units (`read:<table>`, on pool threads, so their times are
+    thread-summed and may exceed the wall) took from storage."""
+    tr = _trace.TRACES.current()
+    spans = (tr.subtree(stage_sid)
+             if tr is not None and stage_sid is not None else [])
+    if not spans:
+        return {}
+    out = dict.fromkeys(
+        ("stage_wait_ms", "stage_assemble_ms", "stage_put_ms",
+         "stage_put_bytes", "read_io_ms", "read_decode_ms", "read_bytes"), 0)
+    for s in spans:
+        name, args = s["name"], s["args"]
+        if name in ("wait", "assemble", "put"):
+            out[f"stage_{name}_ms"] += s["dur"] or 0.0
+            out["stage_put_bytes"] += args.get("bytes", 0)   # only `put`'s
+        elif name.startswith("read:"):
+            out["read_io_ms"] += args.get("io_ms", 0.0)
+            out["read_decode_ms"] += args.get("decode_ms", 0.0)
+            out["read_bytes"] += args.get("bytes_read", 0)
+    return {k: round(v, 3) for k, v in out.items()}
 
 
 class QueryError(RuntimeError):
@@ -530,6 +563,7 @@ class Executor:
             stage_ms = (t_compute - t_stage) * 1e3
             scan_io = {k: counters.get(k) - io0[k] for k in SCAN_COUNTERS}
             _trace.annotate(_sp_stage, **scan_io)
+            stage_split = _stage_split(_sp_stage)
             # last cancellation point before dispatch: once the program
             # is on the device it runs to this boundary (the documented
             # semantic — XLA programs cannot be preempted mid-flight)
@@ -641,9 +675,10 @@ class Executor:
                     # every segment's shard is on the host — finalization
                     # happens per-endpoint at RETRIEVE time
                     return EndpointBatch(comp, flat, snapshot, raw, self.nseg)
-                with _trace.span("finalize", cat="host"):
+                with _trace.span("finalize", cat="host") as _sp_fin:
                     res = self._finalize(comp, flat, snapshot, raw=raw)
                 res.wall_ms = (time.monotonic() - t0) * 1e3
+                finalize_ms = _span_ms(_sp_fin)
                 if not was_cached:
                     # the first dispatch of a fresh program carries the
                     # XLA compile; fold it into the statement's compile
@@ -658,6 +693,12 @@ class Executor:
                     "stage_ms": round(stage_ms, 2),
                     "compute_ms": round(compute_ms, 2),
                     "fetch_ms": round(fetch_ms, 2),
+                    # where stage_ms went, as sums of the trace's own span
+                    # durations (a stat and `gg trace` cannot disagree);
+                    # absent when the statement is not traced
+                    **stage_split,
+                    **({} if finalize_ms is None
+                       else {"finalize_ms": finalize_ms}),
                     "scan_io": scan_io,
                     "segments": self.nseg,
                     # FTS/topology version the dispatch was bound against
@@ -1227,10 +1268,14 @@ class Executor:
         from jax.sharding import PartitionSpec as P
 
         sh = NamedSharding(self.mesh, P())
-        if self.multihost is None:
-            return jax.device_put(host, sh)
-        return jax.make_array_from_callback(host.shape, sh,
-                                            lambda idx: host[idx])
+        # a `put` like the tables': on a TPU this small transfer queues
+        # behind the table transfers still in flight, so it is where the
+        # statement thread waits for them (PERF.md section 5)
+        with _trace.span("put", cat="stage", bytes=int(host.nbytes)):
+            if self.multihost is None:
+                return jax.device_put(host, sh)
+            return jax.make_array_from_callback(host.shape, sh,
+                                                lambda idx: host[idx])
 
     def _stage(self, comp: CompileResult, snapshot, pvec=None) -> list:
         """Pipelined input staging (exec/staging.py, docs/PERF.md): submit
@@ -1263,6 +1308,11 @@ class Executor:
         # threads bind to it for the unit's duration, so block-cache
         # inserts inside the read attribute to the right owner tree
         stmt_acct = memaccount.ACCOUNTS.current()
+        # and so does its trace: the registry is keyed by thread, so a
+        # unit records its `read:<table>` span through this handle, under
+        # the `stage` span this call runs inside
+        stmt_trace = _trace.TRACES.current()
+        stage_sid = stmt_trace.top() if stmt_trace is not None else None
 
         # plan phase: resolve per-table staging decisions. Read units are
         # submitted through a bounded LOOKAHEAD window (the table being
@@ -1348,7 +1398,7 @@ class Executor:
                     futs.append(rpool.submit(
                         self._read_unit, table, st["child_parts"], seg,
                         st["storage_cols"], snapshot, prune, st["rng"],
-                        dest, stmt_ctx, stmt_acct))
+                        dest, stmt_ctx, stmt_acct, stmt_trace, stage_sid))
             st["buffers"] = buffers
             st["futs"] = futs
 
@@ -1386,24 +1436,30 @@ class Executor:
                     if pstats is not None:
                         self._last_prune_stats[table] = pstats
                     continue
-                for j in range(done_reads, min(done_reads + 2,
-                                               len(read_plans))):
-                    _submit(read_plans[j])   # this table + one of lookahead
                 st = payload
-                storage_cols, futs, buffers = \
-                    st["storage_cols"], st["futs"], st["buffers"]
                 per_seg = []
                 kept = total_blocks = 0
-                for fut in futs:
-                    if fut is None:
-                        per_seg.append(({c: np.empty(0, dtype=np.int64)
-                                         for c in storage_cols}, {}, 0))
-                        continue
-                    c, v, n, pstat = fut.result()
-                    per_seg.append((c, v, n))
-                    if pstat is not None:
-                        kept += pstat[0]
-                        total_blocks += pstat[1]
+                # the statement thread's time in a read table is three
+                # kinds of leaf span, exhaustively: `wait` (handing the
+                # units to the pool and blocking on them; scan_threads = 1
+                # runs them inline here), then per column `assemble` and
+                # `put` inside _assemble
+                with _trace.span("wait", cat="stage"):
+                    for j in range(done_reads, min(done_reads + 2,
+                                                   len(read_plans))):
+                        _submit(read_plans[j])   # this table + one ahead
+                    storage_cols, futs, buffers = \
+                        st["storage_cols"], st["futs"], st["buffers"]
+                    for fut in futs:
+                        if fut is None:
+                            per_seg.append(({c: np.empty(0, dtype=np.int64)
+                                             for c in storage_cols}, {}, 0))
+                            continue
+                        c, v, n, pstat = fut.result()
+                        per_seg.append((c, v, n))
+                        if pstat is not None:
+                            kept += pstat[0]
+                            total_blocks += pstat[1]
                 if prune and total_blocks:
                     self._last_prune_stats[table] = (kept, total_blocks)
                 staged = self._assemble(table, cols, cap, per_seg, shard,
@@ -1420,10 +1476,20 @@ class Executor:
                         nbytes=nbytes, version=version)
                 arrays.extend(staged)
                 done_reads += 1
+                # let go of the table's host copies HERE, as the tail of
+                # its assembly: unmapping GBs of decoded blocks and
+                # [nseg*cap] buffers costs this thread ~0.07 s a GB on the
+                # chip's host (PERF.md section 5), which would otherwise
+                # fall between the spans when _stage returns — and until
+                # then every table's copies stayed alive at once
+                with _trace.span("assemble", cat="stage", release=True):
+                    st["futs"] = st["buffers"] = None
+                    per_seg = futs = buffers = fut = c = v = None
         return arrays
 
     def _read_unit(self, table, child_parts, seg, storage_cols, snapshot,
-                   prune, rng, dest=None, stmt_ctx=None, stmt_acct=None):
+                   prune, rng, dest=None, stmt_ctx=None, stmt_acct=None,
+                   stmt_trace=None, parent_sid=None):
         """One pooled staging unit: one segment's decoded columns (+ this
         thread's zone-prune stats). Runs concurrently with other units —
         the store's caches and read-path self-heal are thread-safe.
@@ -1432,14 +1498,31 @@ class Executor:
         interrupt context: each unit is a cancellation point, and the
         raise travels back to the statement thread via fut.result().
         ``stmt_acct`` binds this pool thread to the statement's memory
-        account so block-cache inserts inside the read attribute right."""
+        account so block-cache inserts inside the read attribute right.
+        ``stmt_trace`` is its trace: the unit records one `read:<table>`
+        span there under ``parent_sid`` (the statement's `stage` span),
+        carrying what THIS unit read and how long it spent in file reads
+        and in CRC + decode (blockfile.ReadTally, bound to this thread)."""
         faults.check("cancel_in_staging", segment=seg)
         if stmt_ctx is not None:
             stmt_ctx.check()
-        with memaccount.ACCOUNTS.bind(stmt_acct):
-            c, v, n = self._read_segment_parts(
-                table, child_parts, seg, storage_cols, snapshot, prune,
-                dest=dest)
+        sid = (stmt_trace.begin("read:" + table, cat="stage",
+                                parent=parent_sid, segment=seg)
+               if stmt_trace is not None else -1)
+        with blockfile.tally() as io:
+            try:
+                with memaccount.ACCOUNTS.bind(stmt_acct):
+                    c, v, n = self._read_segment_parts(
+                        table, child_parts, seg, storage_cols, snapshot,
+                        prune, dest=dest)
+            finally:
+                if sid >= 0:
+                    stmt_trace.end(
+                        sid, files=io.files, cache_hits=io.cache_hits,
+                        bytes_read=io.bytes_read,
+                        bytes_decoded=io.bytes_decoded,
+                        io_ms=round(io.io_ns / 1e6, 3),
+                        decode_ms=round(io.decode_ns / 1e6, 3))
         if rng is not None:
             a, b = rng
             c = {k: arr[a:b] for k, arr in c.items()}
@@ -1480,38 +1563,42 @@ class Executor:
         nseg = self.nseg
         booldt = np.dtype(bool)
         for c in cols:
-            if c.startswith(VALID_PREFIX):
-                name = c[len(VALID_PREFIX):]
-                host = staging.fill_buffer(
-                    nseg, cap, booldt,
-                    ((s, vv[name] if vv.get(name) is not None
-                      else np.ones(n, dtype=bool))
-                     for s, (_, vv, n) in enumerate(per_seg)), False)
-            else:
-                dt = self._stage_dtype(schema, c)
-                buf = buffers.get(c) if buffers is not None else None
-                if buf is None:
-                    host = staging.fill_buffer(
-                        nseg, cap, dt,
-                        ((s, cc.get(c, np.zeros(0, dt))
-                          .astype(dt, copy=False))
-                         for s, (cc, _, _) in enumerate(per_seg)), 0)
-                else:
-                    for s, (cc, _, _) in enumerate(per_seg):
-                        arr = cc.get(c)
-                        n = 0 if arr is None else len(arr)
-                        if n and getattr(arr, "base", None) is not buf:
-                            buf[s * cap: s * cap + n] = arr
-                        if n < cap:
-                            buf[s * cap + n: (s + 1) * cap] = 0
-                    host = buf
+            with _trace.span("assemble", cat="stage"):
+                host = self._fill_column(schema, c, cap, per_seg, buffers)
             staged.append(self._put(host, shard, cap))
-        present = staging.fill_buffer(
-            nseg, cap, booldt,
-            ((s, np.ones(n, dtype=bool))
-             for s, (_, _, n) in enumerate(per_seg)), False)
+        with _trace.span("assemble", cat="stage"):
+            present = staging.fill_buffer(
+                nseg, cap, booldt,
+                ((s, np.ones(n, dtype=bool))
+                 for s, (_, _, n) in enumerate(per_seg)), False)
         staged.append(self._put(present, shard, cap))
         return staged
+
+    def _fill_column(self, schema, c, cap, per_seg, buffers) -> np.ndarray:
+        """One column's [nseg*cap] host buffer, padded."""
+        nseg = self.nseg
+        if c.startswith(VALID_PREFIX):
+            name = c[len(VALID_PREFIX):]
+            return staging.fill_buffer(
+                nseg, cap, np.dtype(bool),
+                ((s, vv[name] if vv.get(name) is not None
+                  else np.ones(n, dtype=bool))
+                 for s, (_, vv, n) in enumerate(per_seg)), False)
+        dt = self._stage_dtype(schema, c)
+        buf = buffers.get(c) if buffers is not None else None
+        if buf is None:
+            return staging.fill_buffer(
+                nseg, cap, dt,
+                ((s, cc.get(c, np.zeros(0, dt)).astype(dt, copy=False))
+                 for s, (cc, _, _) in enumerate(per_seg)), 0)
+        for s, (cc, _, _) in enumerate(per_seg):
+            arr = cc.get(c)
+            n = 0 if arr is None else len(arr)
+            if n and getattr(arr, "base", None) is not buf:
+                buf[s * cap: s * cap + n] = arr
+            if n < cap:
+                buf[s * cap + n: (s + 1) * cap] = 0
+        return buf
 
     def _dyn_pruned_parts(self, table, child_parts, dyn, snapshot) -> tuple:
         """-> child partitions surviving the build-side key-value probe
@@ -1633,15 +1720,17 @@ class Executor:
         """Place a [nseg*cap] host array onto the mesh. Multi-host: each
         process holds data only for its LOCAL segments (remote positions
         are zero padding) and contributes exactly its addressable shards
-        via make_array_from_callback."""
-        if self.multihost is None:
-            return jax.device_put(host, shard)
+        via make_array_from_callback. The `put` span ends when that call
+        returns, which need not be when the transfer has ended."""
+        with _trace.span("put", cat="stage", bytes=int(host.nbytes)):
+            if self.multihost is None:
+                return jax.device_put(host, shard)
 
-        def cb(index):
-            sl = index[0]
-            return host[sl.start or 0: sl.stop]
+            def cb(index):
+                sl = index[0]
+                return host[sl.start or 0: sl.stop]
 
-        return jax.make_array_from_callback(host.shape, shard, cb)
+            return jax.make_array_from_callback(host.shape, shard, cb)
 
     # ------------------------------------------------------------------
     def _finalize(self, comp: CompileResult, flat, snapshot,

@@ -10,6 +10,13 @@ backend) and every host-side phase records a span into it:
       compile                      (XLA trace+compile of a cache miss)
       stage                        (host data path; one child per table)
         stage:<table>
+          wait / assemble / put    (the statement thread's time, split
+                                    exhaustively: blocked on the read
+                                    units, filling and freeing host
+                                    buffers, device_put)
+        read:<table>               (one per read unit, recorded from its
+                                    gg-stage pool thread through an
+                                    explicit handle to this trace)
       dispatch                     (device program; multihost: the whole
                                     two-phase exchange, with one child
                                     subtree per worker grafted from its
@@ -30,6 +37,12 @@ dispatch span's clock), so one trace shows the whole cluster's statement.
 Completed traces land in a bounded ring (``trace_ring_size`` GUC) indexed
 by statement id; ``to_chrome()`` renders the ``trace_event`` JSON that
 ``gg trace <id>`` serves and chrome://tracing / Perfetto load directly.
+
+Every locally recorded span is also mirrored into a
+``jax.profiler.TraceAnnotation("gg:<name>")``, so any profiler capture of
+the process holds the program's spans on the profiler's clock, on the
+host thread lines beside the devices' ``XLA Ops``. With no profiler
+session the annotation is a flag test in the runtime.
 """
 
 from __future__ import annotations
@@ -46,16 +59,42 @@ MAX_SPANS = 4096
 MAX_GRAFT_SPANS = 1024
 
 # live device-memory sampler (runtime/memaccount.py installs it): called
-# at span boundaries so every span carries its HBM watermark + delta.
+# at the boundaries of the spans across which HBM can change, so those
+# carry their watermark + delta. One sample is a PJRT call on a TPU, so
+# every other span (the host-only phases, the stage leaves, everything a
+# pool thread records) never calls it.
 # None until installed; the installed sampler returns None on backends
 # without allocator stats (CPU), which keeps spans clean there. A hook
 # (not an import) so this substrate stays dependency-free.
 MEM_SAMPLER = None
+_SAMPLED = frozenset({"stage", "dispatch", "fetch", "batch-dispatch"})
+_SAMPLED_PREFIXES = ("stage:", "spill-", "motion-")
 
 
 def set_mem_sampler(fn) -> None:
     global MEM_SAMPLER
     MEM_SAMPLER = fn
+
+
+def samples_memory(name: str) -> bool:
+    """Whether a span of this name samples the device's memory."""
+    return name in _SAMPLED or name.startswith(_SAMPLED_PREFIXES)
+
+
+# jax.profiler.TraceAnnotation, resolved at the first span (lazily, like
+# the sampler hook: this module imports without jax); False = unavailable
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION
 
 _JSON_SCALARS = (bool, int, float, str, type(None))
 
@@ -94,12 +133,18 @@ class Trace:
         self._spans: list[dict] = []
         self._by_id: dict[int, dict] = {}
         self._stacks: dict[int, list[int]] = {}   # thread ident -> open sids
+        self._mirrors: dict[int, object] = {}     # open sid -> annotation
 
     # ---- recording -----------------------------------------------------
-    def begin(self, name: str, cat: str = "exec", **args) -> int:
+    def begin(self, name: str, cat: str = "exec", parent: int | None = None,
+              **args) -> int:
+        """Open a span on the calling thread. ``parent`` names the parent
+        explicitly — how a pool thread, whose own stack is empty, hangs
+        its span under the statement thread's; by default the parent is
+        the calling thread's innermost open span."""
         ts = (time.monotonic() - self.t0) * 1e3
         tid = threading.get_ident()
-        if MEM_SAMPLER is not None:
+        if MEM_SAMPLER is not None and samples_memory(name):
             hbm = MEM_SAMPLER()   # device watermark at span entry
             if hbm is not None:
                 args["hbm_bytes"] = hbm
@@ -108,9 +153,11 @@ class Trace:
                 return -1
             sid = next(self._ids)
             stack = self._stacks.setdefault(tid, [])
+            if parent is None or parent < 0:
+                parent = stack[-1] if stack else None
             span = {
                 "id": sid,
-                "parent": stack[-1] if stack else None,
+                "parent": parent,
                 "name": name,
                 "cat": cat,
                 "tid": threading.current_thread().name,
@@ -121,17 +168,28 @@ class Trace:
             self._spans.append(span)
             self._by_id[sid] = span
             stack.append(sid)
+        # the same span on the profiler's clock, entered (and, in end(),
+        # left) on the recording thread
+        ann = _annotation()
+        if ann:
+            mirror = ann("gg:" + name, trace_id=self.trace_id)
+            mirror.__enter__()
+            self._mirrors[sid] = mirror
         return sid
 
     def end(self, sid: int, **args) -> None:
         if sid is None or sid < 0:
             return
         now = (time.monotonic() - self.t0) * 1e3
-        hbm = MEM_SAMPLER() if MEM_SAMPLER is not None else None
+        mirror = self._mirrors.pop(sid, None)
+        if mirror is not None:
+            mirror.__exit__(None, None, None)
+        span = self._by_id.get(sid)
+        if span is None:
+            return
+        hbm = (MEM_SAMPLER() if MEM_SAMPLER is not None
+               and samples_memory(span["name"]) else None)
         with self._lock:
-            span = self._by_id.get(sid)
-            if span is None:
-                return
             if hbm is not None:
                 # device-memory delta across the span (`gg trace` shows
                 # which phase grew/shrank HBM — the data-movement lens)
@@ -230,6 +288,24 @@ class Trace:
     def find_spans(self, name: str) -> list[dict]:
         with self._lock:
             return [dict(s) for s in self._spans if s["name"] == name]
+
+    def top(self) -> int | None:
+        """The calling thread's innermost open span — what a pool thread
+        is handed as the parent of the spans it records."""
+        with self._lock:
+            stack = self._stacks.get(threading.get_ident())
+            return stack[-1] if stack else None
+
+    def subtree(self, sid: int) -> list[dict]:
+        """The span ``sid`` and every span below it, in recording order
+        (a child is always recorded after its parent)."""
+        with self._lock:
+            ids, out = {sid}, []
+            for s in self._spans:
+                if s["id"] == sid or s["parent"] in ids:
+                    ids.add(s["id"])
+                    out.append({**s, "args": dict(s["args"])})
+            return out
 
 
 class _NullSpan:
@@ -337,6 +413,12 @@ class TraceRegistry:
             if not self._ring:
                 return None
             return next(reversed(self._ring.values()))
+
+    def between(self, t_lo: float, t_hi: float) -> list[Trace]:
+        """The retired traces that started (``Trace.t0``, on
+        ``time.monotonic()``) inside [t_lo, t_hi], oldest first."""
+        with self._lock:
+            return [t for t in self._ring.values() if t_lo <= t.t0 <= t_hi]
 
     def active_span(self, trace_id: int) -> tuple[str, float] | None:
         """(current span name, elapsed ms) of an IN-FLIGHT statement —

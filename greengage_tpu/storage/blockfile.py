@@ -22,8 +22,11 @@ from __future__ import annotations
 
 import json
 import os
+import threading
+import time
 import warnings
 import zlib
+from contextlib import contextmanager
 
 import numpy as np
 
@@ -38,6 +41,39 @@ FOOTER_TAIL = 16           # u32 crc + u64 footer_len + u32 magic
 DEFAULT_BLOCK_ROWS = 1 << 16
 
 _COMP_BY_NAME = {"none": native.COMP_NONE, "zlib": native.COMP_ZLIB, "zstd": native.COMP_ZSTD}
+
+
+class ReadTally:
+    """What one read unit took from storage. read_column_file sums into
+    the tally bound to the calling thread, so concurrent statements (and
+    the units of one statement on different pool threads) never mix —
+    which a process-wide counter cannot give."""
+
+    __slots__ = ("files", "cache_hits", "bytes_read", "bytes_decoded",
+                 "io_ns", "decode_ns")
+
+    def __init__(self):
+        self.files = self.cache_hits = 0        # decoded / block-cache hit
+        self.bytes_read = self.bytes_decoded = 0   # compressed frames / rows
+        self.io_ns = self.decode_ns = 0         # in f.read / CRC + decode
+
+
+_tally = threading.local()
+
+
+@contextmanager
+def tally():
+    """Bind a fresh ReadTally to the calling thread for the block."""
+    prev, t = getattr(_tally, "t", None), ReadTally()
+    _tally.t = t
+    try:
+        yield t
+    finally:
+        _tally.t = prev
+
+
+def current_tally() -> ReadTally | None:
+    return getattr(_tally, "t", None)
 
 
 def fsync_dir(path: str) -> None:
@@ -210,15 +246,22 @@ def read_column_file(path: str, block_indices: list[int] | None = None,
         u8 = out.view(np.uint8)
         itemsize = dtype.itemsize
         row = 0
+        clock = time.perf_counter_ns
+        io_ns = decode_ns = bytes_read = 0
         for i, b in blocks:
+            t_io = clock()
             f.seek(b["offset"])
             frame = f.read(b["bytes"])
+            io_ns += clock() - t_io
+            bytes_read += len(frame)
             frame = _maybe_inject_corruption(frame, segment)
             slot = u8[row * itemsize: (row + b["nrows"]) * itemsize]
+            t_dec = clock()
             try:
                 nbytes, nrows = native.block_decode_into(frame, slot)
             except CorruptionError as e:
                 raise e.locate(path=path, block=i)
+            decode_ns += clock() - t_dec
             if nrows != b["nrows"] or nbytes != nrows * itemsize:
                 raise CorruptionError(
                     "rowcount_mismatch",
@@ -226,6 +269,13 @@ def read_column_file(path: str, block_indices: list[int] | None = None,
                     f"says {b['nrows']} rows of {itemsize} bytes",
                     path=path, block=i)
             row += nrows
+    t = current_tally()
+    if t is not None:
+        t.files += 1
+        t.bytes_read += bytes_read
+        t.bytes_decoded += out.nbytes
+        t.io_ns += io_ns
+        t.decode_ns += decode_ns
     return out
 
 

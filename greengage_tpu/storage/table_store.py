@@ -35,7 +35,8 @@ from greengage_tpu.runtime.faultinject import FaultError, faults
 from greengage_tpu.runtime.logger import counters
 from greengage_tpu.storage import native
 from greengage_tpu.storage.blockcache import MISS, CacheRegistry
-from greengage_tpu.storage.blockfile import (fsync_dir, read_column_file,
+from greengage_tpu.storage.blockfile import (current_tally, fsync_dir,
+                                             read_column_file,
                                              verify_column_file,
                                              write_column_file)
 from greengage_tpu.storage.corruption import CorruptionError
@@ -457,6 +458,9 @@ class TableStore:
                None if block_indices is None else tuple(block_indices))
         hit = self._block_cache.get(key, MISS)
         if hit is not MISS:
+            tally = current_tally()
+            if tally is not None:
+                tally.cache_hits += 1
             return hit
         arr = self._read_checked(
             table, rel,

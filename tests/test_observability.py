@@ -17,6 +17,7 @@ import pytest
 import greengage_tpu
 from greengage_tpu.runtime.logger import (counters, histograms,
                                           prometheus_text, read_entries)
+from greengage_tpu.runtime import trace as trace_mod
 from greengage_tpu.runtime.trace import (TRACES, Trace, TraceRegistry,
                                          to_chrome)
 
@@ -339,28 +340,45 @@ def test_slow_statement_log_fires_at_threshold(db):
 # overhead bound (acceptance: <= 5% on the warm plan-cache microbench)
 # ---------------------------------------------------------------------------
 
-def test_trace_overhead_bounded_on_warm_statement(db):
+# one device.memory_stats() sample on the chip's host (my chip run, PR 28:
+# bench.py --microbench span_cost read 3,507 ns on a TPU v5e's host); the
+# CPU backend's real sampler latches off at its first probe and costs nothing
+SAMPLE_NS = 3500
+
+
+def test_trace_overhead_bounded_on_warm_statement(db, monkeypatch):
     q = "select count(*), sum(v) from obs where v > 3"
     db.sql(q)   # compile + cache
     runs, t0 = 5, time.perf_counter()
     for _ in range(runs):
         db.sql(q)
     warm_ms = (time.perf_counter() - t0) * 1e3 / runs
-    nspans = len(TRACES.last().export())
-    assert nspans <= 32, nspans   # warm path records a bounded span set
-    # measured per-span record cost x spans per statement must stay under
-    # 5% of the warm statement (timer-verified, not assumed)
-    tr = Trace(0, "overhead-probe")
-    reps = 2000
+    names = [s["name"] for s in TRACES.last().export()]
+    assert len(names) <= 32, names   # warm path records a bounded span set
+    # measured record cost of THIS statement's spans — the mirror into the
+    # profiler idle, and a sampler of the chip's cost installed so the spans
+    # that still sample pay for it — must stay under 5% of the warm
+    # statement (timer-verified, not assumed)
+
+    def sampler():
+        until = time.perf_counter_ns() + SAMPLE_NS
+        while time.perf_counter_ns() < until:
+            pass
+        return 1 << 30
+
+    monkeypatch.setattr(trace_mod, "MEM_SAMPLER", sampler)
+    reps = 300
     t0 = time.perf_counter()
     for _ in range(reps):
-        sid = tr.begin("probe", cat="exec", n=1)
-        tr.end(sid)
-    per_span_ms = (time.perf_counter() - t0) * 1e3 / reps
-    overhead_ms = per_span_ms * nspans
+        tr = Trace(0, "overhead-probe")
+        for name in names:
+            tr.end(tr.begin(name, cat="exec", n=1))
+    overhead_ms = (time.perf_counter() - t0) * 1e3 / reps
+    sampled = [n for n in names if trace_mod.samples_memory(n)]
+    assert sampled and len(sampled) < len(names)
     assert overhead_ms <= 0.05 * warm_ms, (
         f"trace overhead {overhead_ms:.4f} ms vs warm {warm_ms:.2f} ms "
-        f"({nspans} spans @ {per_span_ms * 1e3:.2f} us)")
+        f"({len(names)} spans, {len(sampled)} of them sampling)")
 
 
 # ---------------------------------------------------------------------------
