@@ -81,7 +81,7 @@ THREAD_ROLES: dict[str, Role] = {
     ),
     "staging": Role(
         "staging",
-        "PR-3 staging pool workers: concurrent (table, segment) "
+        "PR-3 staging pool workers: concurrent (table, segment, column) "
         "read+decode units through the store's caches",
         spawns=(("exec/staging.py", "ThreadPoolExecutor"),),
         entries=(("exec/executor.py", "Executor", "_read_unit"),),

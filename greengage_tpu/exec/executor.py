@@ -68,13 +68,15 @@ def _stage_split(stage_sid) -> dict:
         return {}
     out = dict.fromkeys(
         ("stage_wait_ms", "stage_assemble_ms", "stage_put_ms",
-         "stage_put_bytes", "read_io_ms", "read_decode_ms", "read_bytes"), 0)
+         "stage_put_bytes", "stage_read_units", "read_io_ms",
+         "read_decode_ms", "read_bytes"), 0)
     for s in spans:
         name, args = s["name"], s["args"]
         if name in ("wait", "assemble", "put"):
             out[f"stage_{name}_ms"] += s["dur"] or 0.0
             out["stage_put_bytes"] += args.get("bytes", 0)   # only `put`'s
         elif name.startswith("read:"):
+            out["stage_read_units"] += 1
             out["read_io_ms"] += args.get("io_ms", 0.0)
             out["read_decode_ms"] += args.get("decode_ms", 0.0)
             out["read_bytes"] += args.get("bytes_read", 0)
@@ -1278,13 +1280,13 @@ class Executor:
                                                 lambda idx: host[idx])
 
     def _stage(self, comp: CompileResult, snapshot, pvec=None) -> list:
-        """Pipelined input staging (exec/staging.py, docs/PERF.md): submit
-        every (table, segment) read+decode unit of the WHOLE input spec to
-        the staging pool first, then assemble tables in spec order into
-        preallocated [nseg*cap] buffers and issue each table's device
-        transfer as soon as its buffers fill — later tables' disk reads
-        overlap earlier tables' assembly and host->device transfer, and
-        (with JAX async dispatch) the device program itself."""
+        """Pipelined input staging (exec/staging.py, docs/PERF.md): hand
+        the staging pool one read+decode unit per (table, segment, column),
+        column-major, then consume them in spec order column by column:
+        wait for a column's units, fill its preallocated [nseg*cap] buffer
+        and issue its device transfer while the later columns (and the
+        next table's) still decode on the pool — and, with JAX async
+        dispatch, under the device program itself."""
         arrays = []
         shard = seg_sharding(self.mesh)
         local_segs = self._local_segments()
@@ -1358,8 +1360,8 @@ class Executor:
                 continue
             staged_local[key] = None   # first occurrence claims the key
             plans.append(("read", table, cols, cap, key, prune, {
-                "storage_cols": [c for c in cols
-                                 if not c.startswith(VALID_PREFIX)],
+                "units": staging.column_units(
+                    c for c in cols if not c.startswith(VALID_PREFIX)),
                 "child_parts": child_parts, "direct": direct,
                 "rng": ranges.get(table), "futs": None, "buffers": None}))
 
@@ -1383,27 +1385,34 @@ class Executor:
                 schema = self.catalog.get(table)
                 buffers = {c: np.empty(self.nseg * cap,
                                        self._stage_dtype(schema, c))
-                           for c in st["storage_cols"]}
+                           for unit in st["units"] for c in unit}
+            # direct dispatch: only the owning segment's storage is
+            # read/staged (cdbtargeteddispatch.c analog)
+            segs = [seg for seg in range(self.nseg)
+                    if seg in local_segs
+                    and (st["direct"] is None or seg == st["direct"])]
+            # column-major, in the order the assemble loop consumes the
+            # columns: the first column's units of every segment are the
+            # first to finish. Flat, from this thread: a unit never
+            # submits to the pool it runs on.
             futs = []
-            for seg in range(self.nseg):
-                if seg not in local_segs or (st["direct"] is not None
-                                            and seg != st["direct"]):
-                    # direct dispatch: only the owning segment's storage
-                    # is read/staged (cdbtargeteddispatch.c analog)
-                    futs.append(None)
-                else:
-                    dest = ({c: buf[seg * cap: (seg + 1) * cap]
-                             for c, buf in buffers.items()}
+            for unit in st["units"]:
+                row = [None] * self.nseg
+                for seg in segs:
+                    dest = ({c: buffers[c][seg * cap: (seg + 1) * cap]
+                             for c in unit}
                             if buffers is not None else None)
-                    futs.append(rpool.submit(
+                    row[seg] = rpool.submit(
                         self._read_unit, table, st["child_parts"], seg,
-                        st["storage_cols"], snapshot, prune, st["rng"],
-                        dest, stmt_ctx, stmt_acct, stmt_trace, stage_sid))
+                        unit, snapshot, prune, st["rng"],
+                        dest, stmt_ctx, stmt_acct, stmt_trace, stage_sid)
+                futs.append(row)
             st["buffers"] = buffers
             st["futs"] = futs
+            st["read_units"] = len(futs) * len(segs)
 
         # assemble phase (spec order, deterministic): fill staging buffers
-        # in place and put each table on the mesh as soon as it completes
+        # in place and put each column on the mesh as soon as it completes
         done_reads = 0
         for kind, table, cols, cap, key, prune, payload in plans:
             interrupt.check_interrupts()   # between per-table assemblies
@@ -1437,39 +1446,58 @@ class Executor:
                         self._last_prune_stats[table] = pstats
                     continue
                 st = payload
-                per_seg = []
-                kept = total_blocks = 0
+                units = st["units"]
+                # what has landed so far, a segment: [cols, valids, nrows]
+                per_seg = [[{}, {}, 0] for _ in range(self.nseg)]
                 # the statement thread's time in a read table is three
-                # kinds of leaf span, exhaustively: `wait` (handing the
-                # units to the pool and blocking on them; scan_threads = 1
-                # runs them inline here), then per column `assemble` and
-                # `put` inside _assemble
+                # kinds of leaf span, exhaustively: `wait` (blocking on one
+                # column's units; the first also hands this table's units
+                # and the next's to the pool, and scan_threads = 1 runs
+                # them inline there), then that column's `assemble` and
+                # `put`, while the later columns still decode
                 with _trace.span("wait", cat="stage"):
                     for j in range(done_reads, min(done_reads + 2,
                                                    len(read_plans))):
                         _submit(read_plans[j])   # this table + one ahead
-                    storage_cols, futs, buffers = \
-                        st["storage_cols"], st["futs"], st["buffers"]
-                    for fut in futs:
-                        if fut is None:
-                            per_seg.append(({c: np.empty(0, dtype=np.int64)
-                                             for c in storage_cols}, {}, 0))
-                            continue
-                        c, v, n, pstat = fut.result()
-                        per_seg.append((c, v, n))
+                    futs, buffers = st["futs"], st["buffers"]
+                    # every unit of a segment sees the same zone maps and
+                    # row count: the first column's speak for the segment
+                    kept = total_blocks = 0
+                    for seg, n, pstat in self._land(futs, 0, per_seg):
+                        per_seg[seg][2] = n
                         if pstat is not None:
                             kept += pstat[0]
                             total_blocks += pstat[1]
                 if prune and total_blocks:
                     self._last_prune_stats[table] = (kept, total_blocks)
-                staged = self._assemble(table, cols, cap, per_seg, shard,
-                                        buffers)
+                unit_of = {c: u for u, unit in enumerate(units)
+                           for c in unit}
+                schema = self.catalog.get(table)
+                staged = []
+                for c in cols:
+                    # a validity mask comes with the unit of its column
+                    u = unit_of.get(c[len(VALID_PREFIX):]
+                                    if c.startswith(VALID_PREFIX) else c)
+                    if u is not None and futs[u] is not None:
+                        with _trace.span("wait", cat="stage"):
+                            self._land(futs, u, per_seg)
+                    with _trace.span("assemble", cat="stage"):
+                        host = self._fill_column(schema, c, cap, per_seg,
+                                                 buffers)
+                    staged.append(self._put(host, shard, cap))
+                with _trace.span("assemble", cat="stage"):
+                    present = staging.fill_buffer(
+                        self.nseg, cap, np.dtype(bool),
+                        ((s, np.ones(n, dtype=bool))
+                         for s, (_, _, n) in enumerate(per_seg)), False)
+                staged.append(self._put(present, shard, cap))
                 staged_local[key] = (staged,
                                      self._last_prune_stats.get(table))
                 nbytes = sum(int(getattr(a, "nbytes", 64)) for a in staged)
                 memaccount.charge("staging", nbytes, item=table)
                 _trace.annotate(_sp_t, rows=int(sum(n for _, _, n in per_seg)),
-                                bytes=nbytes, segments=len(per_seg))
+                                bytes=nbytes, segments=len(per_seg),
+                                read_units=st["read_units"])
                 if st["rng"] is None:
                     self._stage_cache.put(
                         key, (staged, self._last_prune_stats.get(table)),
@@ -1484,13 +1512,33 @@ class Executor:
                 # then every table's copies stayed alive at once
                 with _trace.span("assemble", cat="stage", release=True):
                     st["futs"] = st["buffers"] = None
-                    per_seg = futs = buffers = fut = c = v = None
+                    per_seg = futs = buffers = host = present = None
         return arrays
+
+    @staticmethod
+    def _land(futs, u, per_seg) -> list:
+        """Block until unit ``u`` of every staged segment is done and its
+        columns and masks are in ``per_seg``; -> [(segment, nrows, prune
+        stats)]. A landed unit's futures are let go (``futs[u] = None``
+        says it has landed). A cancellation point a column; inside the
+        wait the units poll the statement's context themselves."""
+        interrupt.check_interrupts()
+        row, futs[u] = futs[u], None
+        out = []
+        for seg, fut in enumerate(row):
+            if fut is None:
+                continue
+            c, v, n, pstat = fut.result()
+            per_seg[seg][0].update(c)
+            per_seg[seg][1].update(v)
+            out.append((seg, n, pstat))
+        return out
 
     def _read_unit(self, table, child_parts, seg, storage_cols, snapshot,
                    prune, rng, dest=None, stmt_ctx=None, stmt_acct=None,
                    stmt_trace=None, parent_sid=None):
-        """One pooled staging unit: one segment's decoded columns (+ this
+        """One pooled staging unit: one column of one segment, decoded
+        (several where staging.column_units keeps them together; + this
         thread's zone-prune stats). Runs concurrently with other units —
         the store's caches and read-path self-heal are thread-safe.
         ``dest`` carries this segment's staging-buffer slots for the
@@ -1507,7 +1555,8 @@ class Executor:
         if stmt_ctx is not None:
             stmt_ctx.check()
         sid = (stmt_trace.begin("read:" + table, cat="stage",
-                                parent=parent_sid, segment=seg)
+                                parent=parent_sid, segment=seg,
+                                column=",".join(storage_cols))
                if stmt_trace is not None else -1)
         with blockfile.tally() as io:
             try:
@@ -1549,30 +1598,6 @@ class Executor:
                 if col_s.type.kind == T.Kind.TEXT
                 and col_s.encoding == "raw"
                 else col_s.type.np_dtype)
-
-    def _assemble(self, table, cols, cap, per_seg, shard,
-                  buffers=None) -> list:
-        """Fill one preallocated [nseg*cap] staging buffer per column IN
-        PLACE from the per-segment decoded arrays (no pad-then-concatenate
-        copy pair) and place each on the mesh. Columns whose segments
-        already decoded into their buffer slots (read_segment's dest fast
-        path) skip even that one copy — only their padding tails are
-        written."""
-        schema = self.catalog.get(table)
-        staged = []
-        nseg = self.nseg
-        booldt = np.dtype(bool)
-        for c in cols:
-            with _trace.span("assemble", cat="stage"):
-                host = self._fill_column(schema, c, cap, per_seg, buffers)
-            staged.append(self._put(host, shard, cap))
-        with _trace.span("assemble", cat="stage"):
-            present = staging.fill_buffer(
-                nseg, cap, booldt,
-                ((s, np.ones(n, dtype=bool))
-                 for s, (_, _, n) in enumerate(per_seg)), False)
-        staged.append(self._put(present, shard, cap))
-        return staged
 
     def _fill_column(self, schema, c, cap, per_seg, buffers) -> np.ndarray:
         """One column's [nseg*cap] host buffer, padded."""

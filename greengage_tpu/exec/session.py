@@ -2551,6 +2551,7 @@ class Database:
                 # host-data-path breakdown (docs/PERF.md): where the wall
                 # time went — host staging vs device program vs fetch
                 text += (f"\n Host data path: staging {s['stage_ms']:.2f} ms"
+                         f" ({s.get('stage_read_units', 0)} read units)"
                          f", device compute {s['compute_ms']:.2f} ms, "
                          f"result fetch {s['fetch_ms']:.2f} ms")
             io = s.get("scan_io") or {}

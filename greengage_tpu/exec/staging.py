@@ -6,8 +6,9 @@ staged every cold scan through one serial Python loop — read, CRC+zlib
 decode, pad, concatenate, transfer, per segment and per column. This
 module supplies the three pipeline pieces the executor composes:
 
-  - a shared READ POOL (``pool(settings)``): every (table, segment) unit
-    of a statement's input spec reads+decodes concurrently. The native
+  - a shared READ POOL (``pool(settings)``): every (table, segment,
+    column) unit of a statement's input spec reads+decodes concurrently
+    (``column_units`` says which columns travel together). The native
     codec, zlib, and file I/O all release the GIL, so the pool gets real
     parallelism; TableStore's caches and read-path self-heal are
     thread-safe under it. ``scan_threads`` sizes it (0 = auto).
@@ -101,6 +102,21 @@ def pool_queue_depth() -> int:
         return p._work_queue.qsize()
     except (AttributeError, NotImplementedError):
         return 0
+
+
+def column_units(storage_cols) -> list[list[str]]:
+    """A table's storage columns split into read units, in the order the
+    executor assembles them: one column a unit, except that the virtual
+    columns of one raw TEXT column ('@rp:c:w', '@rw:c:w', '@rl:c', '@rc:c',
+    '@hp:c:...') and the column itself share a unit, since they are cut
+    from one derived structure (TableStore.raw_chunk / raw_prefix) that
+    two units would build twice. No storage column (count(*)) still makes
+    one empty unit: it carries the segment's row count."""
+    units: dict[str, list[str]] = {}
+    for c in storage_cols:
+        source = c.split(":", 2)[1] if c.startswith("@") else c
+        units.setdefault(source, []).append(c)
+    return list(units.values()) or [[]]
 
 
 def fill_buffer(nseg: int, cap: int, dtype, parts, fill=0) -> np.ndarray:

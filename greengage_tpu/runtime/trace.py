@@ -11,9 +11,10 @@ backend) and every host-side phase records a span into it:
       stage                        (host data path; one child per table)
         stage:<table>
           wait / assemble / put    (the statement thread's time, split
-                                    exhaustively: blocked on the read
-                                    units, filling and freeing host
-                                    buffers, device_put)
+                                    exhaustively, a column at a time:
+                                    blocked on its read units, filling
+                                    and freeing host buffers,
+                                    device_put)
         read:<table>               (one per read unit, recorded from its
                                     gg-stage pool thread through an
                                     explicit handle to this trace)

@@ -17,6 +17,7 @@ from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.interrupt import (REGISTRY, StatementCancelled,
                                              StatementContext)
 from greengage_tpu.runtime.logger import counters
+from greengage_tpu.runtime.trace import TRACES
 
 
 # ---------------------------------------------------------------------------
@@ -114,9 +115,9 @@ def _cancel_sql(marker: str, cause: str = "user", timeout_s: float = 5.0):
 
 def test_statement_timeout_cancels_in_staging(db):
     """statement_timeout_s arms at statement start and the statement dies
-    at a staging-unit cancellation point (scan_threads=1: units run
-    serially on the statement thread, so the per-unit sleep fault makes
-    the deadline trip deterministic)."""
+    at a staging-unit cancellation point — one a (segment, column)
+    (scan_threads=1: units run serially on the statement thread, so the
+    per-unit sleep fault makes the deadline trip deterministic)."""
     db.sql("set scan_threads = 1")
     db.sql("set statement_timeout_s = 0.3")
     faults.inject("cancel_in_staging", "sleep", sleep_s=0.2, occurrences=-1)
@@ -133,6 +134,43 @@ def test_statement_timeout_cancels_in_staging(db):
     # the registry is clean and the session still serves
     assert REGISTRY.current() is None
     assert db.sql("select count(*) from li").rows()[0][0] == 50_000
+
+
+def test_staging_cancel_point_fires_once_a_read_unit(db):
+    """Every (segment, column) unit is a cancellation point of its own:
+    a two-column scan over four segments passes the point eight times,
+    and a deadline that trips inside the first column's units stops the
+    statement before the second column's are ever read."""
+    q = "select sum(g), sum(v) from li -- two-column-victim"
+    db.sql(q)   # compile outside the deadline below
+    db.sql("set scan_threads = 1")
+    try:
+        db.executor._stage_cache.clear()
+        db.store.blockcache.clear()
+        faults.inject("cancel_in_staging", "sleep", sleep_s=0.0,
+                      occurrences=-1)
+        assert db.sql(q).rows()[0][0] == sum(np.arange(50_000) % 11)
+        assert [f["hits"] for f in faults.status()] == [8]
+        faults.reset("cancel_in_staging")
+        db.executor._stage_cache.clear()
+        db.store.blockcache.clear()
+        db.sql("set statement_timeout_s = 0.3")
+        faults.inject("cancel_in_staging", "sleep", sleep_s=0.2,
+                      occurrences=-1)
+        with pytest.raises(StatementCancelled) as ei:
+            db.sql(q)
+        assert ei.value.cause == "timeout"
+        # column-major: the deadline fell among column g's four units, and
+        # every later unit stopped at the point, before its read
+        reads = [s["args"]["column"]
+                 for s in [t for t in TRACES.between(0.0, float("inf"))
+                           if t.sql == q][-1].export()
+                 if s["name"] == "read:li"]
+        assert reads and len(reads) < 4 and set(reads) == {"g"}
+    finally:
+        faults.reset("cancel_in_staging")
+        db.sql("set statement_timeout_s = 0")
+        db.sql("set scan_threads = 0")
 
 
 def test_user_cancel_lands_mid_staging(db):

@@ -111,23 +111,30 @@ def test_two_process_cluster(tmp_path):
         "GGTPU_REPO": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     })
-    worker = subprocess.Popen(
-        [sys.executable, "-m", "greengage_tpu.mgmt.cli", "worker",
-         "-d", path, "--coordinator", f"127.0.0.1:{port}",
-         "--control-port", str(cport), "--num-processes", "2",
-         "--process-id", "1", "--no-distributed"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    # the worker writes to a file, not a pipe: nobody reads its output
+    # until the coordinator is done, and with a warm compile cache XLA:CPU
+    # logs two 1.8 KB lines a loaded executable — past the pipe's 64 KB the
+    # worker would block in write() and the gang with it
+    wlog = tmp_path / "worker.out"
+    with open(wlog, "w") as wfile:
+        worker = subprocess.Popen(
+            [sys.executable, "-m", "greengage_tpu.mgmt.cli", "worker",
+             "-d", path, "--coordinator", f"127.0.0.1:{port}",
+             "--control-port", str(cport), "--num-processes", "2",
+             "--process-id", "1", "--no-distributed"],
+            env=env, stdout=wfile, stderr=subprocess.STDOUT, text=True)
     coord = subprocess.Popen(
         [sys.executable, "-c", COORD_SCRIPT, str(port), str(cport), path],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
     try:
         cout, _ = coord.communicate(timeout=480)
-        wout, _ = worker.communicate(timeout=60)
+        worker.wait(timeout=60)
+        wout = wlog.read_text()
     except subprocess.TimeoutExpired:
         coord.kill()
         worker.kill()
         cout = coord.stdout.read() if coord.stdout else ""
-        wout = worker.stdout.read() if worker.stdout else ""
+        wout = wlog.read_text()
         raise AssertionError(
             f"multihost timeout\ncoordinator:\n{cout}\nworker:\n{wout}")
     assert coord.returncode == 0, f"coordinator:\n{cout}\nworker:\n{wout}"

@@ -16,6 +16,7 @@ import pytest
 
 import greengage_tpu
 from greengage_tpu.runtime import trace as trace_mod
+from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.trace import TRACES, Trace, TraceRegistry
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -23,7 +24,8 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 N = 60_000
 LEAVES = ("wait", "assemble", "put")
 SPLIT_KEYS = ("stage_wait_ms", "stage_assemble_ms", "stage_put_ms",
-              "stage_put_bytes", "read_io_ms", "read_decode_ms", "read_bytes")
+              "stage_put_bytes", "stage_read_units", "read_io_ms",
+              "read_decode_ms", "read_bytes")
 Q = "select g, count(*), sum(v) from sp_a group by g order by g"
 
 
@@ -63,21 +65,151 @@ def test_cold_scan_records_read_spans_from_pool_threads(db):
     spans = tr.export()
     stage = by_name(spans, "stage")[0]
     reads = by_name(spans, "read:sp_a")
-    # one unit a segment, each recorded on its gg-stage thread, in the
-    # statement's own trace, hung under the statement thread's `stage`
-    assert sorted(s["args"]["segment"] for s in reads) == [0, 1, 2, 3]
+    # one unit a (segment, column), each recorded on its gg-stage thread, in
+    # the statement's own trace, hung under the statement thread's `stage`
+    assert sorted((s["args"]["segment"], s["args"]["column"])
+                  for s in reads) == [(seg, c) for seg in range(4)
+                                      for c in ("g", "v")]
     assert all(s["tid"].startswith("gg-stage") for s in reads), reads
     assert all(s["parent"] == stage["id"] for s in reads)
     assert stage["tid"] == by_name(spans, "statement")[0]["tid"] != reads[0]["tid"]
     for s in reads:
         a = s["args"]
-        assert a["files"] == 2 and a["cache_hits"] == 0   # columns g and v
+        assert a["files"] == 1 and a["cache_hits"] == 0   # its one column
         assert 0 < a["bytes_read"] < a["bytes_decoded"]
         assert 0 <= a["io_ms"] and 0 < a["decode_ms"]
         assert a["io_ms"] + a["decode_ms"] <= s["dur"] + 0.01
         assert "hbm_bytes" not in a
-    # an int32 and an int64 column of every row, over the four units
+    # an int32 and an int64 column of every row, over the eight units
     assert sum(s["args"]["bytes_decoded"] for s in reads) == N * 12
+    # the counter that says the mechanism engaged: segments x column units,
+    # on the table's span and (counted from the `read:` spans) in the stats
+    assert by_name(spans, "stage:sp_a")[0]["args"]["read_units"] == 8
+    assert r.stats["stage_read_units"] == len(reads) == 8
+    plan = cold(db, "explain analyze " + Q)[0].plan_text
+    assert "Host data path: staging" in plan and "(8 read units)" in plan
+
+
+def test_columns_of_one_segment_decode_side_by_side(db):
+    # every unit sleeps before it reads, so with a thread a unit the two
+    # columns of a segment must be in flight at once, on two threads
+    db.sql("set scan_threads = 8")
+    faults.inject("cancel_in_staging", "sleep", sleep_s=0.1, occurrences=-1)
+    try:
+        _r, tr = cold(db)
+    finally:
+        faults.reset("cancel_in_staging")
+        db.sql("set scan_threads = 0")
+    reads = by_name(tr.export(), "read:sp_a")
+    for seg in range(4):
+        g, v = sorted((s for s in reads if s["args"]["segment"] == seg),
+                      key=lambda s: s["args"]["column"])
+        assert g["tid"] != v["tid"] and g["tid"].startswith("gg-stage")
+    assert len({s["tid"] for s in reads}) == 8
+
+
+def test_first_column_lands_before_the_last_unit_is_read(db):
+    """The statement thread consumes column by column: under a sleep in
+    every unit and two pool threads, the units run two at a time in the
+    order they were handed over (column-major: g of four segments, then
+    v), so `wait` for column g must be over a unit's sleep or more before
+    the last v unit ends — and g's assemble and put with it. Waiting for
+    the whole table first would end the first `wait` after every read."""
+    nap = 0.1
+    db.sql("set scan_threads = 2")
+    faults.inject("cancel_in_staging", "sleep", sleep_s=nap, occurrences=-1)
+    try:
+        _r, tr = cold(db)
+    finally:
+        faults.reset("cancel_in_staging")
+        db.sql("set scan_threads = 0")
+    spans = tr.export()
+    table = by_name(spans, "stage:sp_a")[0]
+    kids = [s for s in spans if s["parent"] == table["id"]]
+
+    def end(s):
+        return s["ts"] + s["dur"]
+
+    reads = sorted(by_name(spans, "read:sp_a"), key=lambda s: s["ts"])
+    assert [s["args"]["column"] for s in reads] == ["g"] * 4 + ["v"] * 4
+    last_read = max(end(s) for s in reads)
+    waits = by_name(kids, "wait")
+    assert len(waits) == 2
+    # four sleeps on two threads are behind column g, eight behind v
+    assert waits[0]["dur"] >= 2 * nap * 1e3 - 1
+    assert end(waits[0]) <= last_read - 0.9 * nap * 1e3, (waits, reads)
+    first_put = by_name(kids, "put")[0]
+    assert end(waits[0]) <= first_put["ts"] and end(first_put) < last_read
+    assert end(waits[1]) >= last_read - 1
+
+
+def test_prune_stats_count_a_segment_once(db):
+    q = "select sum(v), sum(k) from sp_a where g < 3"
+    r, tr = cold(db, q)
+    reads = by_name(tr.export(), "read:sp_a")
+    assert sorted({s["args"]["column"] for s in reads}) == ["g", "k", "v"]
+    # what one read of each segment reports, summed over the segments
+    snap = db.store.manifest.snapshot()
+    want = [0, 0]
+    for seg in range(4):
+        db.store.read_segment("sp_a", seg, ["g"], snap,
+                              prune=(("g", "<", 3),))
+        want = [w + x for w, x in zip(want, db.store.last_prune)]
+    assert want[1] >= 4
+    assert tuple(r.stats["zone_prune"]["sp_a"]) == tuple(want)
+
+
+def test_count_star_stages_through_one_unit_a_segment(db):
+    q = "select count(*) from sp_b"
+    r, tr = cold(db, q)
+    assert r.rows() == [(2 * N,)]
+    spans = tr.export()
+    reads = by_name(spans, "read:sp_b")
+    # the planner keeps one narrow column for a count(*)
+    assert sorted((s["args"]["segment"], s["args"]["files"])
+                  for s in reads) == [(seg, 1) for seg in range(4)]
+    assert len({s["args"]["column"] for s in reads}) == 1
+    table = by_name(spans, "stage:sp_b")[0]
+    assert table["args"]["rows"] == 2 * N and table["args"]["read_units"] == 4
+    kids = [s for s in spans if s["parent"] == table["id"]]
+    assert len(by_name(kids, "wait")) == 1 and len(by_name(kids, "put")) == 2
+    # and a spec with NO storage column still has a unit a segment, which
+    # reads no file and carries the segment's row count
+    snap = db.store.manifest.snapshot()
+    got = [db.executor._read_unit("sp_b", None, seg, [], snap, None, None)
+           for seg in range(4)]
+    assert all(c == {} and v == {} for c, v, _n, _p in got)
+    assert sum(n for _c, _v, n, _p in got) == 2 * N
+
+
+def test_virtual_columns_of_one_raw_column_share_a_unit(db):
+    from greengage_tpu.exec.staging import column_units
+
+    assert column_units(["a", "b"]) == [["a"], ["b"]]
+    assert column_units([]) == [[]]
+    hp = "@hp:s:7b7d"
+    assert column_units(["@rl:s", "@rp:s:0", "@rp:s:1", "k", hp, "@rw:t:2",
+                         "s", "@rc:t"]) == [
+        ["@rl:s", "@rp:s:0", "@rp:s:1", hp, "s"], ["k"], ["@rw:t:2", "@rc:t"]]
+    db.sql("create table sp_raw (k int, s text) distributed by (k)")
+    object.__setattr__(db.catalog.get("sp_raw").column("s"), "encoding", "raw")
+    strs = np.array([f"row-{i:05d}" for i in range(2000)], dtype=object)
+    db.load_table("sp_raw", {"k": np.arange(2000), "s": strs})
+    q = "select k from sp_raw where s = 'row-00007'"
+    r, tr = cold(db, q)
+    assert r.rows() == [(7,)]
+    reads = by_name(tr.export(), "read:sp_raw")
+    by_seg = {}
+    for s in reads:
+        by_seg.setdefault(s["args"]["segment"], []).append(s["args"]["column"])
+    assert sorted(by_seg) == [0, 1, 2, 3]
+    for units in by_seg.values():
+        # k alone; every '@rp:s:<w>' lane and '@rl:s' in ONE unit
+        raw = [u for u in units if u != "k"]
+        assert sorted(units) == sorted(raw + ["k"]) and len(raw) == 1
+        cols = raw[0].split(",")
+        assert "@rl:s" in cols and any(c.startswith("@rp:s:") for c in cols)
+    assert r.stats["stage_read_units"] == 8
 
 
 def test_leaves_account_for_the_table_stage_span(db):
@@ -87,7 +219,8 @@ def test_leaves_account_for_the_table_stage_span(db):
     assert table["args"]["kind"] == "read"
     kids = [s for s in spans if s["parent"] == table["id"]]
     assert {s["name"] for s in kids} == set(LEAVES)
-    assert len(by_name(kids, "wait")) == 1
+    # one `wait` a column unit (g, v): the first hands the units over
+    assert len(by_name(kids, "wait")) == 2
     # g, v and the `present` column: one assemble and one put each, then
     # the assemble that lets go of the table's host copies
     assert len(by_name(kids, "put")) == 3
@@ -165,10 +298,17 @@ def test_inline_pool_keeps_the_split_exhaustive(db):
     reads, wait = by_name(spans, "read:sp_a"), by_name(spans, "wait")[0]
     # the units ran on the statement thread, inside `wait`, and still hang
     # under `stage`
-    assert len(reads) == 4 and all(s["tid"] == wait["tid"] for s in reads)
+    assert len(reads) == 8 and all(s["tid"] == wait["tid"] for s in reads)
     assert all(s["parent"] == by_name(spans, "stage")[0]["id"] for s in reads)
+    # all eight inside the FIRST `wait`, where the units are handed over
     assert sum(s["dur"] for s in reads) <= wait["dur"] + 0.01
+    assert all(wait["ts"] <= s["ts"] and s["ts"] + s["dur"]
+               <= wait["ts"] + wait["dur"] + 0.01 for s in reads)
     assert r.stats["read_bytes"] == sum(s["args"]["bytes_read"] for s in reads)
+    assert r.stats["stage_read_units"] == 8
+    st = r.stats
+    split = st["stage_wait_ms"] + st["stage_assemble_ms"] + st["stage_put_ms"]
+    assert st["stage_ms"] - split <= max(0.02 * st["stage_ms"], 1.0)
 
 
 def test_concurrent_statements_do_not_share_read_accounts(db):

@@ -132,10 +132,13 @@ def test_per_statement_scan_io_stats_and_explain(db):
     s = r.stats
     assert s["scan_io"]["scan_files_read"] == _data_files(db, "t", {"v", "w"})
     assert s["stage_ms"] >= 0 and s["compute_ms"] >= 0 and s["fetch_ms"] >= 0
+    # one read unit a (segment, column): 8 segments x {v, w}, a file each
+    # where the segment holds rows
+    assert s["stage_read_units"] == 16 >= s["scan_io"]["scan_files_read"]
     db.executor._stage_cache.clear()
     db.store.blockcache.clear()
     plan = db.sql("explain analyze select sum(v) from t").plan_text
-    assert "Host data path: staging" in plan
+    assert "Host data path: staging" in plan and "(8 read units)" in plan
     assert "Scan I/O:" in plan and "files read" in plan
 
 
@@ -145,7 +148,10 @@ def test_scan_threads_guc_serial_matches_parallel(db):
         db.sql(f"set scan_threads = {n}")
         db.executor._stage_cache.clear()
         db.store.blockcache.clear()
-        assert sorted(db.sql("select k, v from t").rows()) == want
+        r = db.sql("select k, v from t")
+        assert sorted(r.rows()) == want
+        # the split into (segment, column) units does not follow the pool
+        assert r.stats["stage_read_units"] == 16
     assert str(db.settings.show("scan_threads")) == "0"
 
 
