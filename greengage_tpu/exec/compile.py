@@ -100,6 +100,8 @@ class CompileResult:
     # overflow flag -> (plan node id, metric name): lets the executor size
     # the retry capacity from the exact cardinality the device reported
     flag_caps: dict = field(default_factory=dict)
+    # agg_groups metric -> the out_cap its sort-based aggregate was given
+    agg_caps: dict = field(default_factory=dict)
     est_bytes: int = 0                 # rough per-segment device allocation
     node_rows: dict = field(default_factory=dict)  # metric -> plan node id
     flag_packs: dict = field(default_factory=dict)  # pack flag -> plan nid
@@ -160,6 +162,7 @@ class Compiler:
         self.flags: list[str] = []
         self.metrics: list[str] = []
         self.flag_caps: dict = {}
+        self.agg_caps: dict = {}           # agg_groups metric -> out_cap
         # key packing from ANALYZE bounds: a bounds violation (stale stats)
         # re-runs the SAME tier with that node's packing disabled
         self.pack_disabled = pack_disabled or set()
@@ -477,6 +480,7 @@ class Compiler:
             else self._capacity_of(below),
             metric_names=metric_names,
             flag_caps=dict(self.flag_caps),
+            agg_caps=dict(self.agg_caps),
             # a batched program holds ~one member's intermediates PER
             # member (vmap), while the staged scan args are shared; charge
             # the conservative width multiple — admission over-refusing a
@@ -572,6 +576,10 @@ class Compiler:
             if isinstance(p, Join) and getattr(p, "multi", False) \
                     and p.kind in ("semi", "anti"):
                 extra.append(self._join_multi_expand_cap(p))
+            if isinstance(p, (Join, Aggregate)):
+                # the compaction of a build side or a sort-aggregate's
+                # input follows estimates the fields above leave out
+                extra.append(self._compact_k(p.children[-1]))
             nodes.append((type(p).__name__,
                           p.locus.kind.name if p.locus is not None else None,
                           cap, tuple(extra), tuple(fields)))
@@ -622,6 +630,8 @@ class Compiler:
                 cap = 0
             width = sum(max(c.type.np_dtype.itemsize, 1) + 1 for c in p.out_cols())
             node_bytes = cap * width
+            if isinstance(p, Motion) and self._motion_is_identity(p):
+                node_bytes = 0   # its child's batch, not a copy of it
             if isinstance(p, Window) \
                     and getattr(p, "global_mode", False) in ("ordered",
                                                              "range"):
@@ -780,19 +790,25 @@ class Compiler:
             # sort-based path: output capacity = estimated group count with
             # slack; can never exceed the child batch (groups <= rows), and
             # an exact-count retry tightens it after overflow
-            child_cap = self._capacity_of(plan.child)
+            child_cap = self._compact_k(plan.child) \
+                or self._capacity_of(plan.child)
             if self._nid(plan) in self.cap_overrides:
                 return min(_pow2(max(int(self.cap_overrides[self._nid(plan)]),
                                      64)),
                            child_cap)
+            # at least AGG_MIN_SLOTS: a small table costs nothing, and an
+            # estimate that moves below it keeps its program
             est = int(max(plan.est_rows, 16.0) * 1.3) + 64
-            return min(_pow2(est) * (4 ** self.tier), child_cap)
+            return min(max(_pow2(est), self.AGG_MIN_SLOTS) * (4 ** self.tier),
+                       child_cap)
         if isinstance(plan, PartialState):
             return self._capacity_of(plan.child)
         if isinstance(plan, Union):
             return sum(self._capacity_of(c) for c in plan.inputs)
         if isinstance(plan, Motion):
             child_cap = self._capacity_of(plan.child)
+            if self._motion_is_identity(plan):
+                return child_cap
             if plan.kind is MotionKind.BROADCAST:
                 return child_cap * self.nseg
             if plan.kind is MotionKind.REDISTRIBUTE:
@@ -800,10 +816,73 @@ class Compiler:
             return child_cap
         raise NotImplementedError(type(plan).__name__)
 
+    def _motion_is_identity(self, plan: Motion) -> bool:
+        """On one segment a Redistribute or a Broadcast has nowhere to send
+        a row: every row is already where it would go, so the Motion is its
+        child's batch (no hash, no bucketize scatter, no exchange with
+        itself). The plan keeps the node; only the program loses it."""
+        return self.nseg == 1 and plan.kind in (MotionKind.REDISTRIBUTE,
+                                                MotionKind.BROADCAST)
+
     def _motion_bucket(self, child_cap: int) -> int:
         c = int(child_cap * self.s.motion_capacity_slack / self.nseg) + 64
         c = _pow2(c) * (4 ** self.tier)
         return min(c, child_cap)
+
+    # a batch keeps its producer's capacity however few rows survive, and a
+    # sort or a scatter build pays for every slot of it. Where the planner
+    # expects the live rows to fit 1/COMPACT_RATIO of the slots twice over,
+    # the consumer first gathers them into a batch of that size
+    # (sort_ops.compact: a cumsum and a binary search, no sort). The size
+    # follows the capacity, not the estimate: an estimate that the feedback
+    # store corrects a hundredfold must not ask for another program (on the
+    # TPU, another compile of many minutes).
+    COMPACT_RATIO = 32
+    AGG_MIN_SLOTS = 4096
+
+    def _compact_k(self, node: Plan) -> int | None:
+        """Slots to compact ``node``'s output into before a consumer that
+        pays per capacity row, or None where it stays as it is."""
+        k = _pow2(self._capacity_of(node)) // self.COMPACT_RATIO
+        need = self.cap_overrides.get(-1 - self._nid(node))
+        if need is None:
+            need = getattr(node, "est_rows", None)
+            if not need:
+                return None
+            if node.locus is not None and node.locus.is_partitioned \
+                    and self.nseg > 1:
+                need /= self.nseg
+            need = need * 2 + 64
+        # (an override is the exact live count of a run that overflowed: if
+        # that no longer fits, the batch is not worth compacting)
+        return k if need <= k else None
+
+    def _compacted(self, node: Plan, fn):
+        """-> (fn or fn followed by the compaction, its output capacity).
+        More live rows than slots raises a flag, and the exact count sizes
+        the retry (cap_overrides under -1 - the node's ordinal: the
+        ordinal itself may already size the node's own output)."""
+        k = self._compact_k(node)
+        if k is None:
+            return fn, self._capacity_of(node)
+        fid = f"compact_overflow_{len(self.flags)}"
+        self.flags.append(fid)
+        mid = f"compact_live_{len(self.metrics)}"
+        self.metrics.append(mid)
+        self.flag_caps[fid] = (-1 - self._nid(node), mid)
+
+        def run(ctx):
+            b = fn(ctx)
+            live = b.selection()
+            with jax.named_scope("compact"):
+                cols, valids, sel = sort_ops.compact(b.cols, b.valids, live, k)
+                total = jnp.sum(live.astype(jnp.int32))
+                ctx["flags"].append((fid, total > k))
+                ctx["metrics"].append((mid, total))
+            return Batch(cols, {n: v for n, v in valids.items()
+                                if v is not None}, sel)
+
+        return run, k
 
     def _dense_domains(self, plan: Aggregate) -> list[int] | None:
         """Per-key dense domains (|dict|+1 / bool 3) when every group key has
@@ -841,8 +920,24 @@ class Compiler:
     # ------------------------------------------------------------------
     # node compilation (returns closures ctx -> Batch)
     # ------------------------------------------------------------------
+    def _scope_name(self, plan: Plan) -> str:
+        """The plan node's name in the device trace: every operation its
+        function emits carries it (jax.named_scope), innermost last."""
+        if isinstance(plan, Join):
+            return "semi" if plan.kind in ("semi", "anti") else "join"
+        if isinstance(plan, Aggregate):
+            dense = not plan.group_keys or self._dense_domains(plan) is not None
+            return "agg-dense" if dense else "agg-sort"
+        return type(plan).__name__.lower()
+
     def _compile_node(self, plan: Plan):
-        fn = getattr(self, "_c_" + type(plan).__name__.lower())(plan)
+        inner = getattr(self, "_c_" + type(plan).__name__.lower())(plan)
+        scope = self._scope_name(plan)
+
+        def fn(ctx):
+            with jax.named_scope(scope):
+                return inner(ctx)
+
         if not self.instrument:
             # always-on row counters on Filter outputs: selectivity is the
             # estimate the planner gets most wrong, and one jnp.sum per
@@ -977,8 +1072,8 @@ class Compiler:
         if getattr(plan, "multi", False):
             return self._c_join_multi(plan)
         left_fn = self._compile_node(plan.left)
-        right_fn = self._compile_node(plan.right)
-        build_cap = self._capacity_of(plan.right)
+        right_fn, build_cap = self._compacted(
+            plan.right, self._compile_node(plan.right))
         M = self._join_table_size(build_cap)
         probes = self._join_probes()
         lkeys, rkeys = plan.left_keys, plan.right_keys
@@ -1236,16 +1331,21 @@ class Compiler:
                 M *= dom
         else:
             M = 1
-        child_cap = self._capacity_of(plan.child) if use_sort else None
-        out_cap = self._capacity_of(plan) if use_sort else None
-        fid = mid = None
-        if use_sort and out_cap < child_cap:
-            # output capacity below the theoretical max: group count can
-            # overflow it; the device reports the exact count for the retry
-            fid = f"agg_overflow_{len(self.flags)}"
-            self.flags.append(fid)
+        child_cap = out_cap = fid = mid = None
+        if use_sort:
+            child_fn, child_cap = self._compacted(plan.child, child_fn)
+            out_cap = self._capacity_of(plan)
+            # every sort-based aggregate reports its exact group count: the
+            # agg_sort_groups / agg_sort_capacity counters read it beside
+            # the out_cap it was given (how full the group table ran)
             mid = f"agg_groups_{len(self.metrics)}"
             self.metrics.append(mid)
+            self.agg_caps[mid] = out_cap
+        if use_sort and out_cap < child_cap:
+            # output capacity below the theoretical max: group count can
+            # overflow it; the device's exact count sizes the retry
+            fid = f"agg_overflow_{len(self.flags)}"
+            self.flags.append(fid)
             self.flag_caps[fid] = (self._nid(plan), mid)
         keys = plan.group_keys
         aggs = plan.aggs
@@ -1253,13 +1353,17 @@ class Compiler:
         # packed single-operand group sort from ANALYZE key bounds
         key_bounds = getattr(plan, "key_bounds", None)
         fid_pack = None
-        if (use_sort and key_bounds is not None
-                and self._nid(plan) not in self.pack_disabled
-                and agg_ops.pack_bits(key_bounds) is not None):
+        if key_bounds is None or agg_ops.pack_bits(key_bounds) is None:
+            key_bounds = None
+        # keys that do not pack into one word sort by a hash word of them
+        # (ops/agg.group_sort); like a bound that no longer holds, a hash
+        # shared by two keys re-runs this node with neither
+        reduced = use_sort and self._nid(plan) not in self.pack_disabled
+        if reduced:
             fid_pack = f"pack_overflow_{len(self.flags)}"
             self.flags.append(fid_pack)
             self.flag_packs[fid_pack] = self._nid(plan)
-        else:
+        if not reduced:
             key_bounds = None
 
         def run(ctx):
@@ -1282,9 +1386,11 @@ class Compiler:
                 # group table; slot g's keys gather from its first row
                 kspecs = self._key_specs(b, [e for _, e in keys])
                 perm, boundary, sel_sorted, pack_viol = agg_ops.group_sort(
-                    kspecs, sel, key_bounds)
+                    kspecs, sel, key_bounds, hashed=reduced)
                 if fid_pack is not None:
-                    ctx["flags"].append((fid_pack, pack_viol))
+                    ctx["flags"].append(
+                        (fid_pack, jnp.zeros((), bool) if pack_viol is None
+                         else pack_viol))
                 tkeys, tvalids = [], []
             else:
                 slots = jnp.where(sel, 0, 1)
@@ -1402,10 +1508,8 @@ class Compiler:
                 total = meta["total"]
                 used = jnp.arange(out_cap, dtype=jnp.int32) < total
                 if fid is not None:
-                    # overflow reports the exact group count so the retry
-                    # sizes itself right
                     ctx["flags"].append((fid, total > out_cap))
-                    ctx["metrics"].append((mid, total.astype(jnp.int64)))
+                ctx["metrics"].append((mid, total.astype(jnp.int64)))
             return Batch(cols, valids, used)
 
         return run
@@ -1418,6 +1522,8 @@ class Compiler:
         child_fn = self._compile_node(plan.child)
         if plan.kind is MotionKind.GATHER:
             raise AssertionError("nested gather")
+        if self._motion_is_identity(plan):
+            return child_fn
         nseg = self.nseg
         if plan.kind is MotionKind.BROADCAST:
             def run(ctx):

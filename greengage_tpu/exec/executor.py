@@ -30,7 +30,7 @@ from greengage_tpu.exec import staging
 from greengage_tpu.runtime import lockdebug
 from greengage_tpu.exec.compile import (VALID_PREFIX, Compiler, CompileResult,
                                         _pow2)
-from greengage_tpu.parallel.mesh import seg_sharding
+from greengage_tpu.parallel.mesh import replicated_sharding, seg_sharding
 from greengage_tpu.planner.locus import LocusKind
 from greengage_tpu.runtime import interrupt
 from greengage_tpu.runtime import memaccount
@@ -493,6 +493,15 @@ class Executor:
             # admit against ground truth on silicon
             admit_bytes, admit_measured = self._admission_bytes(
                 comp, cache_key)
+            if limit and admit_bytes > limit and not admit_measured \
+                    and self._measure_unstaged(comp):
+                # the ESTIMATE was about to refuse or spill a statement no
+                # one has measured: it sums every plan node's batch as if
+                # all were alive at once, and XLA knows better. Ask it
+                # (one compile, which the dispatch then reuses) and let
+                # the measurement decide.
+                admit_bytes, admit_measured = self._admission_bytes(
+                    comp, cache_key)
             if limit and admit_bytes > limit:
                 if deferred:
                     raise QueryError(
@@ -687,9 +696,16 @@ class Executor:
                     # cost (EXPLAIN ANALYZE "Plan cache" line, bench)
                     compile_ms += compute_ms
                     counters.inc("compile_ms", int(compile_ms))
+                for _mid, _cap in comp.agg_caps.items():
+                    # how full the sort-based aggregates' group tables ran
+                    counters.inc("agg_sort_groups", int(np.max(metrics[_mid])))
+                    counters.inc("agg_sort_capacity", int(_cap))
                 res.stats = {
                     "tiers_used": tier + 1,
                     "compiled": not was_cached,
+                    # 0: admitted whole; the spill paths overwrite it with
+                    # the passes they ran (_spill_fallback)
+                    "spill_passes": 0,
                     "compile_ms": round(compile_ms, 1),
                     # host-data-path breakdown of the SUCCESSFUL attempt
                     "stage_ms": round(stage_ms, 2),
@@ -764,6 +780,13 @@ class Executor:
             pack_over = [f for f in overflow if f.startswith("pack_overflow")]
             capacity_over = [f for f in overflow
                              if not f.startswith("pack_overflow")]
+            compact_over = [f for f in capacity_over
+                            if f.startswith("compact_overflow")]
+            if compact_over:
+                # a compaction that dropped rows starved everything above
+                # it: the counts those operators report are of a truncated
+                # batch. Widen the compactions alone and look again.
+                capacity_over = compact_over
             for fname in pack_over:
                 pack_disabled.add(comp.flag_packs[fname])
             for fname in capacity_over:
@@ -773,12 +796,14 @@ class Executor:
                     need = (int(metrics[metric].flat[0]) if self.multihost
                             else int(np.max(metrics[metric])))
                     cap_overrides[plan_id] = need + max(need // 16, 64)
-            # a gather-compaction overflow carries its exact live count in
+            # a compaction overflow (before the Gather, or of a build side
+            # or a sort-aggregate's input) carries its exact live count in
             # the cap override — re-run the SAME tier with just that slice
             # widened; bumping the tier would needlessly 4x every other
             # node and disable tier-0 direct joins (advisor r3)
             if [f for f in capacity_over
-                    if not f.startswith("gather_compact_overflow")]:
+                    if not f.startswith(("gather_compact_overflow",
+                                         "compact_overflow"))]:
                 tier += 1
             last_err = f"capacity overflow in {overflow} at tier {tier}"
         raise QueryError(f"query exceeded capacity tiers: {last_err}")
@@ -1065,6 +1090,37 @@ class Executor:
             if est_dev > 0:
                 counters.set("mem_est_error_pct", int(round(
                     100.0 * (total - est_dev) / est_dev)))
+
+    def _measure_unstaged(self, comp: CompileResult) -> bool:
+        """Compile ``comp`` from the shapes of its inputs alone, so that
+        admission can read XLA's memory analysis before anything is staged.
+        Only where a measurement could govern (``_admission_bytes``: one
+        host, a backend with a real allocator) and only for a program of
+        base-table scans. -> whether ``comp.mem_analysis`` now holds one."""
+        if comp.mem_analysis is not None:
+            return True
+        if self.multihost is not None or comp.batch_width \
+                or memaccount.device_memory_stats() is None \
+                or any(t in getattr(self, "_aux_tables", {})
+                       for t, *_ in comp.input_spec):
+            return False
+        shard = seg_sharding(self.mesh)
+        shapes = []
+        for table, cols, cap, *_ in comp.input_spec:
+            schema = self.catalog.get(table)
+            for c in cols:
+                dt = (np.dtype(bool) if c.startswith(VALID_PREFIX)
+                      else self._stage_dtype(schema, c))
+                shapes.append(jax.ShapeDtypeStruct((self.nseg * cap,), dt,
+                                                   sharding=shard))
+            shapes.append(jax.ShapeDtypeStruct((self.nseg * cap,), bool,
+                                               sharding=shard))
+        shapes += [jax.ShapeDtypeStruct((1,), dt,
+                                        sharding=replicated_sharding(self.mesh))
+                   for dt in comp.param_dtypes]
+        with _trace.span("compile", cat="plan", unstaged=True):
+            self._ensure_mem_analysis(comp, shapes)
+        return comp.mem_analysis is not None
 
     def _admission_bytes(self, comp: CompileResult,
                          cache_key=None) -> tuple[int, bool]:

@@ -95,13 +95,15 @@ def _masked_reduce(op, vals, gid, D, mask, ident):
     return op(filled, axis=0)
 
 
-def _run_aggs(aggs: list[AggSpec], sel, seg_sum, seg_minmax):
+def _run_aggs(aggs: list[AggSpec], sel, seg_sum, seg_minmax, seg_count=None):
     """The per-function aggregate semantics, shared by every grouping
     regime. The reduce primitives are injected:
 
       seg_sum(masked_vals) -> per-group sums (inputs pre-masked to 0)
       seg_minmax(filled_vals, func, ident) -> per-group min/max
         (inputs pre-filled with the identity at dead/NULL rows)
+      seg_count(live_mask) -> per-group int64 row counts, where a regime
+        counts cheaper than it sums (default: seg_sum of the mask as int64)
 
     Semantics kept in ONE place: count(*)/count ignore NULLs per column;
     sum of no rows is NULL; avg = float64 sum/count descaled by the decimal
@@ -115,7 +117,8 @@ def _run_aggs(aggs: list[AggSpec], sel, seg_sum, seg_minmax):
         key = None if spec is None or spec.valid is None else id(spec.valid)
         if key not in counts_cache:
             lv = sel if spec is None or spec.valid is None else (sel & spec.valid)
-            counts_cache[key] = seg_sum(lv.astype(jnp.int64))
+            counts_cache[key] = (seg_sum(lv.astype(jnp.int64))
+                                 if seg_count is None else seg_count(lv))
         return counts_cache[key]
 
     group_count = live_count(None)
@@ -263,22 +266,51 @@ def pack_keys(keys: list[KeySpec], bounds: list, sel):
     return word, violation
 
 
-def group_sort(keys: list[KeySpec], sel, bounds: list | None = None):
+def hash_keys(key_ops: list, sel):
+    """One uint64 word a row from its key encodings: two independent 32-bit
+    row hashes side by side in bits 0..62, the dead flag in bit 63. Equal
+    key tuples give equal words; different ones almost always different."""
+    from greengage_tpu.ops import hashing
+
+    halves = [hashing.row_hash([hashing.hash_i64(op, seed) for op in key_ops])
+              for seed in (0, 0x9E3779B9)]
+    word = ((halves[1].astype(jnp.uint64) << jnp.uint64(32))
+            | halves[0].astype(jnp.uint64)) >> jnp.uint64(1)
+    return jnp.where(sel, word, word | (jnp.uint64(1) << jnp.uint64(63)))
+
+
+def group_sort(keys: list[KeySpec], sel, bounds: list | None = None,
+               hashed: bool = False):
     """Sort rows by group keys, dead rows last.
 
     -> (perm int32[n], boundary bool[n], sel_sorted bool[n], violation):
     perm is the gather permutation (sorted_col = col[perm]); boundary marks
     the first (live) row of each equal-key run — the group's representative
     row. ``bounds`` (per-key (lo, hi) from ANALYZE) enables the packed
-    single-operand sort; violation is a bool scalar the caller must route
-    to an overflow flag (None when packing was not attempted).
+    single-operand sort. ``hashed`` sorts keys that do not pack by a
+    64-bit hash word instead of by every key: lax.sort costs per operand
+    on the device and far more than that in the TPU compiler (seven
+    operands of 2^20 rows: over a quarter of an hour, two: two minutes),
+    and grouping needs equal keys adjacent, not ordered. violation is a
+    bool scalar the caller must route to a flag that re-runs with neither
+    (None where the keys were sorted themselves): a live value outside its
+    bounds, or two different key tuples with one hash word.
     """
     from jax import lax
 
     n = sel.shape[0]
     violation = None
+    word = None
+    key_ops = []
     if bounds is not None and pack_bits(bounds) is not None:
         word, violation = pack_keys(keys, bounds, sel)
+    else:
+        for k in keys:
+            key_ops.extend(_group_encode(k))
+        if hashed and len(key_ops) > 1:
+            word = hash_keys(key_ops, sel)
+            violation = jnp.zeros((), bool)
+    if word is not None:
         sorted_ops = lax.sort(
             (word, jnp.arange(n, dtype=jnp.int32)), num_keys=2)
         wkey = sorted_ops[0]
@@ -287,14 +319,21 @@ def group_sort(keys: list[KeySpec], sel, bounds: list | None = None):
         if n > 1:
             first = jnp.concatenate(
                 [jnp.ones((1,), bool), wkey[1:] != wkey[:-1]])
+            if key_ops:
+                differs = first[1:]
+                # exact all the same: a run of one word must be a run of
+                # one key tuple (live rows sort first, so a live row's
+                # predecessor is live)
+                other = jnp.zeros((n - 1,), bool)
+                for op in key_ops:
+                    s = op[perm]
+                    other = other | (s[1:] != s[:-1])
+                violation = jnp.any(~differs & other & sel_sorted[1:])
         else:
             first = jnp.ones((n,), bool)
         return perm, sel_sorted & first, sel_sorted, violation
 
     dead = (~sel).astype(jnp.uint8)
-    key_ops = []
-    for k in keys:
-        key_ops.extend(_group_encode(k))
     operands = [dead] + key_ops + [jnp.arange(n, dtype=jnp.int32)]
     sorted_ops = lax.sort(tuple(operands), num_keys=len(operands))
     perm = sorted_ops[-1]
@@ -382,7 +421,14 @@ def sorted_group_aggregate(boundary, sel_sorted, aggs: list[AggSpec],
         tbl = tbl.at[tgt].min(filled) if func == "min" else tbl.at[tgt].max(filled)
         return tbl[:out_cap]
 
-    vals, valids = _run_aggs(aggs, sel_sorted, seg_sum, seg_minmax)
+    def seg_count(live):
+        # a count is below n < 2^31: one int32 prefix sum, exact. As an
+        # int64 sum it would split into limbs, and the TPU compiler spends
+        # tens of minutes on a prefix sum of (mask as int64) >> 32, which
+        # it knows to be zero (Q18's program did not compile for that)
+        return span(jnp.cumsum(live.astype(jnp.int32))).astype(jnp.int64)
+
+    vals, valids = _run_aggs(aggs, sel_sorted, seg_sum, seg_minmax, seg_count)
     return vals, valids, srcpos, total
 
 

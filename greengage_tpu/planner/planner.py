@@ -449,7 +449,7 @@ class Planner:
             est = max(left.est_rows * right.est_rows * sel, 1.0)
         node.est_rows = est if est is not None else max(left.est_rows, right.est_rows)
         if node.kind in ("semi", "anti"):
-            node.est_rows = left.est_rows * 0.5
+            node.est_rows = left.est_rows * self._semi_fraction(node, llook)
         # build-side duplicate keys force the CSR multi-match kernel for
         # inner/left (semi/anti only need existence, the plain table is
         # fine); the multi kernel handles per-match residual
@@ -482,6 +482,23 @@ class Planner:
         self._maybe_direct_join(node)
         self._maybe_dynamic_partition_prune(node)
         return node
+
+    def _semi_fraction(self, node: Join, llook) -> float:
+        """Share of the probe rows a semi/anti join keeps. An IN / EXISTS
+        semi-join with analyzed probe keys keeps the rows whose key is one
+        of the build's: at most build rows / distinct probe keys of them
+        (containment, as for an inner join). Anything else — anti joins,
+        residual correlations, keys without statistics — keeps the old
+        guess of a half."""
+        if node.kind != "semi" or node.residual is not None:
+            return 0.5
+        ndv = 1.0
+        for lk in node.left_keys:
+            cs = llook(lk.name) if isinstance(lk, E.ColRef) else None
+            if cs is None or cs.ndv <= 0:
+                return 0.5
+            ndv *= cs.ndv
+        return min(node.right.est_rows / ndv, 1.0)
 
     def _maybe_dynamic_partition_prune(self, node: Join) -> None:
         """Join-driven runtime partition elimination (the
@@ -653,13 +670,16 @@ class Planner:
         for e in key_exprs:
             b = None
             if isinstance(e, E.ColRef) and e.type.kind in (
-                    T.Kind.INT32, T.Kind.INT64, T.Kind.DATE):
+                    T.Kind.INT32, T.Kind.INT64, T.Kind.DATE, T.Kind.DECIMAL):
+                # (a DECIMAL is its scaled integer, in storage and in stats)
                 cs = lookup(e.name)
                 if cs is not None and cs.min is not None and cs.max is not None:
                     try:
                         b = (int(cs.min), int(cs.max))
                     except (TypeError, ValueError, OverflowError):
                         b = None
+                    if b is not None and e.type.kind is T.Kind.DECIMAL:
+                        b = _rounded_out(*b)
             out.append(b)
         return out
 
@@ -942,6 +962,16 @@ class Planner:
         m.locus = Locus.entry()
         m.est_rows = child.est_rows
         return m
+
+
+def _rounded_out(lo: int, hi: int) -> tuple[int, int]:
+    """(lo, hi) widened to a grid of a sixteenth of the span's power of two.
+    A measure's sampled minimum and maximum are the data's own (keys and
+    dates sit on their domain's ends), and the packed sorts compile their
+    bounds in as constants: rounded out, like data gives the same program
+    and the packed word at most one more bit."""
+    s = max((hi - lo).bit_length() - 4, 0)
+    return (lo >> s) << s, (((hi >> s) + 1) << s) - 1
 
 
 def _find_single_scan(plan: Plan, table: str):

@@ -115,6 +115,10 @@ COUNTER_NAMES = (
     # per-row host chain (@hp chain predicates, finalize-decode
     # projections) — the fused-coverage ratio docs/PERF.md tracks
     "scalar_device_total", "scalar_host_fallback_total",
+    # sort-based aggregates (exec/compile.py _c_aggregate): groups found,
+    # and the out_cap their group tables were compiled with, a statement —
+    # groups / capacity is how full the tables ran
+    "agg_sort_groups", "agg_sort_capacity",
     # overload armor (docs/ROBUSTNESS.md "Overload protection"):
     # connections accepted vs shed at the bounded front end
     # (runtime/server.py), oversized request frames rejected, statements

@@ -380,8 +380,9 @@ class Binder:
                 raise SqlError("subquery for IN must return one column")
             skey = _colref(subouts[0])
             lks, rks = self._align_join_keys([arg], [skey])
-            kind = "anti" if negate else "semi"
-            return Join(kind, plan, subplan, lks, rks, null_aware=negate)
+            if negate:
+                return Join("anti", plan, subplan, lks, rks, null_aware=True)
+            return _sink_semi(plan, Join("semi", plan, subplan, lks, rks))
 
         # EXISTS: correlation via equality predicates against the outer scope
         q = node.query
@@ -432,6 +433,8 @@ class Binder:
                 both = sub_scope.merged(scope)
                 res_pred = self._predicate(_join_and(residuals), both)
             joined = Join(kind, plan, subplan, lks, rks, residual=res_pred)
+            if kind == "semi" and res_pred is None:
+                joined = _sink_semi(plan, joined)
         else:
             # uncorrelated EXISTS: constant-key semi join (matched iff sub
             # produced any row; duplicate constant keys are fine)
@@ -2787,6 +2790,35 @@ def _sink_pred(plan, pred, refs: set):
                 plan.right = child if ok else _merge_filter(plan.right, pred)
                 return plan, True
     return plan, False
+
+
+def _sink_semi(plan, semi):
+    """Place a semi-join (IN / EXISTS, no residual) at the deepest input
+    of the inner and cross joins under it whose columns cover its probe
+    keys. A semi-join only removes rows of the side that owns its keys,
+    so it commutes with every inner join and filter above that side, and
+    the joins then see the rows it keeps instead of the whole table
+    (TPC-H Q18: orders semi-joined on o_orderkey before it meets customer
+    and lineitem). It stops above a base relation's own Filter: the
+    planner's scan pushdown reads the Filter directly over a Scan."""
+    refs = set()
+    for k in semi.left_keys:
+        refs |= _expr_col_ids(k)
+
+    def place(node):
+        inner = node
+        while isinstance(inner, Filter):
+            inner = inner.child
+        if refs and isinstance(inner, Join) and inner.kind in ("inner", "cross"):
+            for side in ("left", "right"):
+                sub = getattr(inner, side)
+                if refs <= {c.id for c in sub.out_cols()}:
+                    setattr(inner, side, place(sub))
+                    return node
+        semi.left = node
+        return semi
+
+    return place(plan)
 
 
 def _merge_filter(node, pred):
