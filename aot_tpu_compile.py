@@ -146,7 +146,7 @@ def main() -> int:
         with open(os.environ["AOT_KEEP_STABLEHLO"], "w") as f:
             f.write(text)
     out["stablehlo_ops"] = {
-        k: len(re.findall(r"stablehlo\." + k + r"\b", text))
+        k: len(re.findall(r"(?<!#)stablehlo\." + k + r"\b", text))
         for k in ("sort", "scatter", "all_to_all", "all_gather", "while",
                   "gather")}
     if not a.lower_only:

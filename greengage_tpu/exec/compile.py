@@ -1393,7 +1393,6 @@ class Compiler:
                          else pack_viol))
                 tkeys, tvalids = [], []
             else:
-                slots = jnp.where(sel, 0, 1)
                 used = jnp.ones((1,), dtype=bool)
                 tkeys, tvalids = [], []
 
@@ -1427,7 +1426,7 @@ class Compiler:
                         agg_ops.sorted_group_aggregate(
                             boundary, sel_sorted, ps, out_cap)
                     return vals, avalids
-                return agg_ops.aggregate(slots, Mx, specs, sel)
+                return agg_ops.scalar_aggregate(specs, sel)
 
             if phase in ("single", "partial"):
                 specs = []
