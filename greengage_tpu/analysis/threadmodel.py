@@ -65,7 +65,7 @@ THREAD_ROLES: dict[str, Role] = {
                  # server handler threads: admission, serve loop, drain
                  ("runtime/server.py", "Handler", "handle"),
                  # scan_threads=1 runs read units on the calling thread
-                 ("exec/executor.py", "Executor", "_read_unit")),
+                 ("exec/staging.py", "Stager", "_read_unit")),
     ),
     "server": Role(
         "server",
@@ -84,7 +84,7 @@ THREAD_ROLES: dict[str, Role] = {
         "PR-3 staging pool workers: concurrent (table, segment, column) "
         "read+decode units through the store's caches",
         spawns=(("exec/staging.py", "ThreadPoolExecutor"),),
-        entries=(("exec/executor.py", "Executor", "_read_unit"),),
+        entries=(("exec/staging.py", "Stager", "_read_unit"),),
     ),
     "spill-prefetch": Role(
         "spill-prefetch",
@@ -201,6 +201,10 @@ def role_of_thread_name(name: str) -> str:
 # puts its whole attribute surface under cross-role analysis.
 SHARED_CLASSES: dict[str, str] = {
     "Executor":          "one per Database; statement + serving pipeline",
+    "ProgramCache":      "the executor's program LRU, signature memo and "
+                         "capacity hints",
+    "Stager":            "the executor's staging: stage cache + "
+                         "dynamic-prune memo",
     "BatchServer":       "admission windows + pipeline queue",
     "CacheRegistry":     "global block-cache byte budget",
     "BlockCache":        "named member caches of the registry",
@@ -236,10 +240,10 @@ SHARED_CLASSES: dict[str, str] = {
 
 # Attribute name -> class name: receiver typing the race walk cannot
 # infer from constructor assignments (factory returns). Lets generic
-# method calls (`self._stage_cache.get(...)`) resolve into the shared
+# method calls (`self.stage_cache.get(...)`) resolve into the shared
 # class's methods instead of going dark.
 RECEIVER_TYPES: dict[str, str] = {
-    "_stage_cache": "BlockCache",
+    "stage_cache": "BlockCache",
     "blockcache": "CacheRegistry",
     # TableStore's named member caches (storage/table_store.py __init__,
     # all created by CacheRegistry.cache())

@@ -52,14 +52,6 @@ class Settings:
     # boundary). 0 disables cross-statement enforcement.
     vmem_global_limit_mb: int = 0
     runaway_red_zone: float = 0.9
-    # measured memory accounting (runtime/memaccount.py, the
-    # vmem_tracker/memaccounting.c analog): attach XLA memory_analysis to
-    # every cached executable, keep the per-statement owner tree, sample
-    # device watermarks at span boundaries, and let admission + the
-    # runaway cleaner prefer MEASURED executable bytes over the planner
-    # estimate once the executable is warm (only when the backend reports
-    # real temps — CPU reports none, so estimates keep governing there)
-    mem_accounting_enabled: bool = True
     # feedback-driven cost calibration (planner/feedback.py): reconcile
     # per-node actual rows + measured executable bytes against planner
     # estimates after every execution, and apply the learned per-digest

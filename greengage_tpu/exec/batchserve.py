@@ -52,7 +52,13 @@ import threading
 import time
 from collections import OrderedDict, deque
 
-from greengage_tpu.exec.executor import BatchFallback
+import numpy as np
+
+from greengage_tpu.exec.compile import CompileResult, _pow2
+from greengage_tpu.exec.executor import Result, effective_limit_bytes
+from greengage_tpu.exec.programs import Unsignable
+from greengage_tpu.runtime import trace as _trace
+from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.interrupt import REGISTRY as _INTERRUPTS
 from greengage_tpu.runtime.logger import counters, histograms
 from greengage_tpu.runtime.trace import TRACES, Trace
@@ -63,6 +69,117 @@ WIDTH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 # hard ceiling on a member's wait for its flush — a stalled pipeline must
 # degrade to serial execution, never to a hung client connection
 _STALL_TIMEOUT_S = 600.0
+
+
+class BatchFallback(Exception):
+    """A batched-serving window cannot run as one program (admission
+    ceiling, overflow flags, unsignable shape): every member re-runs
+    serially through the classic path, which owns retries and spill.
+    Never surfaces to a client — it only routes execution."""
+
+
+# ---- one batch through the executor's four steps ---------------------
+# One XLA dispatch serves a whole admission window of same-shape
+# statements: their hoisted parameter vectors stack along a leading
+# member axis and the width-bucketed batched program (compile.py
+# batch_width) runs once over the shared staged inputs. Split into
+# prepare (find or compile, admit, stage) and Executor.dispatch so the
+# pipeline can stage batch k+1 while batch k runs on device.
+
+def prepare_batch(ex, plan, consts, cache_key, pvec_rows):
+    """-> (comp, inputs, snapshot, compiled: bool) of the width-bucketed
+    batched program. Raises BatchFallback when the batch cannot run as
+    one program (admission ceiling, unsignable shape)."""
+    width = len(pvec_rows)
+    bucket = _pow2(max(width, 1))
+    snapshot = ex.store.manifest.snapshot()
+    try:
+        # no persisted hints here, as there never were (ROADMAP D7)
+        comp, was_cached, compile_ms = ex.programs.find_or_compile(
+            cache_key, plan, consts, snapshot, 0,
+            ex.programs.hints(cache_key), batch_width=bucket)
+    except Unsignable:
+        raise BatchFallback("unsignable statement shape") from None
+    if not was_cached:
+        counters.inc("compile_ms", int(compile_ms))
+    # admission: est_bytes is already width-scaled (compile.py); the
+    # measured footprint of a warm bucket takes over once the AOT
+    # analysis ran — ground truth bounding the batch width
+    limit = effective_limit_bytes(ex.settings)
+    if cache_key is not None:
+        # width-bucket-qualified feedback key: est/measured bytes are
+        # width-scaled, so each bucket calibrates independently
+        comp.fb_key = f"{cache_key}@w{bucket}"
+    admit_bytes, _measured = ex.admission_bytes(comp, comp.fb_key)
+    if limit and admit_bytes > limit:
+        raise BatchFallback(
+            f"batched program would hold ~{admit_bytes >> 20} MB "
+            f"per segment at width {bucket}, above the "
+            f"{limit >> 20} MB ceiling")
+    # staged like a classic statement except that parameter-valued prune
+    # predicates are DROPPED (pvec None): zone-map pruning by one
+    # member's values would starve its batch-mates of blocks their rows
+    # live in. Value-pinned prune predicates are shared by every member
+    # and stay active.
+    padded = list(pvec_rows) + [pvec_rows[-1]] * (bucket - width)
+    staged = ex.stager.stage(comp, snapshot, None, [
+        np.asarray([[pv.values[slot]] for pv in padded], dtype=dt)
+        for slot, dt in enumerate(comp.param_dtypes)])
+    _trace.annotate(staged.sid, batch_width=width, batch_bucket=bucket)
+    return comp, staged.inputs, snapshot, not was_cached
+
+
+def dispatch_batch(ex, comp: CompileResult, inputs) -> list:
+    """Run a prepared batched program and fetch every output to host.
+    The serving pipeline's device stage — runs on the dispatcher thread
+    with NO statement context, so a member's cancellation can never
+    abort its batch-mates (members are masked at demux)."""
+    return ex.dispatch(comp, inputs, comp.fb_key,
+                       lambda _comp: faults.check("batch_dispatch"),
+                       batch_width=comp.batch_width)[0]
+
+
+def batch_overflowed(comp: CompileResult, flat) -> list[str]:
+    """Flag names any member tripped — capacity overflow, packing
+    bounds, duplicate join keys. A batched program never retries in
+    place (per-member capacity needs differ); any flag sends every
+    member down the serial path, whose tier machinery handles it."""
+    ncols_part = 2 * len(comp.out_cols) + 1
+    return [name for j, name in enumerate(comp.flag_names)
+            if np.asarray(flat[ncols_part + j]).any()]
+
+
+def demux_batch(ex, comp: CompileResult, flat, member: int,
+                snapshot) -> Result:
+    """One member's Result from a fetched batched output: slice its
+    row along the leading member axis and finalize exactly like a
+    classic dispatch (merge keys, host LIMIT, TEXT decode)."""
+    ncols_part = 2 * len(comp.out_cols) + 1
+    member_flat = [np.asarray(flat[i])[member] for i in range(ncols_part)]
+    with _trace.span("finalize", cat="host", member=member):
+        return ex.finalize(comp, member_flat, snapshot, raw=False)
+
+
+def run_batch(ex, plan, consts, cache_key, pvec_rows) -> list[Result]:
+    """Synchronous prepare+dispatch+demux of one batch (the gang
+    worker's and the tests' surface; the serving pipeline calls the
+    halves from its own stage/dispatch threads). Raises BatchFallback
+    when the batch must be served serially."""
+    comp, inputs, snapshot, compiled = prepare_batch(
+        ex, plan, consts, cache_key, pvec_rows)
+    flat = dispatch_batch(ex, comp, inputs)
+    over = batch_overflowed(comp, flat)
+    if over:
+        raise BatchFallback(f"overflow flags {over} at width "
+                            f"{len(pvec_rows)}")
+    out = []
+    for m in range(len(pvec_rows)):
+        res = demux_batch(ex, comp, flat, m, snapshot)
+        res.stats = {"batched": True, "batch_width": len(pvec_rows),
+                     "batch_bucket": comp.batch_width,
+                     "compiled": compiled, "segments": ex.nseg}
+        out.append(res)
+    return out
 
 
 class _Member:
@@ -372,8 +489,8 @@ class BatchServer:
                                       batch=b.bid, width=len(b.members))
                 TRACES.adopt(bt)
                 try:
-                    b.staged = self.db.executor.prepare_batch(
-                        b.plan, b.consts, b.outs, b.key,
+                    b.staged = prepare_batch(
+                        self.db.executor, b.plan, b.consts, b.key,
                         [m.pvec for m in b.members])
                 except BaseException as e:
                     b.staged = None
@@ -428,10 +545,10 @@ class BatchServer:
                 # BatchFallback so members re-run via the classic
                 # per-statement dispatch, which owns failover
                 with mh_cm:
-                    flat = ex.dispatch_batch(comp, inputs)
+                    flat = dispatch_batch(ex, comp, inputs)
             else:
-                flat = ex.dispatch_batch(comp, inputs)
-            over = ex.batch_overflowed(comp, flat)
+                flat = dispatch_batch(ex, comp, inputs)
+            over = batch_overflowed(comp, flat)
             if over:
                 # per-member capacity needs differ (value-dependent join
                 # expansion / group counts): the serial path's tier
@@ -456,7 +573,7 @@ class BatchServer:
                         m.masked = True
                         continue
                     try:
-                        res = ex.demux_batch(comp, flat, i, snapshot)
+                        res = demux_batch(ex, comp, flat, i, snapshot)
                     except Exception:
                         m.fallback = True   # lone demux hiccup: serial
                         continue

@@ -348,10 +348,7 @@ class Database:
         # per-statement memory account (runtime/memaccount.py, the
         # memaccounting.c owner tree): staging/block-cache/spill/device
         # charges land here; dumped on OOM, served by `gg mem`
-        acct, a_outer = _memaccount.ACCOUNTS.enter(
-            ctx.statement_id, text,
-            enabled=bool(getattr(self.settings,
-                                 "mem_accounting_enabled", True)))
+        acct, a_outer = _memaccount.ACCOUNTS.enter(ctx.statement_id, text)
         t0 = time.monotonic()
         root = (tr.begin("statement", cat="statement")
                 if tr is not None and t_outer else None)
@@ -1152,12 +1149,12 @@ class Database:
                             # arm spill-schedule recording: the workers
                             # ship theirs in the completion acks and the
                             # parity check below asserts lockstep
-                            self.executor.begin_spill_schedule()
+                            self.executor.spill_schedule.begin()
                             _sched = None
                             try:
                                 out = self._execute(stmt)
                                 _sched = \
-                                    self.executor.collect_spill_schedule()
+                                    self.executor.spill_schedule.collect()
                             finally:
                                 try:
                                     _acks = ch.collect_acks(
@@ -1326,7 +1323,7 @@ class Database:
         belong to the statement role. A dead peer raises WorkerDied again
         on the first serial re-run's own broadcast, where _coordinator_sql
         re-forms the gang on a statement thread."""
-        from greengage_tpu.exec.executor import BatchFallback
+        from greengage_tpu.exec.batchserve import BatchFallback
         from greengage_tpu.parallel.multihost import WorkerDied
 
         ch = self.multihost.channel
@@ -1374,7 +1371,7 @@ class Database:
         broadcast window (same plan cache, same literal hoisting), stack
         their parameter vectors, and run the SAME width-bucketed batched
         program the coordinator is dispatching concurrently."""
-        from greengage_tpu.exec.executor import BatchFallback
+        from greengage_tpu.exec.batchserve import BatchFallback, run_batch
 
         planned = consts = outs = ek = None
         pvecs = []
@@ -1390,7 +1387,7 @@ class Database:
                 # member's bound plan, mirroring the coordinator's window
                 planned, consts, outs, ek = p, c, o, k
             pvecs.append(pv)
-        self.executor.run_batch(planned, consts, outs, ek, pvecs)
+        run_batch(self.executor, planned, consts, ek, pvecs)
 
     def _mh_spill_parity(self, mine: list, acks) -> None:
         """Lockstep assertion for tiered-spill schedules: every worker
@@ -1604,7 +1601,7 @@ class Database:
                 self.store._invalidate_dicts(stmt.name)
                 # compiled programs scanning this table must not survive a
                 # same-named recreate (the shape signature could coincide)
-                self.executor.invalidate_table(stmt.name)
+                self.executor.programs.invalidate_table(stmt.name)
 
                 for st in storage:
                     shutil.rmtree(os.path.join(self.path, "data", st),
@@ -1717,7 +1714,7 @@ class Database:
         self._select_cache.clear()
         # staged-input cache entries predate the index (same manifest
         # version): drop them so the next scan actually prunes
-        getattr(self.executor, "_stage_cache", {}).clear()
+        self.executor.stager.stage_cache.clear()
         return "CREATE INDEX"
 
     def _build_index_sidecars(self, schema) -> None:
@@ -3028,7 +3025,7 @@ class Database:
         shutil.rmtree(os.path.join(self.path, "data", child),
                       ignore_errors=True)
         self._select_cache.clear()
-        self.executor.invalidate_table(stmt.table)
+        self.executor.programs.invalidate_table(stmt.table)
         self._post_commit()
         return "ALTER TABLE"
 

@@ -26,6 +26,7 @@ rejecting it at the vmem admission check.
 from __future__ import annotations
 import copy
 import itertools
+import threading
 
 import numpy as np
 
@@ -44,6 +45,48 @@ from greengage_tpu.planner.logical import (Aggregate, ColInfo, Filter, Join,
 
 class NotSpillable(ValueError):
     """The plan's shape cannot be pass-partitioned soundly."""
+
+
+class ScheduleRecorder:
+    """Multihost spill-schedule parity (docs/PERF.md "Data movement").
+    The tiered workfile's pass/bucket schedules are pure functions of
+    compiled estimates + settings, so every gang member computes the same
+    one. This makes that a VERIFIED invariant instead of a hope: the
+    coordinator arms recording per statement (``begin``, on the
+    statement's thread), every schedule decision is noted (and broadcast
+    one-way to the workers for observability), workers ship the schedule
+    they actually ran in their completion ack, and the session compares.
+    Single-host runs never arm recording, so ``note`` is a no-op there."""
+
+    def __init__(self, multihost):
+        self.multihost = multihost
+        self._tls = threading.local()
+
+    def begin(self) -> None:
+        self._tls.steps = []
+
+    def note(self, kind: str, **info) -> None:
+        steps = getattr(self._tls, "steps", None)
+        if steps is None:
+            return
+        entry = {"kind": kind, **info}
+        steps.append(entry)
+        mh = self.multihost
+        if mh is not None and getattr(mh, "is_coordinator", False):
+            ch = getattr(mh, "channel", None)
+            if ch is not None:
+                try:
+                    # one-way frame (workers' serve loop drops unknown
+                    # ops): the schedule lands on every host's control
+                    # log even if the statement later dies
+                    ch.send({"op": "spill_schedule", **entry})
+                except Exception:
+                    pass   # observability must never fail the statement
+
+    def collect(self) -> list:
+        steps = getattr(self._tls, "steps", None)
+        self._tls.steps = None
+        return steps or []
 
 
 def partial_state_cols(partial: Aggregate) -> list:
@@ -325,9 +368,8 @@ def spill_run(executor, plan: Motion, consts, out_cols, raw: bool,
     chosen, per_table, npasses, comp = _size_chunk_passes(
         executor, consts, pass_plan, candidates, limit_bytes)
     # lockstep parity: the pass schedule every gang member must agree on
-    executor.note_spill_schedule(
-        "agg", passes=npasses,
-        chunks=[[t, c, n] for t, c, n in per_table])
+    executor.spill_schedule.note(
+        "agg", passes=npasses, chunks=[[t, c, n] for t, c, n in per_table])
 
     # run the passes, landing partial rows in the tiered workfile (host
     # RAM, overflowing to compressed disk segments — exec/workfile.py).
@@ -345,7 +387,7 @@ def spill_run(executor, plan: Motion, consts, out_cols, raw: bool,
     partial_cols = state_cols
     combos = list(itertools.product(*grids))
     prefetcher = _staging.PassPrefetcher(
-        executor, comp.input_spec, store.manifest.snapshot())
+        executor.stager, comp.input_spec, store.manifest.snapshot())
     wf = _workfile.SpillWorkfile(executor, partial_cols, "partials")
     try:
         try:
@@ -361,8 +403,7 @@ def spill_run(executor, plan: Motion, consts, out_cols, raw: bool,
                     wf.add(executor.run_single(
                         pass_plan, consts, partial_cols, raw=True,
                         scan_cap_override=caps,
-                        row_ranges=dict(combo), no_direct=True,
-                        instrument=instrument))
+                        row_ranges=dict(combo), instrument=instrument))
         finally:
             prefetcher.close()
         aux_cols, aux_valids = wf.assemble()
@@ -398,7 +439,7 @@ def spill_run(executor, plan: Motion, consts, out_cols, raw: bool,
                 res = executor.run_single(
                     merged, consts, out_cols, raw=raw,
                     aux_tables={aux_name: (aux_cols, aux_valids)},
-                    no_direct=True, instrument=instrument)
+                    instrument=instrument)
         except AdmissionError:
             if capture_agg.aggs:      # partial-state merges never regress
                 raise
@@ -528,7 +569,7 @@ def _bucketed_dedupe_merge(executor, merged, dedupe, host_scan, aux_name,
             "per-bucket dedupe working set still exceeds the limit at 64 "
             "merge buckets")
     bucket = h % np.uint32(K)
-    executor.note_spill_schedule("dedupe", buckets=K)
+    executor.spill_schedule.note("dedupe", buckets=K)
 
     # bucketed merge on the motion pipeline (exec/motionpipe.py): bucket
     # k+1's host subset build overlaps bucket k's device program
@@ -547,7 +588,7 @@ def _bucketed_dedupe_merge(executor, merged, dedupe, host_scan, aux_name,
         sub_cols, sub_valids = staged
         return executor.run_single(
             bucket_plan, consts, state_cols, raw=True,
-            aux_tables={aux_name: (sub_cols, sub_valids)}, no_direct=True)
+            aux_tables={aux_name: (sub_cols, sub_valids)})
 
     bucket_results = _motionpipe.run_pipeline(
         run_bkts, _bstage, _bcompute, settings=executor.settings,
@@ -561,7 +602,7 @@ def _bucketed_dedupe_merge(executor, merged, dedupe, host_scan, aux_name,
     final_plan = _replace_child(merged, outer_partial, host_scan)
     res = executor.run_single(
         final_plan, consts, out_cols, raw=raw,
-        aux_tables={aux2: (s_cols, s_valids)}, no_direct=True)
+        aux_tables={aux2: (s_cols, s_valids)})
     res.stats = dict(res.stats or {})
     res.stats["spill_merge_buckets"] = K
     return res, K
@@ -701,14 +742,14 @@ def spill_sort_run(executor, plan: Motion, consts, out_cols, raw: bool,
     npasses = -(-max_rows // chunk)
     if npasses > 256:
         raise NotSpillable(f"sort spill would need {npasses} passes (> 256)")
-    executor.note_spill_schedule("sort", passes=npasses,
+    executor.spill_schedule.note("sort", passes=npasses,
                                  chunks=[[cand, chunk, npasses]])
 
     from greengage_tpu.exec import staging as _staging
     from greengage_tpu.exec import workfile as _workfile
 
     prefetcher = _staging.PassPrefetcher(
-        executor, comp.input_spec, store.manifest.snapshot())
+        executor.stager, comp.input_spec, store.manifest.snapshot())
     wf = _workfile.SpillWorkfile(executor, out_cols, "sorted-runs")
     try:
         try:
@@ -725,7 +766,7 @@ def spill_sort_run(executor, plan: Motion, consts, out_cols, raw: bool,
                         pass_plan, consts, out_cols, raw=raw,
                         scan_cap_override={cand: chunk},
                         row_ranges={cand: (p * chunk, (p + 1) * chunk)},
-                        no_direct=True, instrument=instrument))
+                        instrument=instrument))
         finally:
             prefetcher.close()
 
@@ -857,7 +898,7 @@ def spill_window_run(executor, plan: Motion, consts, out_cols, raw: bool,
         raise NotSpillable("no partitionable table below the window")
     chosen, per_table, nchunks, comp = _size_chunk_passes(
         executor, consts, pass_plan, candidates, limit_bytes)
-    executor.note_spill_schedule(
+    executor.spill_schedule.note(
         "window-capture", passes=nchunks,
         chunks=[[t, c, n] for t, c, n in per_table])
     grids = [[(t, (i * c, (i + 1) * c)) for i in range(n)]
@@ -865,7 +906,7 @@ def spill_window_run(executor, plan: Motion, consts, out_cols, raw: bool,
     caps = {t: c for t, c, _ in per_table}
     combos = list(itertools.product(*grids))
     prefetcher = _staging.PassPrefetcher(
-        executor, comp.input_spec, store.manifest.snapshot())
+        executor.stager, comp.input_spec, store.manifest.snapshot())
     wf = _workfile.SpillWorkfile(executor, sub_cols, "window-input")
     try:
         try:
@@ -878,8 +919,7 @@ def spill_window_run(executor, plan: Motion, consts, out_cols, raw: bool,
                     wf.add(executor.run_single(
                         pass_plan, consts, sub_cols, raw=True,
                         scan_cap_override=caps,
-                        row_ranges=dict(combo), no_direct=True,
-                        instrument=instrument))
+                        row_ranges=dict(combo), instrument=instrument))
         finally:
             prefetcher.close()
         aux_cols, aux_valids = wf.assemble()
@@ -941,7 +981,7 @@ def spill_window_run(executor, plan: Motion, consts, out_cols, raw: bool,
             "per-bucket window working set still exceeds the limit at 64 "
             "partition buckets")
     bucket = h % np.uint32(K)
-    executor.note_spill_schedule("window", buckets=K)
+    executor.spill_schedule.note("window", buckets=K)
 
     # bucketed window passes on the motion pipeline (exec/motionpipe.py):
     # bucket k+1's host subset build + restage overlaps bucket k's device
@@ -963,8 +1003,7 @@ def spill_window_run(executor, plan: Motion, consts, out_cols, raw: bool,
                          phase="window"):
             return executor.run_single(
                 bucket_plan, consts, out_cols, raw=raw,
-                aux_tables={aux_name: (sub, subv)}, no_direct=True,
-                instrument=instrument)
+                aux_tables={aux_name: (sub, subv)}, instrument=instrument)
 
     bucket_results = _motionpipe.run_pipeline(
         run_bkts, _bstage, _bcompute, settings=settings, label="window")

@@ -931,7 +931,7 @@ def _serve_one(db, ch) -> bool:
     # record the spill pass/bucket schedule this side actually runs: it
     # ships in the completion ack and the coordinator asserts it matches
     # its own (exec/session._mh_spill_parity — lockstep verification)
-    db.executor.begin_spill_schedule()
+    db.executor.spill_schedule.begin()
     try:
         db.worker_sql(msg["sql"])
     except Exception as e:
@@ -944,6 +944,6 @@ def _serve_one(db, ch) -> bool:
     TRACES.exit(tr)
     faults.check("worker_ack")
     ch.ack(True, spans=spans, process_id=db.multihost.process_id,
-           spill_schedule=db.executor.collect_spill_schedule(),
+           spill_schedule=db.executor.spill_schedule.collect(),
            hbm=_hbm_watermark(db))
     return True

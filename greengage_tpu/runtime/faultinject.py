@@ -43,11 +43,11 @@ FAULT_POINTS = frozenset({
     "storage_corrupt_block", "repair_copy", "scrub_file", "delta_fold",
     # statement lifecycle (exec/executor.py)
     "cancel_before_dispatch", "cancel_in_staging",
-    # memory accounting (exec/executor.py): a 'skip' injection fakes a
+    # memory accounting (exec/executor.py _device_oom_fault): a 'skip' fakes a
     # device RESOURCE_EXHAUSTED at dispatch — OOM classification and
     # spill demotion without a real allocator exhaustion
     "device_oom",
-    # vectorized serving (exec/executor.py dispatch_batch): a 'sleep'
+    # vectorized serving (exec/batchserve.py dispatch_batch): a 'sleep'
     # injection holds a batch on the device so tests can pin window
     # accumulation and stage(k+1)/dispatch(k) pipeline overlap
     "batch_dispatch",

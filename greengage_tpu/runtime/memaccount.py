@@ -176,18 +176,16 @@ class AccountRegistry:
         self._by_thread: dict[int, MemoryAccount] = {}
         self._ring: OrderedDict[int, dict] = OrderedDict()
 
-    def enter(self, statement_id: int, sql: str = "",
-              enabled: bool = True) -> tuple[MemoryAccount | None, bool]:
+    def enter(self, statement_id: int,
+              sql: str = "") -> tuple[MemoryAccount, bool]:
         """Open (or re-enter) the calling thread's account; nested sql()
-        calls share the outermost one. -> (account | None, is_outermost)."""
+        calls share the outermost one. -> (account, is_outermost)."""
         tid = threading.get_ident()
         with self._lock:
             cur = self._by_thread.get(tid)
             if cur is not None:
                 cur.depth += 1
                 return cur, False
-            if not enabled:
-                return None, True
             acct = MemoryAccount(statement_id, sql)
             self._by_thread[tid] = acct
             return acct, True
@@ -438,7 +436,7 @@ def executable_mem_summary(executor) -> list[dict]:
     """Per cached executable: the statement key, compile-time estimate,
     and measured memory analysis (None until its first dispatch)."""
     out = []
-    for key, comp in list(executor._plan_cache.items()):
+    for key, comp in executor.programs.items():
         out.append({
             "statement": str(key[0])[:120],
             "est_bytes": int(getattr(comp, "est_bytes", 0)),

@@ -47,7 +47,7 @@ class _Entry:
         self.bytes = nbytes
         # True once the price came from the executable's XLA
         # memory_analysis instead of the planner estimate (warm
-        # executables under mem_accounting_enabled) — the cleaner then
+        # executables) — the cleaner then
         # arbitrates on ground truth, and `gg mem` shows which
         self.measured = False
         self.cancel_reason: str | None = None

@@ -10,8 +10,6 @@ part = 200k x SF, supplier = 10k x SF.
 """
 
 from __future__ import annotations
-import os
-import pickle
 
 import numpy as np
 
@@ -167,44 +165,6 @@ def generate(sf: float, seed: int = 19940801) -> dict[str, dict]:
         "customer": customer, "part": part, "partsupp": partsupp,
         "orders": orders, "lineitem": lineitem,
     }
-
-
-def generate_cached(sf: float, seed: int = 19940801,
-                    cache_dir: str | None = None) -> dict[str, dict]:
-    """generate() with a pickle disk cache: at SF10 generation costs minutes
-    of the bench's measurement window while an unpickle costs seconds. The
-    cache is keyed by (sf, seed) and validated by a version tag so a
-    generator change invalidates stale files. Falls back to generate() on
-    any cache error (corrupt file, disk full, ...)."""
-
-    if cache_dir is None:
-        # user-owned cache dir, not world-writable /tmp: the cache is
-        # loaded with pickle, so the path must not be attacker-creatable
-        cache_dir = os.environ.get(
-            "GGTPU_TPCH_CACHE_DIR",
-            os.path.join(os.path.expanduser("~"), ".cache", "ggtpu"))
-    os.makedirs(cache_dir, exist_ok=True)
-    tag = f"v1:{sf:g}:{seed}"
-    path = os.path.join(cache_dir, f"ggtpu_tpch_sf{sf:g}_{seed}.pkl")
-    try:
-        with open(path, "rb") as f:
-            got_tag, data = pickle.load(f)
-        if got_tag == tag:
-            return data
-    except Exception:
-        pass
-    data = generate(sf, seed)
-    try:
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "wb") as f:
-            pickle.dump((tag, data), f, protocol=pickle.HIGHEST_PROTOCOL)
-        os.replace(tmp, path)
-    except Exception:
-        try:
-            os.unlink(tmp)
-        except Exception:
-            pass
-    return data
 
 
 DDL = """

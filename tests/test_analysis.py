@@ -560,13 +560,13 @@ def test_mutation_unlocked_program_lru_flagged():
     from greengage_tpu.analysis import lint_races
 
     src = astutil.SourceSet(exclude=("greengage_tpu/analysis/",))
-    _mutated(src, "exec/executor.py",
+    _mutated(src, "exec/programs.py",
              "        with self._cache_mu:\n"
              "            self._plan_cache[ck] = comp",
              "        if True:\n"
              "            self._plan_cache[ck] = comp")
     rep = lint_races.run(src)
-    hit = [f for f in rep.findings if "Executor._plan_cache" in f.key]
+    hit = [f for f in rep.findings if "ProgramCache._plan_cache" in f.key]
     assert hit, rep.to_text()
 
 

@@ -27,6 +27,7 @@ import pytest
 
 import greengage_tpu
 import greengage_tpu.exec.compile as C
+from greengage_tpu.exec import batchserve
 from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.interrupt import REGISTRY, StatementCancelled
 from greengage_tpu.runtime.logger import counters
@@ -159,18 +160,19 @@ def test_compile_once_per_width_bucket(db, jits):
               for v in (100, 7, 9, 1, 2, 3, 4, 5)}
 
     n0 = jits["n"]
-    res = db.executor.run_batch(planned, consts, outs, ek, rows([100, 7, 9]))
+    res = batchserve.run_batch(db.executor, planned, consts, ek,
+                               rows([100, 7, 9]))
     assert jits["n"] == n0 + 1          # bucket 4 compiles once
     for v, r in zip((100, 7, 9), res):
         assert r.rows() == oracle[v]
     c0 = counters.snapshot()
-    res = db.executor.run_batch(planned, consts, outs, ek,
-                                rows([1, 2, 3, 4]))
+    res = batchserve.run_batch(db.executor, planned, consts, ek,
+                               rows([1, 2, 3, 4]))
     assert jits["n"] == n0 + 1, "same bucket must not recompile"
     assert counters.since(c0).get("program_cache_hit", 0) == 1
     for v, r in zip((1, 2, 3, 4), res):
         assert r.rows() == oracle[v]
-    db.executor.run_batch(planned, consts, outs, ek, rows([5] * 5))
+    batchserve.run_batch(db.executor, planned, consts, ek, rows([5] * 5))
     assert jits["n"] == n0 + 2          # bucket 8 is a new program
 
     # warm the remaining pow2 buckets (1, 2, 16), then drive 16
@@ -178,7 +180,8 @@ def test_compile_once_per_width_bucket(db, jits):
     # whatever widths the windows happened to form, every bucket is
     # warm, so the storm must compile NOTHING (counter-verified)
     for w in (1, 2, 16):
-        db.executor.run_batch(planned, consts, outs, ek, rows([6] * w))
+        batchserve.run_batch(db.executor, planned, consts, ek,
+                             rows([6] * w))
     n_all = jits["n"]
     db.sql("set batch_serving_enabled = on")
     db.sql("set batch_window_ms = 100")
@@ -343,7 +346,7 @@ def test_fallback_routes_members_to_serial_path(db, monkeypatch):
     db.sql("set batch_serving_enabled = on")
     db.sql("set batch_window_ms = 150")
     db.sql(_q(400))   # warm
-    monkeypatch.setattr(db.executor, "batch_overflowed",
+    monkeypatch.setattr(batchserve, "batch_overflowed",
                         lambda comp, flat: ["join_expand_overflow_0"])
     faults.inject("batch_dispatch", "sleep", sleep_s=0.3, occurrences=1)
     c0 = counters.snapshot()

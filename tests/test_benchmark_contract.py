@@ -1,0 +1,149 @@
+"""What the benchmark reads of the program (ISSUE 33): every `Result.stats`
+key, counter, histogram and span that a file under `benchmark/metrics/`
+names has to exist after a traced statement, and the program cache counts
+one miss cold and one hit warm on both of its callers. The metric files are
+read, never edited. CPU: names and counts, never a time."""
+
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+import greengage_tpu
+from greengage_tpu.exec import batchserve
+from greengage_tpu.runtime.logger import counters, histograms
+from greengage_tpu.runtime.trace import TRACES
+from greengage_tpu.sql.parser import parse
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the kinds that read the program's own names; `trace_*` and `roofline`
+# read the device's operations and the benchmark's own marks
+PROGRAM_KINDS = ("stats_mean", "counter_delta", "counter_share",
+                 "histogram_mean", "span_idle")
+
+
+def _metric_files() -> list:
+    out = []
+    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "metrics",
+                                              "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["kind"] in PROGRAM_KINDS:
+            out.append(pytest.param(spec, id=os.path.basename(path)[:-5]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced(devices8):
+    """A grouped aggregate over two segments, run cold (compiles, reads
+    the files) and again with the staged inputs dropped (finds the
+    program, reads through the block cache): each run's stats and spans,
+    and the registries afterwards."""
+    db = greengage_tpu.connect(numsegments=2)
+    db.sql("create table bc (k bigint, v int) distributed by (k)")
+    n = 6000
+    db.load_table("bc", {"k": np.arange(n, dtype=np.int64) * 7919,
+                         "v": (np.arange(n) % 11).astype(np.int32)})
+    db.sql("analyze")
+    sql = "select k, sum(v) from bc group by k"   # a sort-based aggregate
+    runs = []
+    for _ in range(2):
+        db.executor.stager.stage_cache.clear()
+        res = db.sql(sql)
+        runs.append((res.stats, TRACES.last().export()))
+    yield {"runs": runs, "counters": counters.snapshot(),
+           "histograms": histograms.snapshot()}
+    db.close()
+
+
+@pytest.mark.parametrize("spec", _metric_files())
+def test_metric_reads_a_name_the_program_produces(traced, spec):
+    kind = spec["kind"]
+    if kind == "stats_mean":
+        for stats, _spans in traced["runs"]:
+            assert spec["stat"] in stats, sorted(stats)
+            if "where" in spec:
+                assert spec["where"] in stats, sorted(stats)
+        if "where" in spec:   # the cold run is the one it keeps
+            assert traced["runs"][0][0][spec["where"]]
+    elif kind in ("counter_delta", "counter_share"):
+        names = [spec["name"]] if kind == "counter_delta" \
+            else spec["num"] + spec["den"]
+        for name in names:
+            assert traced["counters"].get(name, 0) > 0, name
+    elif kind == "histogram_mean":
+        for name in [spec["name"]] + spec.get("minus", []):
+            if name != "client_latency_ms":   # the benchmark's own
+                assert traced["histograms"][name]["count"] > 0, name
+    else:
+        assert kind == "span_idle"
+        for _stats, spans in traced["runs"]:
+            names = {s["name"] for s in spans}
+            mine = [s for s in spans if s["tid"] == spans[0]["tid"]]
+            if spec["spans"] != "leaf":
+                assert spec["spans"] in {s["name"] for s in mine}, names
+                continue
+            # the statement thread's leaves are what the idle time is held
+            # against: the staging leaves and `dispatch` must be among them
+            parents = {s["parent"] for s in mine}
+            leaves = {s["name"] for s in mine if s["id"] not in parents}
+            assert {"wait", "assemble", "put", "dispatch"} <= leaves, leaves
+            assert "read:bc" in names and "stage" in names - leaves, names
+
+
+@pytest.fixture()
+def db(devices8):
+    d = greengage_tpu.connect(numsegments=4)
+    d.sql("create table pc (k int, a int) distributed by (k)")
+    d.load_table("pc", {"k": np.arange(500, dtype=np.int32),
+                        "a": np.arange(500, dtype=np.int32)})
+    yield d
+    d.close()
+
+
+@pytest.mark.parametrize("caller", ["classic", "batch"])
+def test_one_miss_cold_and_one_hit_warm(db, caller):
+    """Both callers of ProgramCache.find_or_compile, the same shape."""
+    planned, consts, outs, ek = db._cached_plan(
+        parse("select count(*) from pc where a > 100")[0])
+    pv = consts["@params@"]
+
+    def run():
+        if caller == "classic":
+            return [db.executor.run(planned, consts, outs, cache_key=ek)]
+        return batchserve.run_batch(db.executor, planned, consts, ek,
+                                    [pv, pv])
+
+    for want in ({"program_cache_miss": 1}, {"program_cache_hit": 1}):
+        c0 = counters.snapshot()
+        res = run()
+        assert counters.since(c0, prefix="program_cache_") == want
+        assert all(r.rows() == [(399,)] for r in res)
+
+
+@pytest.mark.parametrize("caller", ["classic", "batch"])
+def test_unsignable_shape_is_counted_once_and_never_cached(db, caller,
+                                                           monkeypatch):
+    """The callers choose what an unsignable shape does: the classic loop
+    compiles it uncached, a batch goes back to the serial path."""
+    from greengage_tpu.exec.compile import Compiler
+
+    planned, consts, outs, ek = db._cached_plan(
+        parse("select count(*) from pc where a > 200")[0])
+
+    def refuse(self, plan, snapshot):
+        raise LookupError("dictionary unavailable")
+    monkeypatch.setattr(Compiler, "shape_signature", refuse)
+    c0 = counters.snapshot()
+    if caller == "classic":
+        res = db.executor.run(planned, consts, outs, cache_key=ek)
+        assert res.rows() == [(299,)] and res.stats["compiled"]
+    else:
+        with pytest.raises(batchserve.BatchFallback):
+            batchserve.run_batch(db.executor, planned, consts, ek,
+                                 [consts["@params@"]])
+    assert counters.since(c0, prefix="program_cache_") == {
+        "program_cache_unsignable": 1}
+    assert not db.executor.programs.items()

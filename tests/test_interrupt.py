@@ -145,14 +145,14 @@ def test_staging_cancel_point_fires_once_a_read_unit(db):
     db.sql(q)   # compile outside the deadline below
     db.sql("set scan_threads = 1")
     try:
-        db.executor._stage_cache.clear()
+        db.executor.stager.stage_cache.clear()
         db.store.blockcache.clear()
         faults.inject("cancel_in_staging", "sleep", sleep_s=0.0,
                       occurrences=-1)
         assert db.sql(q).rows()[0][0] == sum(np.arange(50_000) % 11)
         assert [f["hits"] for f in faults.status()] == [8]
         faults.reset("cancel_in_staging")
-        db.executor._stage_cache.clear()
+        db.executor.stager.stage_cache.clear()
         db.store.blockcache.clear()
         db.sql("set statement_timeout_s = 0.3")
         faults.inject("cancel_in_staging", "sleep", sleep_s=0.2,

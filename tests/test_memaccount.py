@@ -67,7 +67,7 @@ def test_measured_bytes_attached_and_zero_reanalysis_on_warm_hit(db):
 
 def test_owner_tree_charges_staging_blockcache_device(db):
     # force a cold stage (fresh reads + fresh cache inserts)
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     r = db.sql(Q)
     owners = r.stats["mem"]["owners"]
@@ -115,7 +115,7 @@ def test_oom_demotes_to_spill_once(db):
 
 def test_oom_typed_error_carries_accounting_and_dumps_json(db):
     db.sql("set oom_spill_retry = off")
-    db.executor._stage_cache.clear()   # guarantee a staging owner charge
+    db.executor.stager.stage_cache.clear()   # guarantee a staging owner charge
     faults.inject("device_oom", "skip", occurrences=1)
     try:
         with pytest.raises(OutOfDeviceMemory) as ei:
@@ -180,7 +180,7 @@ def test_process_gauges_rss_fds_pool_depth(db):
 
 
 def test_owner_gauges_exported_during_statement(db):
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.sql(Q)
     # live totals drain when statements retire; the gauge names must
     # still be present (written at least once during the run above via

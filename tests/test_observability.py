@@ -341,7 +341,7 @@ def test_slow_statement_log_fires_at_threshold(db):
 # ---------------------------------------------------------------------------
 
 # one device.memory_stats() sample on the chip's host (my chip run, PR 28:
-# bench.py --microbench span_cost read 3,507 ns on a TPU v5e's host); the
+# a loop of Trace.begin/end read 3,507 ns a sample on a TPU v5e's host); the
 # CPU backend's real sampler latches off at its first probe and costs nothing
 SAMPLE_NS = 3500
 

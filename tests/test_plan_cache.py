@@ -201,14 +201,14 @@ def test_plan_cache_lru_and_hint_lifetime(db):
     db.sql("select sum(v) from t where a > 2")            # shape B
     db.sql("select count(*) from t where a > 3")          # touch A (LRU)
     db.sql("select max(a) from t where v < 9.0")          # shape C evicts B
-    assert len(db.executor._plan_cache) <= 2
+    assert len(db.executor.programs._plan_cache) <= 2
     c0 = counters.snapshot()
     db.sql("select count(*) from t where a > 4")          # A again
     assert counters.since(c0).get("program_cache_hit", 0) == 1, \
         "LRU must have kept the recently-touched shape A"
     # bookkeeping for statements no longer cached is dropped
-    live = {k[0] for k in db.executor._plan_cache}
-    assert set(db.executor._cap_hints) <= live
+    live = {k[0] for k in db.executor.programs._plan_cache}
+    assert set(db.executor.programs._cap_hints) <= live
     db.sql("set plan_cache_size = 256")
 
 

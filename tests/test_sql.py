@@ -253,7 +253,7 @@ def test_duplicate_build_keys_multi_match(db):
     assert list(got.bv) == list(want.v_y)
     # dist key == join key, so the planner chose the unique path first; the
     # runtime dup flag must have forced the multi re-plan (retry pinned)
-    assert any(k[0].endswith("#multi") for k in db.executor._plan_cache)
+    assert any(k[0].endswith("#multi") for k in db.executor.programs._plan_cache)
     # repeat must hit the cached multi plan, not re-fail on the stale program
     r2 = db.sql("select a.v av, b.v bv from dup_b a join dup_b b on a.k = b.k "
                 "order by av, bv")

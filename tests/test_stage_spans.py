@@ -45,7 +45,7 @@ def db(devices8):
 
 def cold(db, q=Q):
     """One statement with nothing of its tables cached -> (result, trace)."""
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     r = db.sql(q)
     return r, trace_of(q)
@@ -176,7 +176,7 @@ def test_count_star_stages_through_one_unit_a_segment(db):
     # and a spec with NO storage column still has a unit a segment, which
     # reads no file and carries the segment's row count
     snap = db.store.manifest.snapshot()
-    got = [db.executor._read_unit("sp_b", None, seg, [], snap, None, None)
+    got = [db.executor.stager._read_unit("sp_b", None, seg, [], snap, None, None)
            for seg in range(4)]
     assert all(c == {} and v == {} for c, v, _n, _p in got)
     assert sum(n for _c, _v, n, _p in got) == 2 * N
@@ -315,7 +315,7 @@ def test_concurrent_statements_do_not_share_read_accounts(db):
     qa, qb = Q, Q.replace("sp_a", "sp_b")
     solo = {q: cold(db, q)[0].stats["read_bytes"] for q in (qa, qb)}
     assert 0 < solo[qa] < solo[qb]
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     gate, out = threading.Barrier(2), {}
 

@@ -5,10 +5,7 @@ perf-regression guard (docs/PERF.md).
 The guard asserts COUNTER VALUES (files read, bytes decoded, cache hits),
 never wall clocks, so it is stable on shared CPU runners."""
 
-import json
 import os
-import subprocess
-import sys
 import threading
 
 import numpy as np
@@ -19,8 +16,6 @@ from greengage_tpu.runtime.faultinject import faults
 from greengage_tpu.runtime.logger import counters
 from greengage_tpu.storage.blockcache import CacheRegistry
 from greengage_tpu.storage.corruption import CorruptionError
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -116,7 +111,7 @@ def test_cold_scan_reads_each_file_once_and_repeat_reads_nothing(db):
 
     # drop only the staged inputs: the scan re-assembles entirely from the
     # BLOCK cache — still zero file reads, and real cache hits
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     base = counters.snapshot()
     r = db.sql("select sum(v) from t")
     assert r.rows()[0][0] == sum(i * 10 for i in range(256))
@@ -126,7 +121,7 @@ def test_cold_scan_reads_each_file_once_and_repeat_reads_nothing(db):
 
 
 def test_per_statement_scan_io_stats_and_explain(db):
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     r = db.sql("select sum(v), sum(w) from t")
     s = r.stats
@@ -135,7 +130,7 @@ def test_per_statement_scan_io_stats_and_explain(db):
     # one read unit a (segment, column): 8 segments x {v, w}, a file each
     # where the segment holds rows
     assert s["stage_read_units"] == 16 >= s["scan_io"]["scan_files_read"]
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     plan = db.sql("explain analyze select sum(v) from t").plan_text
     assert "Host data path: staging" in plan and "(8 read units)" in plan
@@ -146,7 +141,7 @@ def test_scan_threads_guc_serial_matches_parallel(db):
     want = sorted((i, i * 10) for i in range(256))
     for n in (1, 2, 0):
         db.sql(f"set scan_threads = {n}")
-        db.executor._stage_cache.clear()
+        db.executor.stager.stage_cache.clear()
         db.store.blockcache.clear()
         r = db.sql("select k, v from t")
         assert sorted(r.rows()) == want
@@ -177,7 +172,7 @@ def test_index_build_drops_staged_inputs_so_scans_prune(db, tmp_path):
               + ",".join(f"({i},{i})" for i in range(lo, lo + 1024)))
     assert d.sql("select sum(v) from u where k = 77").rows()[0][0] == 77
     d.sql("create index u_k on u (k)")
-    assert len(d.executor._stage_cache) == 0    # staged inputs dropped
+    assert len(d.executor.stager.stage_cache) == 0    # staged inputs dropped
     assert d.sql("select sum(v) from u where k = 77").rows()[0][0] == 77
 
 
@@ -273,7 +268,7 @@ def test_fault_injected_corruption_under_parallel_staging(mdb):
     pool reads concurrently: the hit thread repairs, every other thread
     proceeds, the statement returns exact rows."""
     mdb.sql("set scan_threads = 4")
-    mdb.executor._stage_cache.clear()
+    mdb.executor.stager.stage_cache.clear()
     mdb.store.blockcache.clear()
     before = counters.get("storage_repair")
     faults.inject("storage_corrupt_block", "skip", occurrences=1)
@@ -288,31 +283,8 @@ def test_fault_injected_corruption_under_parallel_staging(mdb):
 
 def test_scan_cache_limit_mb_bounds_resident_bytes(db):
     db.sql("set scan_cache_limit_mb = 1")
-    db.executor._stage_cache.clear()
+    db.executor.stager.stage_cache.clear()
     db.store.blockcache.clear()
     db.sql("select sum(v), sum(w), sum(k) from t")
     assert db.store.blockcache.total_bytes <= 1 << 20
     db.sql("set scan_cache_limit_mb = 1024")
-
-
-# ---------------------------------------------------------------------------
-# microbench smoke: one-line JSON, CPU-only
-# ---------------------------------------------------------------------------
-
-def test_staging_microbench_emits_headline(tmp_path):
-    env = dict(os.environ)
-    env.update({
-        "GGTPU_MB_ROWS": "20000", "GGTPU_MB_COLS": "3",
-        "GGTPU_MB_SEGS": "4", "GGTPU_MB_RUNS": "1",
-    })
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--microbench", "staging"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=300)
-    assert p.returncode == 0, p.stderr[-3000:]
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert line["metric"] == "staging_cold_mb_per_sec"
-    assert line["value"] > 0
-    assert line["unit"] == "MB/s"
-    assert line["files_read"] > 0
-    assert line["warm_files_read"] == 0   # repeat served from block cache
