@@ -433,6 +433,8 @@ class Executor:
             **({} if finalize_ms is None
                else {"finalize_ms": finalize_ms}),
             "scan_io": staged.scan_io,
+            # read units, and those that landed in their staging slots
+            **staged.units,
             "segments": self.nseg,
             # FTS/topology version the dispatch was bound against
             # (bumped by mesh re-formation and mirror promotion;

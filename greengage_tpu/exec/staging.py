@@ -15,9 +15,10 @@ and nowhere else. The pieces:
     parallelism; TableStore's caches and read-path self-heal are
     thread-safe under it. ``scan_threads`` sizes it (0 = auto).
   - IN-PLACE staging buffers: one preallocated ``[nseg * cap]`` host array
-    per staged column that per-segment decoded arrays are written into
-    directly (``fill_buffer``) or decoded into (the protocol is written
-    down at ``Stager._submit``).
+    per staged column, whose per-segment slots the read units fill on
+    their own threads (the protocol is written down at
+    ``Stager._submit``); what could not land there is copied in on the
+    statement thread (``fill_buffer``, ``_fill_column``).
   - a spill-pass PREFETCHER (``PassPrefetcher``): while pass k's jitted
     program runs, a background thread warms pass k+1's cold block reads
     into the block cache (JAX async dispatch leaves the host idle there).
@@ -52,6 +53,11 @@ from greengage_tpu.storage.blockcache import MISS
 # so tests can assert them deterministically)
 SCAN_COUNTERS = ("scan_files_read", "scan_bytes_decoded", "scan_cache_hit",
                  "scan_cache_miss", "scan_cache_evict")
+# how often the in-place protocol (Stager._submit) engages: the read units
+# of `read` tables, and those whose every column landed in its staging slot
+# on the thread that ran the unit. Counters, Result.stats keys and (less
+# the prefix) arguments of the `stage:<table>` span alike
+SLOT_COUNTERS = ("stage_units", "stage_units_in_slot")
 
 
 def scan_thread_count(settings) -> int:
@@ -212,21 +218,24 @@ def _pad(arr: np.ndarray, cap: int, fill=0) -> np.ndarray:
     return out
 
 
-def _land(futs, u, per_seg) -> list:
-    """Block until unit ``u`` of every staged segment is done and its
-    columns and masks are in ``per_seg``; -> [(segment, nrows, prune
-    stats)]. A landed unit's futures are let go (``futs[u] = None``
-    says it has landed). A cancellation point a column; inside the
+def _land(st, u, per_seg) -> list:
+    """Block until unit ``u`` of every staged segment of the read table
+    ``st`` is done and its columns and masks are in ``per_seg``;
+    -> [(segment, nrows, prune stats)]. A landed unit's futures are let
+    go (``futs[u] = None`` says it has landed) and those that came in
+    their slots are counted. A cancellation point a column; inside the
     wait the units poll the statement's context themselves."""
     interrupt.check_interrupts()
+    futs = st["futs"]
     row, futs[u] = futs[u], None
     out = []
     for seg, fut in enumerate(row):
         if fut is None:
             continue
-        c, v, n, pstat = fut.result()
+        c, v, n, pstat, in_slot = fut.result()
         per_seg[seg][0].update(c)
         per_seg[seg][1].update(v)
+        st["in_slot"] += in_slot != "no"
         out.append((seg, n, pstat))
     return out
 
@@ -238,6 +247,8 @@ class Staged:
     sid: int = -1             # the `stage` span, for the caller's annotate
     stage_ms: float = 0.0
     scan_io: dict = field(default_factory=dict)  # SCAN_COUNTERS deltas
+    units: dict = field(                         # SLOT_COUNTERS, this call's
+        default_factory=lambda: dict.fromkeys(SLOT_COUNTERS, 0))
     split: dict = field(default_factory=dict)    # stage_*_ms, read_*: spans
     zone_prune: dict = field(default_factory=dict)   # table: (kept, blocks)
     # runtime PartitionSelector results: child partitions kept / total
@@ -348,6 +359,10 @@ class Stager:
         out.stage_ms = (time.monotonic() - t0) * 1e3
         out.scan_io = {k: counters.get(k) - io0[k] for k in SCAN_COUNTERS}
         _trace.annotate(out.sid, **out.scan_io)
+        if out.units["stage_units"]:
+            counters.inc("stage_units", out.units["stage_units"])
+            counters.inc("stage_units_in_slot",
+                         out.units["stage_units_in_slot"])
         out.split = _stage_split(out.sid)
         return out
 
@@ -406,21 +421,32 @@ class Stager:
     def _submit(self, p, call: _Call) -> None:
         """Hand one read table's units to the pool (once).
 
-        THE IN-PLACE PROTOCOL, all of it. (1) This function alone decides
-        whether a table MAY decode in place: a scan that fills every
-        segment's slot whole — no row range, no child partitions, no
-        direct dispatch, every segment local — gets its [nseg*cap]
-        buffers here, and each unit is offered its segment's slots as
-        ``dest``; ranged/partitioned scans slice after the read and keep
-        the copy path, and so do scans that fill only SOME segments (a
-        cached view of a partially-used buffer would pin far more memory
-        than its byte accounting). (2) ``TableStore.read_segment`` takes
-        the offer column by column, only for one data file with no block
-        pruning and no deletion bitmap, and only on a block-cache miss:
-        it then decodes into the slot and returns a VIEW of it;
-        otherwise it returns an array of its own. (3) ``_fill_column``
-        learns which happened by identity (``arr.base is buf``) and
-        copies what is not already in place."""
+        THE IN-PLACE PROTOCOL, all of it. The rule: a read unit that is
+        offered its staging slot fills it, on the thread that runs it;
+        the statement thread builds the ``present`` mask and puts (the
+        buffers come zeroed, so the tails past a segment's rows need no
+        padding). (1) This function alone decides whether a table's units
+        ARE offered their slots: a scan that fills every segment's slot
+        from its start — no row range, no child partitions, no direct
+        dispatch, every segment local — gets its [nseg*cap] buffers
+        here, and each unit is offered its segment's slots as ``dest``;
+        ranged/partitioned scans slice or concatenate after the read and
+        keep the copy path, and so do scans that fill only SOME segments
+        (a cached view of a partially-used buffer would pin far more
+        memory than its byte accounting). (2) ``TableStore.read_segment``
+        takes the offer column by column, whatever zone-map predicate is
+        pushed: a column of one data file lands in its slot through
+        ``read_file(out=)``, which decodes the (kept) blocks into the
+        slot's prefix on a block-cache miss — the cached value is then a
+        view of the slot, charged the whole slot it pins — and copies
+        the cached array there on a hit, and either way returns a VIEW
+        of the slot. What it observes in its input keeps an array of its
+        own: several data files for the column, a deletion bitmap, a
+        virtual '@' column, a slot of another dtype or too short; so do
+        validity masks (a byte a row). (3) ``_read_unit`` says which
+        happened (``in_slot`` on its `read:` span, SLOT_COUNTERS) and
+        ``_fill_column`` learns it by identity (``arr.base is buf``) and
+        copies only what is not already in place."""
         _, table, cols, cap, _key, prune, st = p
         if st["futs"] is not None:
             return
@@ -429,7 +455,10 @@ class Stager:
                 and st["direct"] is None \
                 and len(call.local_segs) == self.nseg:
             schema = self.store.catalog.get(table)
-            buffers = {c: np.empty(self.nseg * cap, stage_dtype(schema, c))
+            # zeros, not empty: a buffer of this size is fresh zero pages
+            # either way, and the slots' tails (cap is a power of two, a
+            # tenth of a buffer at SF10) then need no padding at all
+            buffers = {c: np.zeros(self.nseg * cap, stage_dtype(schema, c))
                        for unit in st["units"] for c in unit}
         # direct dispatch: only the owning segment's storage is
         # read/staged (cdbtargeteddispatch.c analog)
@@ -455,6 +484,7 @@ class Stager:
         st["buffers"] = buffers
         st["futs"] = futs
         st["read_units"] = len(futs) * len(segs)
+        st["in_slot"] = 0   # of them, landed in their slots (_land)
 
     def _assemble(self, plans, snapshot, aux, out: Staged) -> list:
         """Assemble phase (spec order, deterministic): fill staging
@@ -515,7 +545,7 @@ class Stager:
                     # every unit of a segment sees the same zone maps and
                     # row count: the first column's speak for the segment
                     kept = total_blocks = 0
-                    for seg, n, pstat in _land(futs, 0, per_seg):
+                    for seg, n, pstat in _land(st, 0, per_seg):
                         per_seg[seg][2] = n
                         if pstat is not None:
                             kept += pstat[0]
@@ -532,23 +562,25 @@ class Stager:
                                     if c.startswith(VALID_PREFIX) else c)
                     if u is not None and futs[u] is not None:
                         with _trace.span("wait", cat="stage"):
-                            _land(futs, u, per_seg)
+                            _land(st, u, per_seg)
                     with _trace.span("assemble", cat="stage"):
                         host = self._fill_column(schema, c, cap, per_seg,
                                                  buffers)
                     staged.append(self._put(host, shard))
                 with _trace.span("assemble", cat="stage"):
-                    present = fill_buffer(
-                        self.nseg, cap, np.dtype(bool),
-                        ((s, np.ones(n, dtype=bool))
-                         for s, (_, _, n) in enumerate(per_seg)), False)
+                    present = np.zeros(self.nseg * cap, dtype=bool)
+                    for s, (_, _, n) in enumerate(per_seg):
+                        present[s * cap: s * cap + n] = True
                 staged.append(self._put(present, shard))
                 staged_local[key] = (staged, out.zone_prune.get(table))
                 nbytes = sum(int(getattr(a, "nbytes", 64)) for a in staged)
                 memaccount.charge("staging", nbytes, item=table)
                 _trace.annotate(_sp_t, rows=int(sum(n for _, _, n in per_seg)),
                                 bytes=nbytes, segments=len(per_seg),
-                                read_units=st["read_units"])
+                                read_units=st["read_units"],
+                                units_in_slot=st["in_slot"])
+                out.units["stage_units"] += st["read_units"]
+                out.units["stage_units_in_slot"] += st["in_slot"]
                 if st["rng"] is None:
                     self.stage_cache.put(
                         key, staged_local[key], nbytes=nbytes,
@@ -573,16 +605,19 @@ class Stager:
         (several where column_units keeps them together; + this
         thread's zone-prune stats). Runs concurrently with other units —
         the store's caches and read-path self-heal are thread-safe.
-        ``dest`` carries this segment's staging-buffer slots for the
-        in-place decode fast path. ``stmt_ctx`` is the owning statement's
+        ``dest`` carries this segment's staging-buffer slots; the last
+        member of the result says whether every column landed there
+        ("decode", or "copy" where a block-cache hit was copied in) or
+        "no". ``stmt_ctx`` is the owning statement's
         interrupt context: each unit is a cancellation point, and the
         raise travels back to the statement thread via fut.result().
         ``stmt_acct`` binds this pool thread to the statement's memory
         account so block-cache inserts inside the read attribute right.
         ``stmt_trace`` is its trace: the unit records one `read:<table>`
         span there under ``parent_sid`` (the statement's `stage` span),
-        carrying what THIS unit read and how long it spent in file reads
-        and in CRC + decode (blockfile.ReadTally, bound to this thread)."""
+        carrying what THIS unit read and how long it spent in file reads,
+        in CRC + decode and in copying hits into its slots
+        (blockfile.ReadTally, bound to this thread)."""
         faults.check("cancel_in_staging", segment=seg)
         if stmt_ctx is not None:
             stmt_ctx.check()
@@ -590,12 +625,17 @@ class Stager:
                                 parent=parent_sid, segment=seg,
                                 column=",".join(storage_cols))
                if stmt_trace is not None else -1)
+        in_slot = "no"
         with blockfile.tally() as io:
             try:
                 with memaccount.ACCOUNTS.bind(stmt_acct):
                     c, v, n = self._read_segment_parts(
                         table, child_parts, seg, storage_cols, snapshot,
                         prune, dest=dest)
+                if dest is not None and all(
+                        getattr(c.get(k), "base", None) is slot.base
+                        for k, slot in dest.items()):
+                    in_slot = "copy" if io.slot_copies else "decode"
             finally:
                 if sid >= 0:
                     stmt_trace.end(
@@ -603,17 +643,20 @@ class Stager:
                         bytes_read=io.bytes_read,
                         bytes_decoded=io.bytes_decoded,
                         io_ms=round(io.io_ns / 1e6, 3),
-                        decode_ms=round(io.decode_ns / 1e6, 3))
+                        decode_ms=round(io.decode_ns / 1e6, 3),
+                        copy_ms=round(io.copy_ns / 1e6, 3),
+                        in_slot=in_slot)
         if rng is not None:
             a, b = rng
             c = {k: arr[a:b] for k, arr in c.items()}
             v = {k: (arr[a:b] if arr is not None else None)
                  for k, arr in v.items()}
             n = max(min(n, b) - a, 0)
-        return c, v, n, (self.store.last_prune if prune else None)
+        return c, v, n, (self.store.last_prune if prune else None), in_slot
 
     def _fill_column(self, schema, c, cap, per_seg, buffers) -> np.ndarray:
-        """One column's [nseg*cap] host buffer, padded."""
+        """One column's [nseg*cap] host buffer, zero past each segment's
+        rows."""
         nseg = self.nseg
         if c.startswith(VALID_PREFIX):
             name = c[len(VALID_PREFIX):]
@@ -629,13 +672,12 @@ class Stager:
                 nseg, cap, dt,
                 ((s, cc.get(c, np.zeros(0, dt)).astype(dt, copy=False))
                  for s, (cc, _, _) in enumerate(per_seg)), 0)
+        # the buffer came zeroed (_submit): only rows are ever written
         for s, (cc, _, _) in enumerate(per_seg):
             arr = cc.get(c)
-            n = 0 if arr is None else len(arr)
-            if n and getattr(arr, "base", None) is not buf:
-                buf[s * cap: s * cap + n] = arr
-            if n < cap:
-                buf[s * cap + n: (s + 1) * cap] = 0
+            if arr is not None and len(arr) \
+                    and getattr(arr, "base", None) is not buf:
+                buf[s * cap: s * cap + len(arr)] = arr
         return buf
 
     def _dyn_pruned_parts(self, table, child_parts, dyn, snapshot,

@@ -34,7 +34,7 @@ def _param_value(e) -> E.Expr | None:
     """A comparison operand whose VALUE is a hoisted parameter — a bare
     Param or the binder's numeric coercion Cast around one. The returned
     expression is stored in the pushed prune predicate and resolved to a
-    concrete storage value at staging time (exec/executor._resolve_prune)."""
+    concrete storage value at staging time (exec/staging.resolve_prune)."""
     if isinstance(e, E.Param):
         return e
     if isinstance(e, E.Cast) and isinstance(e.arg, E.Param):

@@ -86,6 +86,10 @@ COUNTER_NAMES = (
     # host data path (storage/blockcache.py, exec/executor.py)
     "scan_files_read", "scan_bytes_decoded",
     "scan_cache_hit", "scan_cache_miss", "scan_cache_evict",
+    # the in-place protocol (exec/staging.py): read units staged, and
+    # those whose every column landed in its staging slot on the thread
+    # that ran the unit — in_slot / units is how often it engages
+    "stage_units", "stage_units_in_slot",
     # storage self-heal (storage/table_store.py, storage/scrub.py)
     "storage_repair", "storage_standby_repair", "storage_quarantine",
     "storage_scrub_runs", "storage_scrub_files",

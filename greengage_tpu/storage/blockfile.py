@@ -50,12 +50,15 @@ class ReadTally:
     which a process-wide counter cannot give."""
 
     __slots__ = ("files", "cache_hits", "bytes_read", "bytes_decoded",
-                 "io_ns", "decode_ns")
+                 "io_ns", "decode_ns", "slot_copies", "copy_ns")
 
     def __init__(self):
         self.files = self.cache_hits = 0        # decoded / block-cache hit
         self.bytes_read = self.bytes_decoded = 0   # compressed frames / rows
         self.io_ns = self.decode_ns = 0         # in f.read / CRC + decode
+        # block-cache hits copied into the caller's slot (read_file), and
+        # the time in those copies
+        self.slot_copies = self.copy_ns = 0
 
 
 _tally = threading.local()
@@ -215,6 +218,16 @@ def _maybe_inject_corruption(frame: bytes, segment: int | None) -> bytes:
     return bytes(bad)
 
 
+def fit_slot(out: np.ndarray | None, dtype, nrows: int) -> np.ndarray | None:
+    """The first ``nrows`` of a caller's destination, or None where it
+    cannot hold them as they are stored (no destination, another dtype,
+    too short, not contiguous)."""
+    if out is None or out.dtype != dtype or len(out) < nrows \
+            or not out.flags.c_contiguous:
+        return None
+    return out[:nrows]
+
+
 def read_column_file(path: str, block_indices: list[int] | None = None,
                      segment: int | None = None,
                      out: np.ndarray | None = None) -> np.ndarray:
@@ -226,7 +239,8 @@ def read_column_file(path: str, block_indices: list[int] | None = None,
     no final concatenate — the copy count the pipelined staging path is
     built around. ``out`` lets the caller provide that destination (e.g.
     a slot of the executor's [nseg*cap] staging buffer, dtype- and
-    capacity-compatible); the return value is then a view of it."""
+    capacity-compatible); the return value is then a view of it: the
+    selected blocks' rows, in the slot's prefix."""
     with open(path, "rb") as f:
         footer = _read_footer_fh(f, path)   # one open serves footer + frames
         dtype = np.dtype(footer["dtype"])
@@ -234,13 +248,9 @@ def read_column_file(path: str, block_indices: list[int] | None = None,
         if block_indices is not None:
             blocks = [blocks[i] for i in block_indices]
         total_rows = sum(b["nrows"] for _, b in blocks)
-        if out is not None and (out.dtype != dtype or len(out) < total_rows
-                                or not out.flags.c_contiguous):
-            out = None   # incompatible destination: decode a fresh array
-        if out is None:
+        out = fit_slot(out, dtype, total_rows)
+        if out is None:   # none offered, or incompatible: a fresh array
             out = np.empty(total_rows, dtype=dtype)
-        else:
-            out = out[:total_rows]
         if not blocks:
             return out
         u8 = out.view(np.uint8)

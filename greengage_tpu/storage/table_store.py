@@ -35,8 +35,8 @@ from greengage_tpu.runtime.faultinject import FaultError, faults
 from greengage_tpu.runtime.logger import counters
 from greengage_tpu.storage import native
 from greengage_tpu.storage.blockcache import MISS, CacheRegistry
-from greengage_tpu.storage.blockfile import (current_tally, fsync_dir,
-                                             read_column_file,
+from greengage_tpu.storage.blockfile import (current_tally, fit_slot,
+                                             fsync_dir, read_column_file,
                                              verify_column_file,
                                              write_column_file)
 from greengage_tpu.storage.corruption import CorruptionError
@@ -451,9 +451,12 @@ class TableStore:
         Cache misses count scan_files_read / scan_bytes_decoded.
 
         ``out``: optional preallocated destination (a staging-buffer slot)
-        the frames decode straight into on a miss — the cached value is
-        then a view of it, and the caller skips its own copy. Cache hits
-        ignore ``out`` (the caller copies from the returned array)."""
+        that the rows land in on the CALLING thread, whichever way they
+        come: a miss decodes the frames straight into it (the cached
+        value is then a view of it), a hit copies the cached array into
+        it (the cache entry stays what it was). Either way the return
+        value is a view of ``out`` and the caller skips its own copy; a
+        destination of another dtype, or too short, is passed over."""
         key = (table, rel,
                None if block_indices is None else tuple(block_indices))
         hit = self._block_cache.get(key, MISS)
@@ -461,7 +464,15 @@ class TableStore:
             tally = current_tally()
             if tally is not None:
                 tally.cache_hits += 1
-            return hit
+            slot = fit_slot(out, hit.dtype, len(hit))
+            if slot is None:
+                return hit
+            t0 = _time.perf_counter_ns()
+            np.copyto(slot, hit)
+            if tally is not None:
+                tally.slot_copies += 1
+                tally.copy_ns += _time.perf_counter_ns() - t0
+            return slot
         arr = self._read_checked(
             table, rel,
             lambda p, c: read_column_file(p, block_indices, segment=c,
@@ -1054,6 +1065,10 @@ class TableStore:
         for fileno, ok in keep.items():
             total += by_fileno_nblocks.get(fileno, 0)
             kept += len(ok)
+        # a fileno that keeps every block reads as unpruned: one cache
+        # entry a file whatever the predicate, shared with the plain scan
+        keep = {f: ok for f, ok in keep.items()
+                if len(ok) < by_fileno_nblocks.get(f, 0)}
         return keep, kept, total
 
     def read_segment(self, table: str, seg: int, columns: list[str] | None = None,
@@ -1066,9 +1081,15 @@ class TableStore:
         identical across a fileno's columns), shrinking the staged rows.
 
         ``dest``: optional {col: preallocated array} destinations (the
-        executor's staging-buffer slots). A plain single-file column with
-        no pruning/deletions decodes STRAIGHT into its slot (the returned
-        array is a view of it), skipping the staging copy entirely."""
+        executor's staging-buffer slots). A plain column of at most one
+        data file and no deletion bitmap lands STRAIGHT in its slot, on
+        this thread (``read_file(out=)``: decoded there on a miss, copied
+        there on a block-cache hit; under a keep list the kept blocks'
+        rows, in the slot's prefix) and the returned array is a view of
+        it, so staging has nothing left to copy. More data files (their
+        rows are concatenated), a deletion bitmap (rows are filtered
+        after assembly) and the virtual '@' columns return arrays of
+        their own."""
         schema = self.catalog.get(table)
         snap = snapshot or self.manifest.snapshot()
         tmeta = snap["tables"].get(table, {"segfiles": {}, "nrows": {}})
@@ -1154,11 +1175,11 @@ class TableStore:
                         valid_rels.append(rel)
                     else:
                         data_rels.append(rel)
-            # in-place fast path: one data file, no block pruning, no
-            # deletion bitmap — decode straight into the caller's slot
+            # in place: at most one data file and no deletion bitmap —
+            # the rows land in the caller's slot, pruned or not
             d = None
-            if dest is not None and keep is None and keep_rows is None \
-                    and len(data_rels) == 1:
+            if dest is not None and keep_rows is None \
+                    and len(data_rels) <= 1:
                 d = dest.get(name)
 
             def _bidx(rel):
@@ -1177,14 +1198,18 @@ class TableStore:
                 data_parts.append((rel, self.read_file(table, rel,
                                                        _bidx(rel), out=d)))
             if len(data_parts) == 1:
-                # single segfile (the common post-load shape): hand the
-                # cache-resident array through as-is — staging copies it
-                # into its own buffer, so nothing downstream mutates it
+                # single segfile (the common post-load shape): the view of
+                # the caller's slot, or the cache-resident array as-is —
+                # staging copies that one into its own buffer, so nothing
+                # downstream mutates it
                 cols[name] = data_parts[0][1]
             elif data_parts:
                 cols[name] = np.concatenate([a for _, a in data_parts])
             else:
-                cols[name] = np.empty(0, dtype=c.type.np_dtype)
+                # no rows: none to copy, so the slot's empty prefix will do
+                cols[name] = fit_slot(d, c.type.np_dtype, 0)
+                if cols[name] is None:
+                    cols[name] = np.empty(0, dtype=c.type.np_dtype)
             if valid_parts:
                 # files without a .valid sibling are all-valid
                 vmap = {r.replace(".valid.ggb", ".ggb"): a for r, a in valid_parts}

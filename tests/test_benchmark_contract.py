@@ -93,6 +93,17 @@ def test_metric_reads_a_name_the_program_produces(traced, spec):
             assert "read:bc" in names and "stage" in names - leaves, names
 
 
+def test_slot_counters_are_the_stats_summed_cold_and_warm(traced):
+    """`stage_in_slot_share` reads two counters: the read units of both
+    runs (two segments x two columns, decoded cold and copied from the
+    block cache warm), every one of them in its staging slot."""
+    for stats, spans in traced["runs"]:
+        assert stats["stage_units"] == stats["stage_units_in_slot"] == 4
+        assert len([s for s in spans if s["name"] == "read:bc"]) == 4
+    assert traced["counters"]["stage_units"] >= 8
+    assert traced["counters"]["stage_units_in_slot"] >= 8
+
+
 @pytest.fixture()
 def db(devices8):
     d = greengage_tpu.connect(numsegments=4)

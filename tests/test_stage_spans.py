@@ -178,8 +178,8 @@ def test_count_star_stages_through_one_unit_a_segment(db):
     snap = db.store.manifest.snapshot()
     got = [db.executor.stager._read_unit("sp_b", None, seg, [], snap, None, None)
            for seg in range(4)]
-    assert all(c == {} and v == {} for c, v, _n, _p in got)
-    assert sum(n for _c, _v, n, _p in got) == 2 * N
+    assert all(c == {} and v == {} for c, v, _n, _p, _slot in got)
+    assert sum(got_n for _c, _v, got_n, _p, _slot in got) == 2 * N
 
 
 def test_virtual_columns_of_one_raw_column_share_a_unit(db):
@@ -343,6 +343,38 @@ def test_concurrent_statements_do_not_share_read_accounts(db):
             sum(s["args"]["io_ms"] for s in reads), 3)
         assert st["read_decode_ms"] == round(
             sum(s["args"]["decode_ms"] for s in reads), 3) > 0
+
+
+@pytest.mark.parametrize("q", [Q, "select sum(v), sum(k) from sp_a where g < 3"],
+                         ids=["plain", "predicate"])
+@pytest.mark.parametrize("warm", [False, True], ids=["miss", "hit"])
+def test_a_unit_fills_its_slot_on_its_own_thread(db, q, warm):
+    """The in-place protocol as the trace shows it: `in_slot` on every
+    `read:` span says how the rows reached the staging slot, decoded there
+    or (a block-cache hit) copied there by the pool thread that ran the
+    unit; no `assemble` span of the statement thread holds a copy."""
+    r, tr = cold(db, q)
+    if warm:
+        db.executor.stager.stage_cache.clear()
+        r, tr = db.sql(q), trace_of(q)
+    spans = tr.export()
+    reads, stage = by_name(spans, "read:sp_a"), by_name(spans, "stage")[0]
+    assert {s["args"]["in_slot"] for s in reads} \
+        == {"copy" if warm else "decode"}
+    for s in reads:
+        a = s["args"]
+        assert s["tid"].startswith("gg-stage") and s["parent"] == stage["id"]
+        assert (a["cache_hits"], a["files"]) == ((1, 0) if warm else (0, 1))
+        assert a["copy_ms"] >= 0 and (a["decode_ms"] == 0) == warm
+        assert a["copy_ms"] + a["decode_ms"] + a["io_ms"] <= s["dur"] + 0.01
+    # the statement thread's `assemble` spans stay leaves: no unit ran
+    # inside one
+    parents = {s["parent"] for s in spans}
+    assert not [s for s in by_name(spans, "assemble") if s["id"] in parents]
+    table = by_name(spans, "stage:sp_a")[0]["args"]
+    assert table["units_in_slot"] == table["read_units"] == len(reads)
+    assert r.stats["stage_units_in_slot"] == r.stats["stage_units"] \
+        == r.stats["stage_read_units"] == len(reads)
 
 
 def test_explicit_parent_and_subtree():
