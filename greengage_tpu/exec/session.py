@@ -36,6 +36,7 @@ from greengage_tpu.analysis.plancheck import validate_plan
 from greengage_tpu.catalog import (Catalog, Column, DistPolicy, Partition,
                                    PolicyKind, TableSchema)
 from greengage_tpu.config import Settings
+from greengage_tpu.exec import staging as _staging
 from greengage_tpu.exec.executor import (Executor, OutOfDeviceMemory,
                                          QueryError, Result)
 from greengage_tpu.parallel import make_mesh
@@ -158,6 +159,8 @@ class Database:
         self._select_cache: dict = _OD()
         # per-thread (threaded SQL server): see the _plan_cache_info property
         self._pc_info_local = threading.local()
+        # the DML statement a thread is running (_dml): its _DmlRun
+        self._dml_local = threading.local()
         # statement signatures the binder proved unparameterizable: later
         # literal variants of the shape skip the doomed normalized bind
         # and go straight to the value-pinned plan (bounded backstop)
@@ -1519,7 +1522,7 @@ class Database:
                      if self._append_needs_table_lock(stmt.table)
                      else _NullSlot()):
                 if isinstance(stmt, A.InsertStmt):
-                    out = self._insert(stmt)
+                    out = self._dml(self._insert, stmt)
                 else:
                     out = self._copy(stmt)
                 self._post_commit()
@@ -1607,20 +1610,14 @@ class Database:
                     shutil.rmtree(os.path.join(self.path, "data", st),
                                   ignore_errors=True)
             return "DROP TABLE"
-        if isinstance(stmt, A.InsertStmt):
-            out = self._insert(stmt)
-            self._post_commit()
-            return out
         if isinstance(stmt, A.CopyStmt):
             out = self._copy(stmt)
             self._post_commit()
             return out
-        if isinstance(stmt, A.DeleteStmt):
-            out = self._delete(stmt)
-            self._post_commit()
-            return out
-        if isinstance(stmt, A.UpdateStmt):
-            out = self._update(stmt)
+        if isinstance(stmt, (A.InsertStmt, A.DeleteStmt, A.UpdateStmt)):
+            out = self._dml({A.InsertStmt: self._insert,
+                             A.DeleteStmt: self._delete,
+                             A.UpdateStmt: self._update}[type(stmt)], stmt)
             self._post_commit()
             return out
         if isinstance(stmt, A.CreateExternalTableStmt):
@@ -2053,15 +2050,27 @@ class Database:
 
     def _scalar_subquery(self, stmt):
         """Run an uncorrelated scalar subquery at bind time (InitPlan
-        analog): the value is inlined as a literal into the outer plan."""
-        planned, consts, outs = self._plan(stmt)
-        if len(outs) != 1:
-            raise SqlError("scalar subquery must return one column")
-        aux, dirty = self._load_external_aux(planned)
-        if dirty:
-            planned, consts, outs = self._plan(stmt)
-        res = self.executor.run(planned, consts, outs,
-                                aux_tables=aux or None)
+        analog): the value is inlined as a literal into the outer plan,
+        or, in a DML statement's inner SELECT, hoisted into its parameter
+        vector (sql/paramize.py). Planned and compiled through the plan
+        and program caches like any SELECT; the outer statement's
+        plan-cache report is put back afterwards."""
+        local = self._pc_info_local
+        outer = (getattr(local, "info", {}), getattr(local, "planned", None))
+        try:
+            planned, consts, outs, ek = self._cached_plan(stmt)
+            if len(outs) != 1:
+                raise SqlError("scalar subquery must return one column")
+            aux, dirty = self._load_external_aux(planned)
+            if dirty:
+                planned, consts, outs, ek = self._cached_plan(stmt)
+            res = self.executor.run(planned, consts, outs, cache_key=ek,
+                                    aux_tables=aux or None)
+            if isinstance(res.stats, dict):
+                res.stats["plan_cache"] = dict(self._plan_cache_info)
+            self._dml_note(res)
+        finally:
+            local.info, local.planned = outer
         if len(res) > 1:
             raise SqlError("more than one row returned by a scalar subquery")
         t = outs[0].type
@@ -2235,7 +2244,8 @@ class Database:
             info["params"] = 0
         return consts
 
-    def _cached_plan(self, stmt, force_multi_join: bool = False):
+    def _cached_plan(self, stmt, force_multi_join: bool = False,
+                     hoist_subqueries: bool = False):
         """Memoized planning for SELECT-shaped statements (plain SELECT
         and the DECLARE CURSOR body) — the plancache.c prepared-statement
         role. Plan-safe literals are hoisted into a parameter vector
@@ -2250,15 +2260,20 @@ class Database:
         executor's SHAPE signature decides executable reuse across
         versions. A force_multi_join re-plan is remembered under the
         PLAIN key so repeats skip the failing unique-join program. Real
-        LRU, bounded by the plan_cache_size GUC.
+        LRU, bounded by the plan_cache_size GUC. ``hoist_subqueries`` (a
+        DML statement's inner SELECT) runs the uncorrelated scalar
+        subqueries HERE, every time, and hoists their values like
+        literals: the statement that follows a write re-reads them and
+        still finds its program.
         -> (planned, consts, outs, exec_key)."""
         from greengage_tpu.sql.paramize import ParamVector, paramize
 
         version = self.store.manifest.snapshot().get("version", 0)
         with _trace.span("paramize", cat="plan"):
-            norm, pv, sig = (paramize(stmt, self.catalog)
-                             if self.settings.plan_cache_params
-                             else (stmt, None, None))
+            norm, pv, sig = (
+                paramize(stmt, self.catalog,
+                         self._scalar_subquery if hoist_subqueries else None)
+                if self.settings.plan_cache_params else (stmt, None, None))
         if sig is not None and sig in self._paramize_fallback:
             # this shape is known-unparameterizable: plan value-pinned
             # directly instead of re-paying the doomed normalized bind
@@ -2377,7 +2392,8 @@ class Database:
         cur = self.dtm.current
         return cur is None or cur.state != "active"
 
-    def _select(self, stmt: A.SelectStmt) -> Result:
+    def _select(self, stmt: A.SelectStmt,
+                hoist_subqueries: bool = False) -> Result:
         rctes = getattr(stmt, "_recursive_ctes", None)
         if rctes:
             return self._select_recursive(stmt, rctes)
@@ -2400,14 +2416,16 @@ class Database:
                     # fall through to the ConstRel device path; the
                     # screen above keeps InitPlan subqueries from running
                     # twice for the COMMON fallthrough shapes
-        planned, consts, outs, exec_key = self._cached_plan(stmt)
+        planned, consts, outs, exec_key = self._cached_plan(
+            stmt, hoist_subqueries=hoist_subqueries)
         pc_info = self._plan_cache_info
         # external tables materialize to host arrays before execution
         # (fileam external_beginscan role); first-seen strings grow the
         # dictionary, so the bound plan refreshes afterwards
         aux, dirty = self._load_external_aux(planned)
         if dirty:
-            planned, consts, outs, exec_key = self._cached_plan(stmt)
+            planned, consts, outs, exec_key = self._cached_plan(
+                stmt, hoist_subqueries=hoist_subqueries)
             pc_info = self._plan_cache_info
         # resource-queue admission (ResLockPortal analog): bound concurrent
         # mesh statements; excess statements queue or time out. Multi-host
@@ -3029,6 +3047,43 @@ class Database:
         self._post_commit()
         return "ALTER TABLE"
 
+    # ---- DML: one answer shape, one account ----------------------------
+    def _dml(self, handler, stmt) -> "DmlResult":
+        """Run an INSERT / DELETE / UPDATE handler (-> its command tag)
+        under a fresh account of its inner statements and its two phases
+        -> the tag as a DmlResult."""
+        run, prev = _DmlRun(), getattr(self._dml_local, "run", None)
+        self._dml_local.run = run
+        try:
+            tag = handler(stmt)
+        finally:
+            self._dml_local.run = prev
+        out = run.answer(tag)
+        # rows_inserted is the store's (every append path passes there);
+        # a deletion is a bitmap or a republish, so it is counted here
+        _counters.inc("rows_deleted", out.stats["rows_deleted"])
+        return out
+
+    def _dml_note(self, res) -> None:
+        """An inner statement of the running DML statement (a scalar
+        subquery, the inner SELECT, the predicate scan) finished."""
+        run = getattr(self._dml_local, "run", None)
+        if run is not None and isinstance(getattr(res, "stats", None), dict):
+            run.inner.append(res.stats)
+
+    @_contextmanager
+    def _dml_phase(self, name: str):
+        """`dml_scan` (what the statement reads) or `write` (everything
+        after): a span, and the phase's clock in the statement's account."""
+        run = getattr(self._dml_local, "run", None)
+        t0 = time.monotonic()
+        try:
+            with _trace.span(name, cat="dml"):
+                yield
+        finally:
+            if run is not None:
+                run.ms[name] += (time.monotonic() - t0) * 1e3
+
     def _insert(self, stmt: A.InsertStmt):
         schema = self.catalog.get(stmt.table)
         ext = self._external_def(schema)
@@ -3072,7 +3127,8 @@ class Database:
             va = np.array(valids[n], dtype=bool)
             if not va.all():
                 enc_valids[n] = va
-        n = self._write_rows(stmt.table, enc_cols, enc_valids)
+        with self._dml_phase("write"):
+            n = self._write_rows(stmt.table, enc_cols, enc_valids)
         return f"INSERT 0 {n}"
 
     def _insert_select(self, schema, ext, stmt) -> str:
@@ -3080,8 +3136,11 @@ class Database:
         values back to storage representation, and either append to the
         table or — for WRITABLE EXTERNAL tables — emit CSV to the
         location/command (the gpfdist WET/EXECUTE writer role)."""
-        res = self._select(stmt.query) if not isinstance(stmt.query, A.UnionStmt) \
-            else self._execute(stmt.query)
+        with self._dml_phase("dml_scan"):
+            res = (self._select(stmt.query, hoist_subqueries=True)
+                   if not isinstance(stmt.query, A.UnionStmt)
+                   else self._execute(stmt.query))
+            self._dml_note(res)
         names = stmt.columns or schema.column_names
         if set(names) != set(schema.column_names):
             raise SqlError("INSERT must provide all columns")
@@ -3094,10 +3153,20 @@ class Database:
                 raise SqlError(
                     f'cannot write to READABLE external table "{schema.name}"')
             return self._write_external(schema, ext, res)
+        with self._dml_phase("write"):
+            with _trace.span("encode", cat="dml", rows=len(res)):
+                cols, valids = self._storage_values(schema, names, res)
+            n = self._write_rows(schema.name, cols, valids)
+            self._post_commit()
+        return f"INSERT 0 {n}"
+
+    @staticmethod
+    def _storage_values(schema, names, res) -> tuple[dict, dict]:
+        """A Result's presented columns back in storage representation,
+        under the target's column names -> (columns, validity masks)."""
         cols: dict = {}
         valids: dict = {}
-        order = res._order
-        for n, oid in zip(names, order):
+        for n, oid in zip(names, res._order):
             c = schema.column(n)
             data = res.cols[oid]
             v = res.valids.get(oid)
@@ -3121,9 +3190,7 @@ class Database:
             cols[n] = data
             if v is not None:
                 valids[n] = np.asarray(v, dtype=bool)
-        n = self._write_rows(schema.name, cols, valids)
-        self._post_commit()
-        return f"INSERT 0 {n}"
+        return cols, valids
 
     def _write_external(self, schema, ext, res) -> str:
         buf = io.StringIO()
@@ -3427,8 +3494,16 @@ class Database:
         return tx
 
     def _run_raw(self, sel_stmt):
-        planned, consts, outs = self._plan(sel_stmt)
-        res = self.executor.run(planned, consts, outs, raw=True)
+        """A DML statement's own scan, in storage representation: planned
+        and compiled through the plan and program caches like a SELECT,
+        its scalar subqueries hoisted, so the statement finds its program
+        again after the write it follows."""
+        planned, consts, outs, ek = self._cached_plan(sel_stmt,
+                                                      hoist_subqueries=True)
+        res = self.executor.run(planned, consts, outs, cache_key=ek, raw=True)
+        if isinstance(res.stats, dict):
+            res.stats["plan_cache"] = dict(self._plan_cache_info)
+        self._dml_note(res)
         return res, outs
 
     def _check_dml_target(self, table: str):
@@ -3543,11 +3618,14 @@ class Database:
             off += live
             if not m.any():
                 continue
-            newdel = (np.zeros(full[seg], np.uint8) if keep is None
-                      else (~keep).astype(np.uint8))
-            live_pos = (np.flatnonzero(keep) if keep is not None
-                        else np.arange(full[seg]))
-            newdel[live_pos[m]] = 1
+            if keep is None:
+                newdel = m.astype(np.uint8)
+            else:
+                # the predicate's mask laid over the live rows; no index
+                # array of them (8 bytes a row of a 60M-row segment)
+                newdel = ~keep
+                newdel[keep] = m
+                newdel = newdel.view(np.uint8)
             masks[seg] = newdel
         if off != len(pred_mask):
             raise RuntimeError(
@@ -3563,6 +3641,24 @@ class Database:
         tx = self._tx_for_dml(stmt.table, "DELETE")
         _reject_dml_subqueries(stmt.where)
         schema = self.catalog.get(stmt.table)
+        if not schema.is_partitioned and stmt.where is not None:
+            # visimap path (appendonly_visimap.c analog): publish a
+            # deletion bitmap instead of rewriting the table — DELETE
+            # stages only the predicate's columns and writes O(bitmap),
+            # not O(table)
+            with self._dml_phase("dml_scan"):
+                mask = self._predicate_mask(stmt.table, stmt.where)
+            if worker_scan_only:
+                return "DELETE 0"   # lockstep scan only; coordinator publishes
+            with self._dml_phase("write"):
+                with _trace.span("delmask", cat="dml", phase="merge"):
+                    masks = self._visimap_masks(stmt.table, mask)
+                if masks:
+                    if tx is not None:
+                        tx.set_delmask(stmt.table, masks)
+                    else:
+                        self.store.set_delmask(stmt.table, masks)
+            return f"DELETE {int(mask.sum())}"
         # VISIBLE rows (manifest counts minus deletion bitmaps): the
         # reported DELETE count must not re-count already-deleted rows
         total = sum(self.store.live_rowcounts(stmt.table))
@@ -3574,29 +3670,16 @@ class Database:
                 0, dtype=(np.int64 if c.name in raw_names
                           else c.type.np_dtype)) for c in schema.columns}
             raw_strs = {n: np.empty(0, dtype=object) for n in raw_names}
-            self._replace_table(schema, empty, {}, tx, raw_strs or None)
+            with self._dml_phase("write"):
+                self._replace_table(schema, empty, {}, tx, raw_strs or None)
             return f"DELETE {total}"
-        if not schema.is_partitioned:
-            # visimap path (appendonly_visimap.c analog): publish a
-            # deletion bitmap instead of rewriting the table — DELETE
-            # stages only the predicate's columns and writes O(bitmap),
-            # not O(table)
-            mask = self._predicate_mask(stmt.table, stmt.where)
-            if worker_scan_only:
-                return "DELETE 0"   # lockstep scan only; coordinator publishes
-            masks = self._visimap_masks(stmt.table, mask)
-            if masks:
-                if tx is not None:
-                    tx.set_delmask(stmt.table, masks)
-                else:
-                    self.store.set_delmask(stmt.table, masks)
-            return f"DELETE {int(mask.sum())}"
         # partitioned fallback: republish survivors (predicate false OR
         # NULL) — per-child bitmaps need per-child row spans, deferred
         survive = A.Bin("or", A.Unary("not", stmt.where), A.IsNullTest(stmt.where, False))
         sel = A.SelectStmt(items=[A.SelectItem(A.Star())],
                            from_=[A.BaseTable(stmt.table)], where=survive)
-        res, outs = self._run_raw(sel)
+        with self._dml_phase("dml_scan"):
+            res, outs = self._run_raw(sel)
         if worker_scan_only:
             return "DELETE 0"
         enc = {}
@@ -3614,7 +3697,8 @@ class Database:
                                                    dtype=c.type.np_dtype)
             if v is not None:
                 valids[c.name] = v
-        self._replace_table(schema, enc, valids, tx, raw_strs or None)
+        with self._dml_phase("write"):
+            self._replace_table(schema, enc, valids, tx, raw_strs or None)
         return f"DELETE {total - len(res)}"
 
     def _update(self, stmt: A.UpdateStmt, worker_scan_only: bool = False):
@@ -3675,11 +3759,12 @@ class Database:
         # O(table). Partitioned / whole-table UPDATEs keep the republish.
         visimap = not schema.is_partitioned and stmt.where is not None
         pred_mask = None
-        if visimap:
-            pred_mask = self._predicate_mask(stmt.table, stmt.where)
         sel = A.SelectStmt(items=items, from_=[A.BaseTable(stmt.table)],
                            where=stmt.where if visimap else None)
-        res, outs = self._run_raw(sel)
+        with self._dml_phase("dml_scan"):
+            if visimap:
+                pred_mask = self._predicate_mask(stmt.table, stmt.where)
+            res, outs = self._run_raw(sel)
         if worker_scan_only:
             return "UPDATE 0"   # multi-host worker: scan only, no publish
         fo = outs[flag_slot]
@@ -3731,15 +3816,18 @@ class Database:
                     f"UPDATE matched-row scan returned {len(res)} rows but "
                     f"the predicate pass marked {int(pred_mask.sum())} — "
                     "concurrent write raced the statement; retry")
-            masks = self._visimap_masks(stmt.table, pred_mask)
-            with self._autocommit_tx() as atx:
-                if masks:
-                    atx.set_delmask(stmt.table, masks)
-                if len(res):
-                    atx.insert_encoded(stmt.table, enc, valids,
-                                       raw_strs or None)
+            with self._dml_phase("write"):
+                with _trace.span("delmask", cat="dml", phase="merge"):
+                    masks = self._visimap_masks(stmt.table, pred_mask)
+                with self._autocommit_tx() as atx:
+                    if masks:
+                        atx.set_delmask(stmt.table, masks)
+                    if len(res):
+                        atx.insert_encoded(stmt.table, enc, valids,
+                                           raw_strs or None)
             return f"UPDATE {int(pred_mask.sum())}"
-        self._replace_table(schema, enc, valids, tx, raw_strs or None)
+        with self._dml_phase("write"):
+            self._replace_table(schema, enc, valids, tx, raw_strs or None)
         return f"UPDATE {int(mask.sum())}"
 
     # ------------------------------------------------------------------
@@ -3891,6 +3979,62 @@ class _RWLock:
                     self._c.notify_all()
 
         return _shared_cm()
+
+
+class DmlResult(str):
+    """What `Database.sql` answers an INSERT, DELETE or UPDATE with: its
+    command tag — it IS that string, so `db.sql("delete ...") == "DELETE
+    2"` holds and every caller that prints or forwards a tag still does —
+    which also answers like a SELECT's Result: `rows()` is one row (tag,
+    rows affected) and `stats` holds a SELECT's keys where they apply,
+    summed over the statement's inner statements, plus `dml_scan_ms`,
+    `write_ms`, `rows_written` and `rows_deleted`."""
+
+    def __new__(cls, tag: str, stats: dict | None = None):
+        self = super().__new__(cls, tag)
+        self.stats = stats if stats is not None else {}
+        return self
+
+    def __reduce__(self):
+        return (DmlResult, (str(self), self.stats))
+
+    @property
+    def nrows(self) -> int:
+        return int(self.rsplit(" ", 1)[1])
+
+    def rows(self) -> list[tuple]:
+        return [(str(self), self.nrows)]
+
+
+class _DmlRun:
+    """One DML statement's account (Database._dml): the statistics of its
+    inner statements as they finish and its two phases' clocks."""
+
+    # a SELECT's statistics that add up over a statement's inner
+    # statements: the phases of each attempt and the staging counts
+    SUMMED = ("compile_ms", "stage_ms", "compute_ms", "fetch_ms",
+              *_staging.STAGE_COUNTERS)
+
+    def __init__(self):
+        self.inner: list[dict] = []
+        self.ms = {"dml_scan": 0.0, "write": 0.0}
+
+    def answer(self, tag: str) -> DmlResult:
+        out = DmlResult(tag)
+        verb = tag.split(" ", 1)[0]
+        caches = [s.get("plan_cache") or {} for s in self.inner]
+        out.stats.update(
+            {k: round(sum(s.get(k, 0) for s in self.inner), 2)
+             for k in self.SUMMED},
+            compiled=any(s.get("compiled") for s in self.inner),
+            plan_cache={"hit": all(c.get("hit") for c in caches),
+                        "params": sum(c.get("params", 0) for c in caches)},
+            inner_statements=len(self.inner),
+            dml_scan_ms=round(self.ms["dml_scan"], 2),
+            write_ms=round(self.ms["write"], 2),
+            rows_written=out.nrows if verb in ("INSERT", "UPDATE") else 0,
+            rows_deleted=out.nrows if verb in ("DELETE", "UPDATE") else 0)
+        return out
 
 
 class _DegradedResult:

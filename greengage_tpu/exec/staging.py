@@ -54,10 +54,16 @@ from greengage_tpu.storage.blockcache import MISS
 SCAN_COUNTERS = ("scan_files_read", "scan_bytes_decoded", "scan_cache_hit",
                  "scan_cache_miss", "scan_cache_evict")
 # how often the in-place protocol (Stager._submit) engages: the read units
-# of `read` tables, and those whose every column landed in its staging slot
-# on the thread that ran the unit. Counters, Result.stats keys and (less
-# the prefix) arguments of the `stage:<table>` span alike
-SLOT_COUNTERS = ("stage_units", "stage_units_in_slot")
+# of `read` tables; those whose every column landed in its staging slot on
+# the thread that ran the unit; those that were offered their slots and
+# left the in-place path because the table has been written to (several
+# data files a column; a deletion bitmap, which counts where both hold);
+# and the `read` tables whose pushed zone-map predicates a bitmap switched
+# off. Counters, Result.stats keys and (less the prefix) arguments of the
+# `stage:<table>` span alike
+STAGE_COUNTERS = ("stage_units", "stage_units_in_slot",
+                  "stage_units_copy_files", "stage_units_copy_delmask",
+                  "zone_prune_skipped_delmask")
 
 
 def scan_thread_count(settings) -> int:
@@ -232,10 +238,13 @@ def _land(st, u, per_seg) -> list:
     for seg, fut in enumerate(row):
         if fut is None:
             continue
-        c, v, n, pstat, in_slot = fut.result()
+        c, v, n, pstat, in_slot, io = fut.result()
         per_seg[seg][0].update(c)
         per_seg[seg][1].update(v)
         st["in_slot"] += in_slot != "no"
+        if io.off_slot:
+            st["copy_" + io.off_slot] += 1
+        st["prune_skipped"] |= io.prune_skipped
         out.append((seg, n, pstat))
     return out
 
@@ -247,8 +256,8 @@ class Staged:
     sid: int = -1             # the `stage` span, for the caller's annotate
     stage_ms: float = 0.0
     scan_io: dict = field(default_factory=dict)  # SCAN_COUNTERS deltas
-    units: dict = field(                         # SLOT_COUNTERS, this call's
-        default_factory=lambda: dict.fromkeys(SLOT_COUNTERS, 0))
+    units: dict = field(                         # STAGE_COUNTERS, this call's
+        default_factory=lambda: dict.fromkeys(STAGE_COUNTERS, 0))
     split: dict = field(default_factory=dict)    # stage_*_ms, read_*: spans
     zone_prune: dict = field(default_factory=dict)   # table: (kept, blocks)
     # runtime PartitionSelector results: child partitions kept / total
@@ -359,10 +368,15 @@ class Stager:
         out.stage_ms = (time.monotonic() - t0) * 1e3
         out.scan_io = {k: counters.get(k) - io0[k] for k in SCAN_COUNTERS}
         _trace.annotate(out.sid, **out.scan_io)
-        if out.units["stage_units"]:
-            counters.inc("stage_units", out.units["stage_units"])
-            counters.inc("stage_units_in_slot",
-                         out.units["stage_units_in_slot"])
+        u = out.units
+        if u["stage_units"]:
+            counters.inc("stage_units", u["stage_units"])
+            counters.inc("stage_units_in_slot", u["stage_units_in_slot"])
+            counters.inc("stage_units_copy_files", u["stage_units_copy_files"])
+            counters.inc("stage_units_copy_delmask",
+                         u["stage_units_copy_delmask"])
+            counters.inc("zone_prune_skipped_delmask",
+                         u["zone_prune_skipped_delmask"])
         out.split = _stage_split(out.sid)
         return out
 
@@ -374,7 +388,11 @@ class Stager:
         # unreachable and only waste HBM — the dispatcher's
         # CdbComponentDatabases invalidation analog)
         version = snapshot.get("version", 0)
-        self.store.blockcache.invalidate_versions(version)
+        dropped: dict = {}
+        self.store.blockcache.invalidate_versions(version, dropped)
+        # staged device inputs a write made unreachable: what the scans
+        # after a refresh stage again
+        counters.inc("stage_cache_dropped", dropped.get("stage", 0))
         plans = []
         claimed = set()
         for table, cols, cap, direct, prune, child_parts, dyn in comp.input_spec:
@@ -444,7 +462,7 @@ class Stager:
         own: several data files for the column, a deletion bitmap, a
         virtual '@' column, a slot of another dtype or too short; so do
         validity masks (a byte a row). (3) ``_read_unit`` says which
-        happened (``in_slot`` on its `read:` span, SLOT_COUNTERS) and
+        happened (``in_slot`` on its `read:` span, STAGE_COUNTERS) and
         ``_fill_column`` learns it by identity (``arr.base is buf``) and
         copies only what is not already in place."""
         _, table, cols, cap, _key, prune, st = p
@@ -484,7 +502,10 @@ class Stager:
         st["buffers"] = buffers
         st["futs"] = futs
         st["read_units"] = len(futs) * len(segs)
-        st["in_slot"] = 0   # of them, landed in their slots (_land)
+        # of them (_land): landed in their slots; left the in-place path
+        # over data files / a deletion bitmap; a bitmap switched pruning off
+        st["in_slot"] = st["copy_files"] = st["copy_delmask"] = 0
+        st["prune_skipped"] = False
 
     def _assemble(self, plans, snapshot, aux, out: Staged) -> list:
         """Assemble phase (spec order, deterministic): fill staging
@@ -578,9 +599,14 @@ class Stager:
                 _trace.annotate(_sp_t, rows=int(sum(n for _, _, n in per_seg)),
                                 bytes=nbytes, segments=len(per_seg),
                                 read_units=st["read_units"],
-                                units_in_slot=st["in_slot"])
-                out.units["stage_units"] += st["read_units"]
-                out.units["stage_units_in_slot"] += st["in_slot"]
+                                units_in_slot=st["in_slot"],
+                                units_copy_files=st["copy_files"],
+                                units_copy_delmask=st["copy_delmask"],
+                                prune_skipped_delmask=st["prune_skipped"])
+                for name, n in zip(STAGE_COUNTERS, (
+                        st["read_units"], st["in_slot"], st["copy_files"],
+                        st["copy_delmask"], int(st["prune_skipped"]))):
+                    out.units[name] += n
                 if st["rng"] is None:
                     self.stage_cache.put(
                         key, staged_local[key], nbytes=nbytes,
@@ -645,14 +671,16 @@ class Stager:
                         io_ms=round(io.io_ns / 1e6, 3),
                         decode_ms=round(io.decode_ns / 1e6, 3),
                         copy_ms=round(io.copy_ns / 1e6, 3),
-                        in_slot=in_slot)
+                        in_slot=in_slot,
+                        **({"off_slot": io.off_slot} if io.off_slot else {}))
         if rng is not None:
             a, b = rng
             c = {k: arr[a:b] for k, arr in c.items()}
             v = {k: (arr[a:b] if arr is not None else None)
                  for k, arr in v.items()}
             n = max(min(n, b) - a, 0)
-        return c, v, n, (self.store.last_prune if prune else None), in_slot
+        return (c, v, n, (self.store.last_prune if prune else None), in_slot,
+                io)
 
     def _fill_column(self, schema, c, cap, per_seg, buffers) -> np.ndarray:
         """One column's [nseg*cap] host buffer, zero past each segment's

@@ -175,6 +175,38 @@ def _range_sel(cs, v: float, op: str) -> float:
     return float(min(max(s, 0.0), 1.0)) * (1.0 - cs.null_frac)
 
 
+def _pair_ranges(conjuncts, lookup) -> list:
+    """A lower and an upper bound on one column with statistics are one
+    range, not two independent filters: the rows between them are
+    sel(upper) + sel(lower) - 1 of the non-null ones (clauselist_selectivity's
+    range pairing). -> the conjuncts, each such pair replaced by its joint
+    selectivity as a float. Multiplying the two sides instead makes the
+    estimate of `k between lo and lo + n` grow with lo: every refresh of a
+    warehouse's oldest keys would move its capacity buckets."""
+    if lookup is None:
+        return list(conjuncts)
+    out, open_bounds = [], {}   # column -> (index in out, side, selectivity)
+    for c in conjuncts:
+        info = _col_and_lit(c) if isinstance(c, E.Cmp) else None
+        cs = lookup(info[0]) if info else None
+        side = {"<": "hi", "<=": "hi", ">": "lo", ">=": "lo"}.get(
+            info[2]) if cs is not None else None
+        if side is None:
+            out.append(c)
+            continue
+        col, v, op = info
+        sel = _range_sel(cs, v, op)
+        first = open_bounds.get(col)
+        if first is not None and first[1] != side:
+            joint = sel + first[2] - (1.0 - cs.null_frac)
+            out[first[0]] = float(max(joint, 1e-6))
+            del open_bounds[col]
+        else:
+            open_bounds.setdefault(col, (len(out), side, sel))
+            out.append(c)
+    return out
+
+
 def filter_selectivity(pred: E.Expr, lookup=None) -> float:
     """Estimated fraction of rows passing ``pred``. ``lookup`` maps a
     column id to its ColumnStats (or None) when the caller can resolve
@@ -207,8 +239,8 @@ def filter_selectivity(pred: E.Expr, lookup=None) -> float:
         return max(1.0 - filter_selectivity(pred.arg, lookup), 1e-4)
     if isinstance(pred, E.BoolOp) and pred.op == "and":
         s = 1.0
-        for a in pred.args:
-            s *= filter_selectivity(a, lookup)
+        for a in _pair_ranges(pred.args, lookup):
+            s *= a if isinstance(a, float) else filter_selectivity(a, lookup)
         return max(s, 1e-4)
     if isinstance(pred, E.BoolOp) and pred.op == "or":
         s = 0.0

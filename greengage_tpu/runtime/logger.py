@@ -90,6 +90,19 @@ COUNTER_NAMES = (
     # those whose every column landed in its staging slot on the thread
     # that ran the unit — in_slot / units is how often it engages
     "stage_units", "stage_units_in_slot",
+    # the read path after a write (exec/staging.py): read units that were
+    # offered their slots and left the in-place path because a column is
+    # several data files or the table has a deletion bitmap (a unit under
+    # both counts as delmask), read tables whose pushed zone-map
+    # predicates a bitmap switched off, and staged device inputs a
+    # manifest bump made unreachable
+    "stage_units_copy_files", "stage_units_copy_delmask",
+    "zone_prune_skipped_delmask", "stage_cache_dropped",
+    # the write path (storage/table_store.py, storage/blockfile.py,
+    # exec/session.py): rows appended, rows a DML statement deleted,
+    # bytes of block files written (data, validity, bitmaps), and commit
+    # lines appended to the manifest's log (delta and intent alike)
+    "rows_inserted", "rows_deleted", "write_bytes", "manifest_commits",
     # storage self-heal (storage/table_store.py, storage/scrub.py)
     "storage_repair", "storage_standby_repair", "storage_quarantine",
     "storage_scrub_runs", "storage_scrub_files",

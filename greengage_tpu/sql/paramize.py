@@ -122,8 +122,11 @@ def _literal_of(node):
 
 
 class _Paramizer:
-    def __init__(self, catalog):
+    def __init__(self, catalog, subquery_executor=None):
         self.params: list[tuple] = []   # (value, SqlType)
+        # runs an uncorrelated scalar subquery -> (storage value, SqlType);
+        # None leaves subqueries to the binder, which inlines them
+        self.subquery_executor = subquery_executor
         # column names whose comparisons stay pinned: partition keys for
         # every op (static partition pruning is a plan-time decision),
         # hash-distribution keys for equality (direct dispatch). Matching
@@ -156,6 +159,13 @@ class _Paramizer:
     # ------------------------------------------------------------------
     def _hoist(self, node):
         lit = _literal_of(node)
+        if lit is None and self.subquery_executor is not None \
+                and isinstance(node, A.ScalarSubquery):
+            # a correlated one raises at its bind: paramize() then hands
+            # the statement back whole and the binder decorrelates it
+            lit = self.subquery_executor(node.query)
+            if lit[0] is None:
+                return node   # NULL stays the binder's typed literal
         if lit is None:
             return node
         v, t = lit
@@ -280,18 +290,20 @@ class _Paramizer:
             self._join_on(ref.right)
 
 
-def paramize(stmt, catalog):
+def paramize(stmt, catalog, subquery_executor=None):
     """-> (normalized stmt, ParamVector, signature) for SELECT-shaped
     statements, or (stmt, None, None) when nothing was hoisted. The
     normalized statement is a deep copy with hoistable literals replaced
     by A.ParamRef nodes; the signature is its value-free repr (ParamRef
-    reprs carry the literal TYPES, so only same-typed shapes share it)."""
+    reprs carry the literal TYPES, so only same-typed shapes share it).
+    With ``subquery_executor`` an uncorrelated scalar subquery in a
+    hoistable position is run and hoisted like a literal of its value."""
     if not isinstance(stmt, (A.SelectStmt, A.UnionStmt)):
         return stmt, None, None
     if getattr(stmt, "_recursive_ctes", None):
         return stmt, None, None   # fixpoint terms re-execute via session
     norm = copy.deepcopy(stmt)
-    p = _Paramizer(catalog)
+    p = _Paramizer(catalog, subquery_executor)
     try:
         if isinstance(norm, A.UnionStmt):
             for s in norm.selects:

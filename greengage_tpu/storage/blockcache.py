@@ -232,10 +232,12 @@ class CacheRegistry:
             self._total -= ent[1]
             counters.inc("scan_cache_evict")
 
-    def invalidate_versions(self, keep_version: int) -> int:
+    def invalidate_versions(self, keep_version: int,
+                            by_cache: dict | None = None) -> int:
         """Drop every version-tagged entry from another manifest version
         (the manifest-bump invalidation); untagged entries — immutable
-        committed files — stay. -> count removed."""
+        committed files — stay. -> count removed (and, a cache's name,
+        in ``by_cache``: what a write cost the readers after it)."""
         removed = 0
         with self._lock:
             for c in self._caches.values():
@@ -246,6 +248,8 @@ class CacheRegistry:
                     c.bytes -= ent[1]
                     self._total -= ent[1]
                 removed += len(victims)
+                if by_cache is not None and victims:
+                    by_cache[c.name] = len(victims)
         return removed
 
     def clear(self) -> None:

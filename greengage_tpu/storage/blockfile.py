@@ -30,6 +30,7 @@ from contextlib import contextmanager
 
 import numpy as np
 
+from greengage_tpu.runtime.logger import counters
 from greengage_tpu.storage import native
 from greengage_tpu.storage.corruption import CorruptionError
 
@@ -50,7 +51,8 @@ class ReadTally:
     which a process-wide counter cannot give."""
 
     __slots__ = ("files", "cache_hits", "bytes_read", "bytes_decoded",
-                 "io_ns", "decode_ns", "slot_copies", "copy_ns")
+                 "io_ns", "decode_ns", "slot_copies", "copy_ns",
+                 "off_slot", "prune_skipped")
 
     def __init__(self):
         self.files = self.cache_hits = 0        # decoded / block-cache hit
@@ -59,6 +61,14 @@ class ReadTally:
         # block-cache hits copied into the caller's slot (read_file), and
         # the time in those copies
         self.slot_copies = self.copy_ns = 0
+        # what read_segment saw in a table that has been written to since
+        # its load: why a column that was offered its staging slot kept an
+        # array of its own ("delmask": a deletion bitmap filters the rows
+        # after assembly; "files": several data files are concatenated;
+        # "" where none did), and whether a bitmap switched the pushed
+        # zone-map predicates off
+        self.off_slot = ""
+        self.prune_skipped = False
 
 
 _tally = threading.local()
@@ -142,6 +152,7 @@ def write_column_file(path: str, values: np.ndarray, compresstype: str = "zlib",
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, path)
+    counters.inc("write_bytes", off + len(fj) + FOOTER_TAIL)
     return footer
 
 

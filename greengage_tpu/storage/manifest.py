@@ -639,6 +639,7 @@ class Manifest:
             finally:
                 os.close(fd)
         counters.inc("manifest_delta_commits")
+        counters.inc("manifest_commits")
         return self.version()
 
     def abort_delta(self, handle: dict) -> None:
@@ -737,6 +738,7 @@ class Manifest:
         except OSError:
             pass
         counters.inc("manifest_intent_commits")
+        counters.inc("manifest_commits")
         return self.version()
 
     def abort_intent(self, handle: dict) -> None:

@@ -31,6 +31,7 @@ import numpy as np
 
 from greengage_tpu import types as T
 from greengage_tpu.catalog import Catalog, PolicyKind, TableSchema
+from greengage_tpu.runtime import trace as _trace
 from greengage_tpu.runtime.faultinject import FaultError, faults
 from greengage_tpu.runtime.logger import counters
 from greengage_tpu.storage import native
@@ -706,45 +707,46 @@ class TableStore:
         dict_sizes = {c.name: len(self.dictionary(table, c.name))
                       for c in schema.columns
                       if c.type.kind is T.Kind.TEXT and c.encoding != "raw"}
-        for c in schema.columns:
-            if c.name not in columns:
-                raise ValueError(f"missing column {c.name}")
-            raw = columns[c.name]
-            if c.type.kind is T.Kind.TEXT:
-                c = self._resolve_text_encoding(schema, c, raw)
-                if c.encoding == "raw":
-                    vals = (raw.decode() if isinstance(raw, T.Coded)
-                            else np.asarray(raw, dtype=object))
-                    raw_strs[c.name] = vals
-                    # placeholder for ragged checks; never hashed (raw
-                    # distribution keys are rejected in _resolve)
-                    arr = np.zeros(len(vals), dtype=np.int64)
-                    enc[c.name] = arr
-                    nrows = len(arr) if nrows is None else nrows
-                    if len(arr) != nrows:
-                        raise ValueError("ragged insert")
-                    continue
-                d = self.dictionary(table, c.name)
-                vmask = valids.get(c.name)
-                if isinstance(raw, T.Coded):
-                    arr = d.encode_coded(list(raw.vocab), raw.codes)
-                    if vmask is not None:
-                        arr = np.where(vmask, arr, d.encode([""])[0])
-                elif vmask is None:
-                    arr = d.encode(list(raw))
+        with _trace.span("encode", cat="write", table=table):
+            for c in schema.columns:
+                if c.name not in columns:
+                    raise ValueError(f"missing column {c.name}")
+                raw = columns[c.name]
+                if c.type.kind is T.Kind.TEXT:
+                    c = self._resolve_text_encoding(schema, c, raw)
+                    if c.encoding == "raw":
+                        vals = (raw.decode() if isinstance(raw, T.Coded)
+                                else np.asarray(raw, dtype=object))
+                        raw_strs[c.name] = vals
+                        # placeholder for ragged checks; never hashed (raw
+                        # distribution keys are rejected in _resolve)
+                        arr = np.zeros(len(vals), dtype=np.int64)
+                        enc[c.name] = arr
+                        nrows = len(arr) if nrows is None else nrows
+                        if len(arr) != nrows:
+                            raise ValueError("ragged insert")
+                        continue
+                    d = self.dictionary(table, c.name)
+                    vmask = valids.get(c.name)
+                    if isinstance(raw, T.Coded):
+                        arr = d.encode_coded(list(raw.vocab), raw.codes)
+                        if vmask is not None:
+                            arr = np.where(vmask, arr, d.encode([""])[0])
+                    elif vmask is None:
+                        arr = d.encode(list(raw))
+                    else:
+                        strs = ["" if not ok else s for s, ok in zip(raw, vmask)]
+                        arr = d.encode(strs)
+                elif c.type.kind is T.Kind.DECIMAL and not isinstance(raw, np.ndarray):
+                    arr = np.array([T.decimal_to_int(v, c.type.scale) for v in raw], dtype=np.int64)
+                elif c.type.kind is T.Kind.DATE and not isinstance(raw, np.ndarray):
+                    arr = np.array([T.date_to_days(v) for v in raw], dtype=np.int32)
                 else:
-                    strs = ["" if not ok else s for s, ok in zip(raw, vmask)]
-                    arr = d.encode(strs)
-            elif c.type.kind is T.Kind.DECIMAL and not isinstance(raw, np.ndarray):
-                arr = np.array([T.decimal_to_int(v, c.type.scale) for v in raw], dtype=np.int64)
-            elif c.type.kind is T.Kind.DATE and not isinstance(raw, np.ndarray):
-                arr = np.array([T.date_to_days(v) for v in raw], dtype=np.int32)
-            else:
-                arr = np.asarray(raw, dtype=c.type.np_dtype)
-            enc[c.name] = arr
-            nrows = len(arr) if nrows is None else nrows
-            if len(arr) != nrows:
-                raise ValueError("ragged insert")
+                    arr = np.asarray(raw, dtype=c.type.np_dtype)
+                enc[c.name] = arr
+                nrows = len(arr) if nrows is None else nrows
+                if len(arr) != nrows:
+                    raise ValueError("ragged insert")
 
         return self._append_encoded(table, schema, enc, valids, raw_strs,
                                     tx, dict_sizes, stream_marks=stream_marks)
@@ -771,100 +773,111 @@ class TableStore:
             seg_of = self._placement(schema, enc, valids, nrows, total_existing)
             seg_rows = [np.nonzero(seg_of == s)[0] for s in range(nseg)]
 
-        records = self._write_segfiles(schema, table, tmeta, enc, valids,
-                                       seg_rows, fileno, raw_strs=raw_strs)
+        with _trace.span("append", cat="write", table=table,
+                         rows=nrows) as sp:
+            records = self._write_segfiles(schema, table, tmeta, enc, valids,
+                                           seg_rows, fileno,
+                                           raw_strs=raw_strs)
+            _trace.annotate(sp, files=sum(len(r[1]) for r in records))
+        counters.inc("rows_inserted", nrows)
 
         if own_tx:
-            # Ordering: stage files -> prepare_delta (the PER-TABLE
-            # sequence CAS — appenders to different tables never contend)
-            # -> persist dictionaries (fsynced; superset-safe) -> commit
-            # (one fsynced commit-log line). A concurrent SAME-TABLE CAS
-            # conflict RETRIES against the fresh snapshot: the staged
-            # files are tx-unique and remain valid, so only the manifest
-            # record needs re-merging (the appendonly writer's
-            # segfile-concurrency model — writers never block readers and
-            # autocommit writers serialize optimistically). Each retry is
-            # counted in manifest_cas_retry_total (zero for cross-table
-            # workloads by construction).
+            with _trace.span("commit", cat="write", table=table):
+                self._commit_append(table, tx, records, dict_sizes,
+                                    stream_marks)
+        # else a DTM-managed tx: the caller drives prepare/commit and must
+        # call flush_dicts(table) between those phases (see runtime/dtm.py)
+        return nrows
 
-            from greengage_tpu.runtime.logger import counters as _counters
+    def _commit_append(self, table, tx, records, dict_sizes,
+                       stream_marks) -> None:
+        """The autocommit append's manifest commit (write intent, else the
+        per-table CAS with its optimistic retry)."""
+        # Ordering: stage files -> prepare_delta (the PER-TABLE
+        # sequence CAS — appenders to different tables never contend)
+        # -> persist dictionaries (fsynced; superset-safe) -> commit
+        # (one fsynced commit-log line). A concurrent SAME-TABLE CAS
+        # conflict RETRIES against the fresh snapshot: the staged
+        # files are tx-unique and remain valid, so only the manifest
+        # record needs re-merging (the appendonly writer's
+        # segfile-concurrency model — writers never block readers and
+        # autocommit writers serialize optimistically). Each retry is
+        # counted in manifest_cas_retry_total (zero for cross-table
+        # workloads by construction).
 
-            # a CROSS-PROCESS retry is only safe when this insert assigned
-            # no new dictionary codes: a concurrent writer in another
-            # process may have claimed the same codes for different words
-            # (in-process writers share Dictionary objects and serialize on
-            # the session write lock, so they never hit this)
-            dict_grew = any(
-                len(self.dictionary(table, n)) != sz
-                for n, sz in dict_sizes.items())
-            if not dict_grew and (stream_marks is not None
-                                  or self._use_write_intents()):
-                # WRITE-INTENT fast path (autocommit appends): a txid-named
-                # intent + one merge line carrying these records — no
-                # per-table claim, so N same-table appenders commit with
-                # ZERO retries (manifest_cas_retry_total stays flat by
-                # construction). Gated on `not dict_grew`: an insert that
-                # assigned new dictionary codes must keep the per-table
-                # CAS, whose conflict is the only cross-process signal
-                # that another writer may hold the same codes.
-                self.flush_dicts(table)
-                ihandle = self.manifest.stage_intent(
-                    table, records, streams=stream_marks)
-                try:
-                    self.manifest.commit_intent(ihandle)
-                except BaseException:
-                    self.manifest.abort_intent(ihandle)
-                    raise
-                self.maybe_fold_manifest()
-                return nrows
-            def _fold_stream_marks(tx_):
-                # Dictionary growth forces a streamed micro-batch onto
-                # the CAS path; the full-state line it stages must still
-                # carry the stream's resume watermark — otherwise the
-                # rows commit but the durable watermark never advances,
-                # and after kill-9 the client resumes from a stale seq
-                # and replays already-durable batches (double-apply).
-                if not stream_marks:
-                    return
-                state = tx_["tables"].setdefault(
-                    table, {"segfiles": {}, "nrows": {}})
-                marks = state.setdefault("streams", {})
-                for sid, sq in stream_marks.items():
-                    marks[str(sid)] = max(int(marks.get(str(sid), 0)),
-                                          int(sq))
+        from greengage_tpu.runtime.logger import counters as _counters
 
-            _fold_stream_marks(tx)
-            last = None
-            for attempt in range(20):
-                try:
-                    handle = self.manifest.prepare_delta(tx, [table])
-                    break
-                except RuntimeError as e:
-                    last = e
-                    if dict_grew:
-                        self._invalidate_dicts(table)
-                        raise
-                    _counters.inc("manifest_cas_retry_total")
-                    _time.sleep(0.01 * (attempt + 1))
-                    tx = self.manifest.begin()
-                    merge_segfile_records(tx, table, records)
-                    _fold_stream_marks(tx)
-            else:
-                self._invalidate_dicts(table)
-                raise RuntimeError(
-                    f"write-write conflict persisted after retries: {last}")
+        # a CROSS-PROCESS retry is only safe when this insert assigned
+        # no new dictionary codes: a concurrent writer in another
+        # process may have claimed the same codes for different words
+        # (in-process writers share Dictionary objects and serialize on
+        # the session write lock, so they never hit this)
+        dict_grew = any(
+            len(self.dictionary(table, n)) != sz
+            for n, sz in dict_sizes.items())
+        if not dict_grew and (stream_marks is not None
+                              or self._use_write_intents()):
+            # WRITE-INTENT fast path (autocommit appends): a txid-named
+            # intent + one merge line carrying these records — no
+            # per-table claim, so N same-table appenders commit with
+            # ZERO retries (manifest_cas_retry_total stays flat by
+            # construction). Gated on `not dict_grew`: an insert that
+            # assigned new dictionary codes must keep the per-table
+            # CAS, whose conflict is the only cross-process signal
+            # that another writer may hold the same codes.
             self.flush_dicts(table)
+            ihandle = self.manifest.stage_intent(
+                table, records, streams=stream_marks)
             try:
-                self.manifest.commit_delta(handle)
+                self.manifest.commit_intent(ihandle)
             except BaseException:
-                self.manifest.abort_delta(handle)
+                self.manifest.abort_intent(ihandle)
                 raise
             self.maybe_fold_manifest()
+            return
+        def _fold_stream_marks(tx_):
+            # Dictionary growth forces a streamed micro-batch onto
+            # the CAS path; the full-state line it stages must still
+            # carry the stream's resume watermark — otherwise the
+            # rows commit but the durable watermark never advances,
+            # and after kill-9 the client resumes from a stale seq
+            # and replays already-durable batches (double-apply).
+            if not stream_marks:
+                return
+            state = tx_["tables"].setdefault(
+                table, {"segfiles": {}, "nrows": {}})
+            marks = state.setdefault("streams", {})
+            for sid, sq in stream_marks.items():
+                marks[str(sid)] = max(int(marks.get(str(sid), 0)),
+                                      int(sq))
+
+        _fold_stream_marks(tx)
+        last = None
+        for attempt in range(20):
+            try:
+                handle = self.manifest.prepare_delta(tx, [table])
+                break
+            except RuntimeError as e:
+                last = e
+                if dict_grew:
+                    self._invalidate_dicts(table)
+                    raise
+                _counters.inc("manifest_cas_retry_total")
+                _time.sleep(0.01 * (attempt + 1))
+                tx = self.manifest.begin()
+                merge_segfile_records(tx, table, records)
+                _fold_stream_marks(tx)
         else:
-            # DTM-managed tx: the caller drives prepare/commit and must call
-            # flush_dicts(table) between those phases (see runtime/dtm.py).
-            pass
-        return nrows
+            self._invalidate_dicts(table)
+            raise RuntimeError(
+                f"write-write conflict persisted after retries: {last}")
+        self.flush_dicts(table)
+        try:
+            self.manifest.commit_delta(handle)
+        except BaseException:
+            self.manifest.abort_delta(handle)
+            raise
+        self.maybe_fold_manifest()
 
     def _use_write_intents(self) -> bool:
         """GUC gate for the intent append path (write_intents_enabled,
@@ -1106,6 +1119,9 @@ class TableStore:
         # exists — pruned blocks would desync the bitmap's row numbering;
         # VACUUM compaction restores pruned scans.
         keep_rows = self.delmask_keep(table, seg, snap)
+        tally = current_tally()
+        if prune and keep_rows is not None and tally is not None:
+            tally.prune_skipped = True
         if prune and keep_rows is None:
             idx_cols = frozenset(
                 d["column"] for d in getattr(schema, "indexes", {}).values())
@@ -1181,6 +1197,9 @@ class TableStore:
             if dest is not None and keep_rows is None \
                     and len(data_rels) <= 1:
                 d = dest.get(name)
+            elif tally is not None and dest is not None and name in dest:
+                tally.off_slot = "delmask" if keep_rows is not None \
+                    else "files"
 
             def _bidx(rel):
                 # the kept-block slice applies to data AND valid files of
@@ -1809,9 +1828,12 @@ class TableStore:
         last = None
         for attempt in range(10):
             tx = self.manifest.begin()
-            old = self.stage_delmask(tx, table, masks)
+            with _trace.span("delmask", cat="write", table=table,
+                             phase="write"):
+                old = self.stage_delmask(tx, table, masks)
             try:
-                self.manifest.commit_tables_tx(tx, [table])
+                with _trace.span("commit", cat="write", table=table):
+                    self.manifest.commit_tables_tx(tx, [table])
             except IntentConflict as e:
                 last = e
                 # the freshly staged bitmap files never became visible

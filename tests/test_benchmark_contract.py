@@ -2,7 +2,10 @@
 key, counter, histogram and span that a file under `benchmark/metrics/`
 names has to exist after a traced statement, and the program cache counts
 one miss cold and one hit warm on both of its callers. The metric files are
-read, never edited. CPU: names and counts, never a time."""
+read, never edited. Since ISSUE 35 the traced statements include a write:
+an INSERT ... SELECT, a DELETE and the scans after each, for the metrics
+of the write path and of the read path after a write. CPU: names and
+counts, never a time."""
 
 import glob
 import json
@@ -21,7 +24,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the kinds that read the program's own names; `trace_*` and `roofline`
 # read the device's operations and the benchmark's own marks
 PROGRAM_KINDS = ("stats_mean", "counter_delta", "counter_share",
-                 "histogram_mean", "span_idle")
+                 "counter_per", "histogram_mean", "span_idle")
+WRITE_SPANS = ("dml_scan", "write", "encode", "append", "delmask", "commit")
+WRITE_COUNTERS = ("rows_inserted", "rows_deleted", "write_bytes",
+                  "manifest_commits", "stage_cache_dropped",
+                  "stage_units_copy_files", "stage_units_copy_delmask",
+                  "zone_prune_skipped_delmask")
 
 
 def _metric_files() -> list:
@@ -39,8 +47,9 @@ def _metric_files() -> list:
 def traced(devices8):
     """A grouped aggregate over two segments, run cold (compiles, reads
     the files) and again with the staged inputs dropped (finds the
-    program, reads through the block cache): each run's stats and spans,
-    and the registries afterwards."""
+    program, reads through the block cache): each run's stats and spans.
+    Then the write path (`dml`): an INSERT ... SELECT and a DELETE, each
+    followed by a scan under a pushed predicate. The registries last."""
     db = greengage_tpu.connect(numsegments=2)
     db.sql("create table bc (k bigint, v int) distributed by (k)")
     n = 6000
@@ -53,7 +62,14 @@ def traced(devices8):
         db.executor.stager.stage_cache.clear()
         res = db.sql(sql)
         runs.append((res.stats, TRACES.last().export()))
-    yield {"runs": runs, "counters": counters.snapshot(),
+    dml, scan = [], "select count(*) from bc where v < 5"
+    for stmt in ("insert into bc select k + (select max(k) from bc), v "
+                 "from bc where v = 3",
+                 "delete from bc where k >= (select min(k) from bc) and v = 4"):
+        res = db.sql(stmt)
+        dml.append((res, res.stats, TRACES.last().export()))
+        db.sql(scan)
+    yield {"runs": runs, "dml": dml, "counters": counters.snapshot(),
            "histograms": histograms.snapshot()}
     db.close()
 
@@ -62,14 +78,18 @@ def traced(devices8):
 def test_metric_reads_a_name_the_program_produces(traced, spec):
     kind = spec["kind"]
     if kind == "stats_mean":
-        for stats, _spans in traced["runs"]:
+        # a SELECT's statistic, or one only a DML statement carries
+        select = [stats for stats, _spans in traced["runs"]]
+        write = [stats for _res, stats, _spans in traced["dml"]]
+        mine = select if spec["stat"] in select[0] else write
+        for stats in mine:
             assert spec["stat"] in stats, sorted(stats)
             if "where" in spec:
                 assert spec["where"] in stats, sorted(stats)
         if "where" in spec:   # the cold run is the one it keeps
-            assert traced["runs"][0][0][spec["where"]]
-    elif kind in ("counter_delta", "counter_share"):
-        names = [spec["name"]] if kind == "counter_delta" \
+            assert mine[0][spec["where"]]
+    elif kind in ("counter_delta", "counter_share", "counter_per"):
+        names = [spec["name"]] if kind != "counter_share" \
             else spec["num"] + spec["den"]
         for name in names:
             assert traced["counters"].get(name, 0) > 0, name
@@ -91,6 +111,47 @@ def test_metric_reads_a_name_the_program_produces(traced, spec):
             leaves = {s["name"] for s in mine if s["id"] not in parents}
             assert {"wait", "assemble", "put", "dispatch"} <= leaves, leaves
             assert "read:bc" in names and "stage" in names - leaves, names
+
+
+def test_a_dml_statement_answers_like_a_select(traced):
+    """ISSUE 35: the tag, one row, and a SELECT's statistics where they
+    apply, summed over the statement's inner statements."""
+    (ins, ins_stats, _), (dele, del_stats, _) = traced["dml"]
+    n = 6000 // 11 + (3 < 6000 % 11)
+    assert ins == f"INSERT 0 {n}" and ins.rows() == [(f"INSERT 0 {n}", n)]
+    assert dele.rows() == [(str(dele), dele.nrows)] and dele.nrows > 0
+    assert (ins_stats["rows_written"], ins_stats["rows_deleted"]) == (n, 0)
+    assert (del_stats["rows_written"], del_stats["rows_deleted"]) == (
+        0, dele.nrows)
+    for stats in (ins_stats, del_stats):
+        assert {"compiled", "compile_ms", "stage_ms", "compute_ms",
+                "fetch_ms", "plan_cache", "stage_units",
+                "stage_units_in_slot", "stage_units_copy_files",
+                "stage_units_copy_delmask", "dml_scan_ms",
+                "write_ms"} <= set(stats), sorted(stats)
+        assert stats["inner_statements"] == 2   # a subquery, the scan
+
+
+def test_the_write_path_has_its_spans_and_counters(traced):
+    """Every span and counter docs/OBSERVABILITY.md lists for a write is
+    there after one INSERT ... SELECT and one DELETE; a read unit that left
+    the in-place path says why on its `read:` span."""
+    names = [{s["name"] for s in spans} for _r, _s, spans in traced["dml"]]
+    assert {"dml_scan", "write", "encode", "append", "commit"} <= names[0]
+    assert {"dml_scan", "write", "delmask", "commit"} <= names[1]
+    assert set(WRITE_SPANS) <= names[0] | names[1]
+    for _res, _stats, spans in traced["dml"]:
+        by_id = {s["id"]: s for s in spans}
+        write = next(s for s in spans if s["name"] == "write")
+        for s in spans:
+            if s["name"] in ("encode", "append", "delmask", "commit"):
+                assert by_id[s["parent"]] is write, s
+    for name in WRITE_COUNTERS:
+        assert traced["counters"].get(name, 0) > 0, name
+    # the DELETE's own scan reads a table of two data files a column
+    reads = [s for s in traced["dml"][1][2] if s["name"] == "read:bc"]
+    assert reads and all(s["args"]["in_slot"] == "no"
+                         and s["args"]["off_slot"] == "files" for s in reads)
 
 
 def test_slot_counters_are_the_stats_summed_cold_and_warm(traced):

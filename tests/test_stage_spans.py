@@ -178,8 +178,8 @@ def test_count_star_stages_through_one_unit_a_segment(db):
     snap = db.store.manifest.snapshot()
     got = [db.executor.stager._read_unit("sp_b", None, seg, [], snap, None, None)
            for seg in range(4)]
-    assert all(c == {} and v == {} for c, v, _n, _p, _slot in got)
-    assert sum(got_n for _c, _v, got_n, _p, _slot in got) == 2 * N
+    assert all(c == {} and v == {} for c, v, *_ in got)
+    assert sum(got_n for _c, _v, got_n, *_ in got) == 2 * N
 
 
 def test_virtual_columns_of_one_raw_column_share_a_unit(db):
