@@ -102,6 +102,9 @@ class CompileResult:
     flag_caps: dict = field(default_factory=dict)
     # agg_groups metric -> the out_cap its sort-based aggregate was given
     agg_caps: dict = field(default_factory=dict)
+    # those of them whose group starts the one-pass form finds
+    # (ops/agg.group_starts_direct)
+    agg_direct: frozenset = frozenset()
     est_bytes: int = 0                 # rough per-segment device allocation
     node_rows: dict = field(default_factory=dict)  # metric -> plan node id
     flag_packs: dict = field(default_factory=dict)  # pack flag -> plan nid
@@ -163,6 +166,7 @@ class Compiler:
         self.metrics: list[str] = []
         self.flag_caps: dict = {}
         self.agg_caps: dict = {}           # agg_groups metric -> out_cap
+        self.agg_direct: set = set()       # ... found by the one-pass form
         # key packing from ANALYZE bounds: a bounds violation (stale stats)
         # re-runs the SAME tier with that node's packing disabled
         self.pack_disabled = pack_disabled or set()
@@ -481,6 +485,7 @@ class Compiler:
             metric_names=metric_names,
             flag_caps=dict(self.flag_caps),
             agg_caps=dict(self.agg_caps),
+            agg_direct=frozenset(self.agg_direct),
             # a batched program holds ~one member's intermediates PER
             # member (vmap), while the staged scan args are shared; charge
             # the conservative width multiple — admission over-refusing a
@@ -1341,6 +1346,8 @@ class Compiler:
             mid = f"agg_groups_{len(self.metrics)}"
             self.metrics.append(mid)
             self.agg_caps[mid] = out_cap
+            if agg_ops.group_starts_direct(out_cap, child_cap):
+                self.agg_direct.add(mid)
         if use_sort and out_cap < child_cap:
             # output capacity below the theoretical max: group count can
             # overflow it; the device's exact count sizes the retry

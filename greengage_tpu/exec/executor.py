@@ -415,6 +415,8 @@ class Executor:
             # how full the sort-based aggregates' group tables ran
             counters.inc("agg_sort_groups", int(np.max(metrics[_mid])))
             counters.inc("agg_sort_capacity", int(_cap))
+            if _mid in comp.agg_direct:
+                counters.inc("agg_sort_capacity_direct", int(_cap))
         res.stats = {
             "tiers_used": at.tier + 1,
             "compiled": not at.was_cached,

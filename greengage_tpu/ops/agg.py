@@ -22,6 +22,7 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 BIG = jnp.iinfo(jnp.int32).max   # scatter-min identity (used by ops/join)
 
@@ -313,8 +314,6 @@ def group_sort(keys: list[KeySpec], sel, bounds: list | None = None,
     (None where the keys were sorted themselves): a live value outside its
     bounds, or two different key tuples with one hash word.
     """
-    from jax import lax
-
     n = sel.shape[0]
     violation = None
     word = None
@@ -367,6 +366,55 @@ def group_sort(keys: list[KeySpec], sel, bounds: list | None = None,
     return perm, sel_sorted & first, sel_sorted, violation
 
 
+# What the two forms of `group_starts` cost on a TPU v5e (the builder's
+# microbenchmark on the chip, PR 36: PERF.md §6). The search: ns a group a
+# round, 20.9-23.5 for 2^17-2^21 groups among 2^25 rows, 19.7 for 2^14 among
+# 2^20 (29.5 at 2^23 among 2^25). The one-operand int32 sort: ns a row, 2.43
+# at 2^25 rows, 2.16 at 2^24, 1.5-1.7 at 2^20-2^22.
+NS_SEARCH_GROUP_ROUND = 21.0
+NS_SORT_ROW = 2.4
+
+
+def group_starts_direct(out_cap: int, n: int) -> bool:
+    """Whether `group_starts` takes its one-pass form for a table of
+    `out_cap` groups over `n` sorted rows (both static a program; the
+    compiler records the answer for the `agg_sort_capacity_direct`
+    counter). The search is ceil(log2(n + 1)) rounds of `out_cap` dependent
+    gathers, the one-pass form a sort of the `n` rows: at 2^25 rows they
+    break even near 150,000 groups (measured: 2^17 groups 71 ms against the
+    sort's 82, 2^19 groups 295 ms)."""
+    return out_cap * n.bit_length() * NS_SEARCH_GROUP_ROUND > n * NS_SORT_ROW
+
+
+def _starts_search(csb, out_cap: int):
+    """One binary search a group over the running boundary count: lowers to
+    a `while` of ceil(log2(n + 1)) rounds, each a gather of `out_cap`
+    elements out of n."""
+    return jnp.searchsorted(
+        csb, jnp.arange(1, out_cap + 1, dtype=jnp.int32)).astype(jnp.int32)
+
+
+def _starts_direct(boundary, out_cap: int):
+    """One pass over the rows: a boundary row keeps its row number, every
+    other row takes n, and a one-operand sort brings the boundary rows'
+    numbers to the front in order, the n of absent groups behind them."""
+    n = boundary.shape[0]
+    rows = lax.sort(jnp.where(boundary, jnp.arange(n, dtype=jnp.int32),
+                              jnp.int32(n)))
+    return jnp.pad(rows, (0, max(out_cap - n, 0)),
+                   constant_values=n)[:out_cap]
+
+
+def group_starts(boundary, csb, out_cap: int):
+    """-> int32[out_cap]: the sorted-row index of each group's first row,
+    n for an absent group, the first `out_cap` groups only. `csb` is the
+    running count of `boundary`. Two forms of one result, chosen by the
+    shapes (`group_starts_direct`)."""
+    if group_starts_direct(out_cap, csb.shape[0]):
+        return _starts_direct(boundary, out_cap)
+    return _starts_search(csb, out_cap)
+
+
 def sorted_group_aggregate(boundary, sel_sorted, aggs: list[AggSpec],
                            out_cap: int):
     """Table-shaped aggregation over key-sorted rows.
@@ -377,34 +425,32 @@ def sorted_group_aggregate(boundary, sel_sorted, aggs: list[AggSpec],
     Groups beyond out_cap are dropped — the caller flags total > out_cap
     and retries with the exact count.
 
-    TPU cost model (measured on v5e): cumsum ~40ms/6M, scatter ~540ms/6M,
-    gather ~64ms/6M, associative_scan/searchsorted-over-rows unusably slow.
+    TPU cost model (v5e; ns a row). Measured on the chip at 2^25 rows by
+    PR 36's builder (PERF.md §6): a one-operand int32 sort 2.4; a scatter
+    whose index vector is promised ascending 8.8; any other scatter into a
+    large table 7-11, because the TPU compiler first sorts its (index,
+    update) pairs (7.1 with unique addresses, 11.0 with three quarters of
+    the rows on one). Rows that share an address cost no more in an int32
+    min or set (all but 30 rows of 2^25 on one slot of 2^15: 8.8); the one
+    collision on record that did is Q6's ungrouped 64-bit sums as a scatter
+    into two slots, 91 a row (ledger, PR 32). `gg checkperf --device`
+    (PR 23): a random scatter-add 9.85, a random gather 8.79, a two-operand
+    sort 1.61 an operand. A binary search over rows is ~21 a group a round
+    (`group_starts_direct`).
     So: sums/counts = whole-batch cumsum + span difference at the M group
-    boundaries (M-sized gathers are ~free). int64 (scaled DECIMAL) sums
-    split into 32-bit limbs with separate cumsums so the span difference is
-    EXACT regardless of batch magnitude; float64 keeps one cumsum (group
-    error ~ batch_total * eps — floats round under any summation order).
+    boundaries. int64 (scaled DECIMAL) sums split into 32-bit limbs with
+    separate cumsums so the span difference is EXACT regardless of batch
+    magnitude; float64 sums scatter-add into the group table (a whole-batch
+    prefix sum would cost a small group the batch total's rounding).
     min/max are not invertible, so they scatter into the group-id table
-    (the only scatter in the path, paid per min/max aggregate).
+    (paid per min/max aggregate).
     All spec arrays must already be key-sorted."""
     n = sel_sorted.shape[0]
     csb = jnp.cumsum(boundary.astype(jnp.int32))
     total = csb[-1] if n else jnp.int32(0)
     # first sorted row of group g; RAW positions keep n for absent groups
-    # so the span ends don't truncate the last real group off by one.
-    # Two interchangeable forms, picked by measured v5e costs: binary
-    # search costs ~26 gathers of out_cap elements; a unique-index scatter
-    # costs one ~(n*90ns) pass — cheaper once out_cap is a sizable
-    # fraction of the batch (high-cardinality groupings).
-    # break-even from the stated per-element costs: scatter ~90ns vs
-    # gather ~10.7ns => 26 * out_cap * 10.7 > n * 90
-    if out_cap * 26 * 10.7 > n * 90:
-        stgt = jnp.where(boundary, jnp.minimum(csb - 1, out_cap), out_cap)
-        raw = jnp.full((out_cap + 1,), n, jnp.int32).at[stgt].min(
-            jnp.arange(n, dtype=jnp.int32))[:out_cap]
-    else:
-        raw = jnp.searchsorted(
-            csb, jnp.arange(1, out_cap + 1, dtype=jnp.int32)).astype(jnp.int32)
+    # so the span ends don't truncate the last real group off by one
+    raw = group_starts(boundary, csb, out_cap)
     ends = jnp.clip(
         jnp.concatenate([raw[1:], jnp.full((1,), n, jnp.int32)]) - 1,
         0, max(n - 1, 0))

@@ -134,8 +134,10 @@ COUNTER_NAMES = (
     "scalar_device_total", "scalar_host_fallback_total",
     # sort-based aggregates (exec/compile.py _c_aggregate): groups found,
     # and the out_cap their group tables were compiled with, a statement —
-    # groups / capacity is how full the tables ran
-    "agg_sort_groups", "agg_sort_capacity",
+    # groups / capacity is how full the tables ran; the capacity of those
+    # whose group starts one pass over the rows found, not a search a group
+    # (ops/agg.group_starts)
+    "agg_sort_groups", "agg_sort_capacity", "agg_sort_capacity_direct",
     # overload armor (docs/ROBUSTNESS.md "Overload protection"):
     # connections accepted vs shed at the bounded front end
     # (runtime/server.py), oversized request frames rejected, statements

@@ -5,6 +5,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from greengage_tpu import expr as E
@@ -186,6 +187,97 @@ def test_groupby_dead_rows_excluded():
         boundary, sel_sorted, [agg_ops.AggSpec("s", "sum", v, None)], 5)
     got = sorted(np.asarray(vals["s"])[:int(total)].tolist())
     assert got == [1, 5]
+
+
+def _sorted_rows(case: str):
+    """-> (boundary, sel_sorted, values, out_cap) of key-sorted rows as
+    `group_sort` leaves them: live rows first, a boundary on each group's
+    first row."""
+    n = 64
+    sel = np.ones(n, bool)
+    boundary = np.zeros(n, bool)
+    out_cap = 16
+    if case == "more_groups_than_slots":
+        boundary[::2] = True                      # 32 groups, 16 slots
+    elif case == "no_live_row":
+        sel[:] = False
+    elif case == "one_group":
+        boundary[0] = True
+    elif case == "every_row_a_group":
+        boundary[:] = True
+        out_cap = n
+    elif case == "dead_rows_after_the_last_group":
+        sel[40:] = False
+        boundary[[0, 3, 4, 17, 39]] = True
+    elif case == "slots_beyond_the_rows":
+        boundary[[0, 1, 9, 63]] = True
+        out_cap = 2 * n
+    else:
+        assert case == "last_group_ends_at_the_last_row"
+        boundary[[0, 5, 50]] = True               # rows 50..63: no successor
+    values = (np.arange(n, dtype=np.int64) * 2654435761) % 1009 - 500
+    return boundary & sel, sel, values, out_cap
+
+
+@pytest.mark.parametrize("form", ["search", "direct"])
+@pytest.mark.parametrize("case", [
+    "more_groups_than_slots", "no_live_row", "one_group", "every_row_a_group",
+    "dead_rows_after_the_last_group", "slots_beyond_the_rows",
+    "last_group_ends_at_the_last_row"])
+def test_group_starts_forms_equal_a_numpy_reference(monkeypatch, case, form):
+    """ISSUE 36: both forms of the group-start step, bit for bit: group g's
+    first sorted row, n for an absent group, the first out_cap groups only;
+    and the aggregate over them: exact total, sums, counts, srcpos."""
+    boundary, sel, values, out_cap = _sorted_rows(case)
+    n = len(sel)
+    first = np.flatnonzero(boundary)
+    want = np.full(out_cap, n, np.int32)
+    want[:min(len(first), out_cap)] = first[:out_cap]
+    csb = jnp.cumsum(jnp.asarray(boundary).astype(jnp.int32))
+    got = agg_ops._starts_search(csb, out_cap) if form == "search" \
+        else agg_ops._starts_direct(jnp.asarray(boundary), out_cap)
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), want)
+
+    monkeypatch.setattr(agg_ops, "group_starts_direct",
+                        lambda out_cap, n: form == "direct")
+    vals, _, srcpos, total = agg_ops.sorted_group_aggregate(
+        jnp.asarray(boundary), jnp.asarray(sel),
+        [agg_ops.AggSpec("c", "count_star", None, None),
+         agg_ops.AggSpec("s", "sum", jnp.asarray(values), None),
+         agg_ops.AggSpec("mx", "max", jnp.asarray(values), None)], out_cap)
+    assert int(total) == len(first)
+    kept = min(len(first), out_cap)
+    ends = np.append(first[1:], sel.sum())[:kept]
+    spans = [values[a:b] for a, b in zip(first[:kept], ends)]
+    assert np.array_equal(np.asarray(srcpos)[:kept], first[:kept])
+    if len(first) > out_cap:      # the last slot also holds the dropped
+        kept -= 1                 # groups' rows: the caller retries anyway
+    assert np.asarray(vals["c"])[:kept].tolist() == [
+        len(x) for x in spans[:kept]]
+    assert np.asarray(vals["s"])[:kept].tolist() == [
+        x.sum() for x in spans[:kept]]
+    assert np.asarray(vals["mx"])[:kept].tolist() == [
+        x.max() for x in spans[:kept]]
+
+
+def test_group_starts_search_only_under_a_small_group_table():
+    """The choice reads out_cap and n alone: a group table a quarter of the
+    rows finds its starts in one pass (no `while` in the lowered text), one
+    of 1/1024 of them by the search."""
+    n = 1 << 14
+
+    def lowered(out_cap):
+        return jax.jit(lambda b, s, v: agg_ops.sorted_group_aggregate(
+            b, s, [agg_ops.AggSpec("s", "sum", v, None)], out_cap)).lower(
+                jax.ShapeDtypeStruct((n,), jnp.bool_),
+                jax.ShapeDtypeStruct((n,), jnp.bool_),
+                jax.ShapeDtypeStruct((n,), jnp.int64)).as_text()
+
+    assert agg_ops.group_starts_direct(n // 4, n)
+    assert not agg_ops.group_starts_direct(n // 1024, n)
+    assert "stablehlo.while" not in lowered(n // 4)
+    assert "stablehlo.while" in lowered(n // 1024)
 
 
 # ---------------------------------------------------------------------------

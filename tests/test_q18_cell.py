@@ -84,7 +84,7 @@ def _ops(text: str) -> dict:
     # the operation, not its `#stablehlo.scatter<...>` attribute: counted
     # with it (as until ISSUE 32) every scatter read as two
     out = {k: len(re.findall(r"(?<!#)stablehlo\." + k + r"\b", text))
-           for k in ("sort", "scatter", "all_to_all", "all_gather")}
+           for k in ("sort", "scatter", "while", "all_to_all", "all_gather")}
     # 64-bit prefix sums: two limbs a sum; a count must not add any (the TPU
     # compiler folds `(mask as int64) >> 32` to zeros and then evaluates the
     # prefix sum of them on the host, quadratic in the rows)
@@ -136,13 +136,18 @@ def test_four_segment_explain_is_the_parents(env, query):
 
 # what ISSUE 31 recorded for the lowered one-segment programs at SF 0.05; its
 # parent's hold Q18 6 sorts / 35 scatters / 2 all_gathers / 14 64-bit prefix
-# sums, Q3 3 / 15 / 0 / 5. Q1 as ISSUE 32 found it
-RECORDED = {"q18": {"sort": 4, "scatter": 6, "all_to_all": 0, "all_gather": 0,
-                    "cumsum_i64": 8},
-            "q3": {"sort": 2, "scatter": 4, "all_to_all": 0, "all_gather": 0,
-                   "cumsum_i64": 3},
-            "q1": {"sort": 1, "scatter": 0, "all_to_all": 0, "all_gather": 0,
-                   "cumsum_i64": 0}}
+# sums, Q3 3 / 15 / 0 / 5. Q1 as ISSUE 32 found it. ISSUE 36: an aggregate
+# whose table is a sizable share of its rows finds its group starts by a
+# one-operand sort, not by a search's `while` (Q18's inner aggregate, Q3's,
+# and at this scale Q18's partial one: 4,096 slots over 524,288 rows) or by
+# the colliding scatter (Q18's final one): three sorts more and a scatter
+# less in Q18, one sort more in Q3, no `while` in either
+RECORDED = {"q18": {"sort": 7, "scatter": 5, "while": 0, "all_to_all": 0,
+                    "all_gather": 0, "cumsum_i64": 8},
+            "q3": {"sort": 3, "scatter": 4, "while": 0, "all_to_all": 0,
+                   "all_gather": 0, "cumsum_i64": 3},
+            "q1": {"sort": 1, "scatter": 0, "while": 0, "all_to_all": 0,
+                   "all_gather": 0, "cumsum_i64": 0}}
 
 
 def _lowered_once(env, nseg: int, query: str) -> tuple[str, object]:
@@ -206,6 +211,26 @@ def test_agg_sort_counters_hold_groups_and_capacity(env):
     cap = d["agg_sort_capacity"]
     assert cap >= n_orders and cap & (cap - 1) == 0   # the pow2 out_cap
     assert cap < 4 * n_orders
+
+
+@pytest.mark.parametrize("query", ["q1", "q6", "q18"])
+def test_group_starts_counter_counts_the_one_pass_form(env, query):
+    """ISSUE 36: Q1 (dense) and Q6 (ungrouped) hold no sort aggregate and
+    count nothing; Q18's aggregates find their group starts in one pass at
+    this scale (the inner one's table is a quarter of its rows, the outer
+    ones' 1/128: SF5's 2^14 of 2^25 takes the search) and say so."""
+    db = env["dbs"][1]
+    db.sql(_sql(query))               # settle capacity hints, compile
+    c0 = counters.snapshot()
+    db.sql(_sql(query))
+    d = counters.since(c0)
+    cap, direct = (d.get("agg_sort_capacity", 0),
+                   d.get("agg_sort_capacity_direct", 0))
+    if query != "q18":
+        assert (cap, direct) == (0, 0)
+        return
+    n_orders = len(env["data"]["orders"]["o_orderkey"])
+    assert n_orders <= direct == cap, (direct, cap)
 
 
 def test_oracle_refuses_a_tie_on_both_order_keys(env):
