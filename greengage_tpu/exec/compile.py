@@ -52,6 +52,12 @@ def _shard_map(fn, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
+# program metrics that count rows: summed over the segments (in a gang on
+# the device, elsewhere by the executor); every other metric sizes a
+# per-segment capacity and reports the fullest segment's
+SUMMED_METRICS = ("nrows_", "join_null_extended_")
+
+
 def _pow2(n: float) -> int:
     m = 1
     while m < n:
@@ -105,6 +111,9 @@ class CompileResult:
     # those of them whose group starts the one-pass form finds
     # (ops/agg.group_starts_direct)
     agg_direct: frozenset = frozenset()
+    # join_expand_total metric -> (the out_cap its multi join's expansion
+    # was given, the join_null_extended metric of a LEFT one | None)
+    expand_caps: dict = field(default_factory=dict)
     est_bytes: int = 0                 # rough per-segment device allocation
     node_rows: dict = field(default_factory=dict)  # metric -> plan node id
     flag_packs: dict = field(default_factory=dict)  # pack flag -> plan nid
@@ -167,6 +176,7 @@ class Compiler:
         self.flag_caps: dict = {}
         self.agg_caps: dict = {}           # agg_groups metric -> out_cap
         self.agg_direct: set = set()       # ... found by the one-pass form
+        self.expand_caps: dict = {}        # join_expand_total metric -> (out_cap, null metric)
         # key packing from ANALYZE bounds: a bounds violation (stale stats)
         # re-runs the SAME tier with that node's packing disabled
         self.pack_disabled = pack_disabled or set()
@@ -380,7 +390,7 @@ class Compiler:
             for name in metric_names:
                 m = mdict[name].astype(jnp.int64)
                 if mh:
-                    m = (lax.psum(m, SEG_AXIS) if name.startswith("nrows_")
+                    m = (lax.psum(m, SEG_AXIS) if name.startswith(SUMMED_METRICS)
                          else lax.pmax(m, SEG_AXIS))
                 outs.append(jnp.broadcast_to(m, (1,)))
             return tuple(outs)
@@ -486,6 +496,7 @@ class Compiler:
             flag_caps=dict(self.flag_caps),
             agg_caps=dict(self.agg_caps),
             agg_direct=frozenset(self.agg_direct),
+            expand_caps=dict(self.expand_caps),
             # a batched program holds ~one member's intermediates PER
             # member (vmap), while the staged scan args are shared; charge
             # the conservative width multiple — admission over-refusing a
@@ -1185,12 +1196,19 @@ class Compiler:
         return run
 
     def _join_multi_expand_cap(self, plan: Join) -> int:
-        """Semi/anti multi-join pair-EXPANSION capacity: the output is
-        probe-shaped (_capacity_of), but the matched-pair expansion needs
+        """A multi join's pair-EXPANSION capacity (`out_cap`), said once.
+        Inner/left: the expansion IS the node's output, so `_capacity_of`
+        sizes it — the exact-total retry hint, else 1.5 x the planner's
+        output estimate (est_rows = |L||R|/NDV), pow2, x4 a tier.
+        Semi/anti (below): the output is probe-shaped (`_capacity_of`
+        gives the probe capacity), but the matched-pair expansion needs
         its own slot count — the exact-total retry hint, else the
-        planner's stats-driven pair estimate (|L||R|/NDV), else a blind
-        multiple of the probe capacity. pow2-bucketed for shape-stable
-        executable reuse (shape_signature walks this too)."""
+        planner's stats-driven pair estimate (expand_est, the same
+        |L||R|/NDV), else a blind multiple of the probe capacity.
+        pow2-bucketed for shape-stable executable reuse
+        (shape_signature walks this too)."""
+        if plan.kind not in ("semi", "anti"):
+            return self._capacity_of(plan)
         probe_cap0 = self._capacity_of(plan.left)
         if self._nid(plan) in self.cap_overrides:
             out_cap = _pow2(max(int(self.cap_overrides[self._nid(plan)]), 64))
@@ -1216,10 +1234,7 @@ class Compiler:
         right_fn = self._compile_node(plan.right)
         build_cap = self._capacity_of(plan.right)
         M = self._join_table_size(build_cap)
-        if plan.kind in ("semi", "anti"):
-            out_cap = self._join_multi_expand_cap(plan)
-        else:
-            out_cap = self._capacity_of(plan)
+        out_cap = self._join_multi_expand_cap(plan)
         probes = self._join_probes()
         lkeys, rkeys = plan.left_keys, plan.right_keys
         kind = plan.kind
@@ -1232,6 +1247,13 @@ class Compiler:
         self.metrics.append(mid_total)
         # overflow retry can size from the exact reported cardinality
         self.flag_caps[fid_exp] = (self._nid(plan), mid_total)
+        # probe rows a LEFT join null-extends: one more reported count
+        mid_null = None
+        if plan.kind == "left":
+            mid_null = f"join_null_extended_{len(self.metrics)}"
+            self.metrics.append(mid_null)
+        # the join_expand_* counters read both beside the out_cap given
+        self.expand_caps[mid_total] = (out_cap, mid_null)
         left_cols = [c for c in plan.left.out_cols()]
         right_cols = [c for c in plan.right.out_cols()]
         jkb = getattr(plan, "key_bounds", None)
@@ -1244,11 +1266,72 @@ class Compiler:
         else:
             jkb = None
 
+        def pair_batch(lb, rb, prow, brow, matched, sel) -> Batch:
+            """Both sides' columns gathered to the expansion's slots."""
+            cols, valids = {}, {}
+            for c in left_cols:
+                cols[c.id] = lb.cols[c.id][prow]
+                v = lb.valids.get(c.id)
+                if v is not None:
+                    valids[c.id] = v[prow]
+            for c in right_cols:
+                cols[c.id] = rb.cols[c.id][brow]
+                v = rb.valids.get(c.id)
+                gv = v[brow] if v is not None else jnp.ones_like(matched)
+                valids[c.id] = gv & matched
+            return Batch(cols, valids, sel)
+
+        def pairs(ctx, lb, rb, present, prow, brow, matched):
+            """The node's output from the expansion's slots."""
+            P = lb.selection().shape[0]   # probe-side capacity
+            if kind in ("semi", "anti"):
+                # evaluate the residual on the PAIR batch, then reduce to
+                # per-probe-row existence
+                keep = present & matched
+                if residual is not None:
+                    pair = pair_batch(lb, rb, prow, brow, matched, keep)
+                    keep = keep & Evaluator(pair, self.consts).predicate(residual)
+                any_kept = jnp.zeros((P + 1,), bool).at[
+                    jnp.where(present, prow, P)].max(keep)[:P]
+                lsel = lb.selection()
+                sel2 = (lsel & any_kept if kind == "semi"
+                        else lsel & ~any_kept)
+                return Batch(dict(lb.cols), dict(lb.valids), sel2)
+            sel = present if kind == "left" else (present & matched)
+            out = pair_batch(lb, rb, prow, brow, matched, sel)
+            null_row = present & ~matched   # LEFT: probe rows with no match
+            if residual is not None:
+                mask = Evaluator(out, self.consts).predicate(residual)
+                if kind == "left":
+                    # per-match disqualification over duplicate builds
+                    # (TPC-H Q13 shape): a pair failing the residual drops
+                    # its output row — UNLESS the probe row then has no
+                    # surviving pair, in which case its FIRST expanded row
+                    # becomes the single null-extended row
+                    keep = matched & mask
+                    K = keep.shape[0]
+                    any_kept = jnp.zeros((P + 1,), bool).at[
+                        jnp.where(present, prow, P)].max(keep)
+                    first = jnp.concatenate(
+                        [jnp.ones((min(K, 1),), bool), prow[1:] != prow[:-1]]) \
+                        if K > 1 else jnp.ones((K,), bool)
+                    null_row = present & first & ~any_kept[prow]
+                    out = out.with_sel(present & (keep | null_row))
+                    for c in right_cols:
+                        out.valids[c.id] = out.valids[c.id] & keep
+                else:
+                    out = out.with_sel(out.selection() & mask)
+            if mid_null is not None:
+                ctx["metrics"].append(
+                    (mid_null, jnp.sum(null_row.astype(jnp.int64))))
+            return out
+
         def run(ctx):
             lb = left_fn(ctx)
             rb = right_fn(ctx)
             table = join_ops.build_multi(
                 self._key_specs(rb, rkeys), rb.selection(), M, probes, jkb)
+            # the walk, then the expansion under its own scope (join-expand)
             (present, prow, brow, matched, expand_ov, walk_ov,
              total) = join_ops.probe_multi(
                 table, self._key_specs(lb, lkeys), lb.selection(), probes,
@@ -1261,67 +1344,8 @@ class Compiler:
             ctx["flags"].append((fid_ov, table.base.overflow | walk_ov))
             ctx["flags"].append((fid_exp, expand_ov))
             ctx["metrics"].append((mid_total, total))
-            if kind in ("semi", "anti"):
-                # evaluate the residual on the PAIR batch, then reduce to
-                # per-probe-row existence
-                keep = present & matched
-                if residual is not None:
-                    pcols, pvalids = {}, {}
-                    for c in left_cols:
-                        pcols[c.id] = lb.cols[c.id][prow]
-                        v = lb.valids.get(c.id)
-                        if v is not None:
-                            pvalids[c.id] = v[prow]
-                    for c in right_cols:
-                        pcols[c.id] = rb.cols[c.id][brow]
-                        v = rb.valids.get(c.id)
-                        gv = v[brow] if v is not None else jnp.ones_like(matched)
-                        pvalids[c.id] = gv & matched
-                    pair = Batch(pcols, pvalids, keep)
-                    keep = keep & Evaluator(pair, self.consts).predicate(residual)
-                P = lb.selection().shape[0]
-                any_kept = jnp.zeros((P + 1,), bool).at[
-                    jnp.where(present, prow, P)].max(keep)[:P]
-                lsel = lb.selection()
-                sel2 = (lsel & any_kept if kind == "semi"
-                        else lsel & ~any_kept)
-                return Batch(dict(lb.cols), dict(lb.valids), sel2)
-            cols, valids = {}, {}
-            for c in left_cols:
-                cols[c.id] = lb.cols[c.id][prow]
-                v = lb.valids.get(c.id)
-                if v is not None:
-                    valids[c.id] = v[prow]
-            for c in right_cols:
-                cols[c.id] = rb.cols[c.id][brow]
-                v = rb.valids.get(c.id)
-                gv = v[brow] if v is not None else jnp.ones_like(matched)
-                valids[c.id] = gv & matched
-            sel = present if kind == "left" else (present & matched)
-            out = Batch(cols, valids, sel)
-            if residual is not None:
-                mask = Evaluator(out, self.consts).predicate(residual)
-                if kind == "left":
-                    # per-match disqualification over duplicate builds
-                    # (TPC-H Q13 shape): a pair failing the residual drops
-                    # its output row — UNLESS the probe row then has no
-                    # surviving pair, in which case its FIRST expanded row
-                    # becomes the single null-extended row
-                    keep = matched & mask
-                    K = keep.shape[0]
-                    P = lb.selection().shape[0]   # probe-side capacity
-                    any_kept = jnp.zeros((P + 1,), bool).at[
-                        jnp.where(present, prow, P)].max(keep)
-                    first = jnp.concatenate(
-                        [jnp.ones((min(K, 1),), bool), prow[1:] != prow[:-1]]) \
-                        if K > 1 else jnp.ones((K,), bool)
-                    null_row = present & first & ~any_kept[prow]
-                    out = out.with_sel(present & (keep | null_row))
-                    for c in right_cols:
-                        out.valids[c.id] = out.valids[c.id] & keep
-                else:
-                    out = out.with_sel(out.selection() & mask)
-            return out
+            with jax.named_scope("join-expand"):
+                return pairs(ctx, lb, rb, present, prow, brow, matched)
 
         return run
 

@@ -24,7 +24,7 @@ import jax
 
 from greengage_tpu import types as T
 from greengage_tpu.exec import spill
-from greengage_tpu.exec.compile import CompileResult
+from greengage_tpu.exec.compile import SUMMED_METRICS, CompileResult
 from greengage_tpu.exec.programs import ProgramCache, Unsignable
 from greengage_tpu.exec.staging import Staged, Stager
 from greengage_tpu.planner.locus import LocusKind
@@ -197,6 +197,7 @@ class _Statement:
     hints: dict = field(default_factory=dict)
     cap_overrides: dict = field(default_factory=dict)   # grown by retries
     pack_disabled: set = field(default_factory=set)
+    expand_retries: int = 0   # attempts a multi join's expansion overflowed
 
 
 @dataclass
@@ -364,6 +365,13 @@ class Executor:
         identically and stays in lockstep)."""
         return int(v.flat[0]) if self.multihost else int(np.max(v))
 
+    def _metric(self, name: str, v) -> int:
+        """A program metric as one number: row counts (SUMMED_METRICS) sum
+        over the segments, capacity metrics report the fullest one's."""
+        if self.multihost or not name.startswith(SUMMED_METRICS):
+            return self._peak(v)
+        return int(np.sum(v))
+
     def _grow(self, st: _Statement, comp, overflow, metrics, tier) -> int:
         """Size the retry from the exact cardinalities the device reported
         -> the tier to run next."""
@@ -379,6 +387,8 @@ class Executor:
             capacity_over = compact_over
         for fname in pack_over:
             st.pack_disabled.add(comp.flag_packs[fname])
+        st.expand_retries += any(
+            f.startswith("join_expand_overflow") for f in capacity_over)
         for fname in capacity_over:
             hint = comp.flag_caps.get(fname)
             if hint is not None:
@@ -417,6 +427,17 @@ class Executor:
             counters.inc("agg_sort_capacity", int(_cap))
             if _mid in comp.agg_direct:
                 counters.inc("agg_sort_capacity_direct", int(_cap))
+        if comp.expand_caps:
+            # attempts run again because a pair expansion overflowed
+            counters.inc("join_expand_retries", st.expand_retries)
+        for _mid, (_cap, _mid_null) in comp.expand_caps.items():
+            # how full the multi joins' pair expansions ran, and the probe
+            # rows their LEFT joins null-extended
+            counters.inc("join_expand_rows", self._metric(_mid, metrics[_mid]))
+            counters.inc("join_expand_capacity", int(_cap))
+            if _mid_null is not None:
+                counters.inc("join_null_extended_rows",
+                             self._metric(_mid_null, metrics[_mid_null]))
         res.stats = {
             "tiers_used": at.tier + 1,
             "compiled": not at.was_cached,
@@ -460,10 +481,7 @@ class Executor:
             # per-node row counters SUM across segments; capacity
             # metrics report the per-segment max (multi-host:
             # already device-reduced + replicated)
-            "metrics": {k: (int(v.flat[0]) if self.multihost
-                            else int(np.sum(v)) if k.startswith("nrows_")
-                            else int(np.max(v)))
-                        for k, v in metrics.items()},
+            "metrics": {k: self._metric(k, v) for k, v in metrics.items()},
             # nrows_* metrics are already psum-reduced on device
             # under multihost (every process holds the cluster
             # total replicated), so host-side summing there would

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 from greengage_tpu.ops import hashing
@@ -297,23 +298,26 @@ def probe_multi(table: SortTable, keys: list[KeySpec], sel, num_probes: int,
     left_outer: unmatched probe rows still emit one output row with
     matched=False (NULL-extended build side downstream)."""
     matched, pos, run_count, walk_ov = _walk(table, keys, sel, num_probes)
-    count = run_count
-    if left_outer:
-        count = jnp.where(sel & ~matched, 1, count)
-    cum = jnp.cumsum(count.astype(jnp.int64))
-    total = cum[-1] if count.shape[0] else jnp.int64(0)
-    overflow = total > out_cap
-    j = jnp.arange(out_cap, dtype=jnp.int64)
-    probe_row = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-    pr = jnp.clip(probe_row, 0, count.shape[0] - 1)
-    prev = jnp.where(pr > 0, cum[pr - 1], 0)
-    ordinal = (j - prev).astype(jnp.int32)
-    present = j < total
-    m_at = matched[pr]
-    n = table.rows_sorted.shape[0]
-    build_row = table.rows_sorted[
-        jnp.clip(pos[pr] + ordinal, 0, n - 1)]
-    build_row = jnp.where(m_at, build_row, 0)
+    # the expansion proper: in a device trace everything after the walk
+    # reads as `join-expand` inside the plan node's `join` / `semi`
+    with jax.named_scope("join-expand"):
+        count = run_count
+        if left_outer:
+            count = jnp.where(sel & ~matched, 1, count)
+        cum = jnp.cumsum(count.astype(jnp.int64))
+        total = cum[-1] if count.shape[0] else jnp.int64(0)
+        overflow = total > out_cap
+        j = jnp.arange(out_cap, dtype=jnp.int64)
+        probe_row = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+        pr = jnp.clip(probe_row, 0, count.shape[0] - 1)
+        prev = jnp.where(pr > 0, cum[pr - 1], 0)
+        ordinal = (j - prev).astype(jnp.int32)
+        present = j < total
+        m_at = matched[pr]
+        n = table.rows_sorted.shape[0]
+        build_row = table.rows_sorted[
+            jnp.clip(pos[pr] + ordinal, 0, n - 1)]
+        build_row = jnp.where(m_at, build_row, 0)
     return present, pr, build_row, m_at & present, overflow, walk_ov, total
 
 

@@ -138,6 +138,13 @@ COUNTER_NAMES = (
     # whose group starts one pass over the rows found, not a search a group
     # (ops/agg.group_starts)
     "agg_sort_groups", "agg_sort_capacity", "agg_sort_capacity_direct",
+    # duplicate-key (multi) joins (exec/compile.py _c_join_multi): the pairs
+    # each expansion held in its fullest segment and the out_cap it ran with,
+    # a statement — rows / capacity is how full the expansions ran; attempts
+    # run again because an expansion overflowed its out_cap; probe rows a
+    # LEFT join let through with no surviving pair (null-extended)
+    "join_expand_rows", "join_expand_capacity", "join_expand_retries",
+    "join_null_extended_rows",
     # overload armor (docs/ROBUSTNESS.md "Overload protection"):
     # connections accepted vs shed at the bounded front end
     # (runtime/server.py), oversized request frames rejected, statements

@@ -263,12 +263,7 @@ class Parser:
         ctes: dict = {}
         while True:
             name = self.expect("name")[1]
-            colnames = None
-            if self.accept("op", "("):
-                colnames = [self.expect("name")[1]]
-                while self.accept("op", ","):
-                    colnames.append(self.expect("name")[1])
-                self.expect("op", ")")
+            colnames = self._column_alias_list()
             self.expect("kw", "as")
             self.expect("op", "(")
             inner = self.with_prefix() if self.at_kw("with") else {}
@@ -285,6 +280,17 @@ class Parser:
             if not self.accept("op", ","):
                 break
         return ctes
+
+    def _column_alias_list(self) -> list | None:
+        """An optional `(c1, c2, ...)` after a CTE's or a derived table's
+        name -> the names, or None where there is no list."""
+        if not self.accept("op", "("):
+            return None
+        colnames = [self.expect("name")[1]]
+        while self.accept("op", ","):
+            colnames.append(self.expect("name")[1])
+        self.expect("op", ")")
+        return colnames
 
     # ---- SELECT --------------------------------------------------------
     def select_or_union(self) -> A.ANode:
@@ -489,6 +495,10 @@ class Parser:
             self.expect("op", ")")
             self.accept("kw", "as")
             alias = self.expect("name")[1]
+            # `(<subquery>) [as] name (c1, c2, ...)`: the CTE form's rule
+            colnames = self._column_alias_list()
+            if colnames:
+                _apply_cte_column_aliases(q, colnames, alias)
             return A.SubqueryRef(q, alias)
         name = self.expect("name")[1]
         alias = None

@@ -4,7 +4,8 @@ names has to exist after a traced statement, and the program cache counts
 one miss cold and one hit warm on both of its callers. The metric files are
 read, never edited. Since ISSUE 35 the traced statements include a write:
 an INSERT ... SELECT, a DELETE and the scans after each, for the metrics
-of the write path and of the read path after a write. CPU: names and
+of the write path and of the read path after a write; since ISSUE 37 a
+duplicate-key LEFT JOIN whose expansion overflows once. CPU: names and
 counts, never a time."""
 
 import glob
@@ -69,6 +70,18 @@ def traced(devices8):
         res = db.sql(stmt)
         dml.append((res, res.stats, TRACES.last().export()))
         db.sql(scan)
+    # ISSUE 37: a LEFT JOIN over a duplicate-key build side whose pairs
+    # (200 x 201) overflow the expansion the estimate sized (400 rows):
+    # one retry at the exact total, for the join_expand_* counters
+    db.sql("create table jp (k bigint) distributed by (k)")
+    db.sql("create table jb (k bigint, w int) distributed by (k)")
+    db.load_table("jp", {"k": np.zeros(200, dtype=np.int64)})
+    db.load_table("jb", {"k": np.concatenate([np.arange(200), np.zeros(200)])
+                         .astype(np.int64),
+                         "w": np.arange(400, dtype=np.int32)})
+    db.sql("analyze")
+    pairs = db.sql("select count(w) from jp left join jb on jp.k = jb.k")
+    assert pairs.rows() == [(200 * 201,)] and pairs.stats["tiers_used"] == 2
     yield {"runs": runs, "dml": dml, "counters": counters.snapshot(),
            "histograms": histograms.snapshot()}
     db.close()

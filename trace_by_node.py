@@ -3,7 +3,8 @@
     python trace_by_node.py <dir given to --keep-trace | file.xplane.pb> [executable.hlo.txt] [--stats]
 
 `exec/compile.py` builds every plan node's function under a
-`jax.named_scope` (scan, filter, project, join, semi, agg-sort, agg-dense,
+`jax.named_scope` (scan, filter, project, join with join-expand inside a
+duplicate-key one, semi, agg-sort, agg-dense,
 sort, limit, motion, window, union), so each device operation's metadata
 holds the path of the nodes it was emitted for, innermost last. This sums
 the first device's operation time inside the benchmark's statement marks
@@ -22,7 +23,7 @@ import os
 import re
 import sys
 
-SCOPES = ("scan", "filter", "project", "join", "semi", "agg-sort", "agg-dense",
+SCOPES = ("scan", "filter", "project", "join", "join-expand", "semi", "agg-sort", "agg-dense",
           "sort", "limit", "motion", "window", "union", "constrel",
           "partialstate")
 SCOPE_AT = re.compile(r"(?:^|/)(" + "|".join(map(re.escape, SCOPES)) + r")(?=/|$)")
