@@ -273,11 +273,45 @@ def probe(table: SortTable, keys: list[KeySpec], sel, num_probes: int):
 # Multi-match join: duplicate build keys via the runs themselves
 #
 # A probe hit knows its run's start position and length, so the output
-# expands via prefix sums + searchsorted over a static output capacity —
-# output row j maps to (probe_row[j], build_row[j]); an overflow flag plus
-# the exact total cardinality feed the executor's tier retry, standing in
-# for nodeHashjoin's dynamic batching under XLA's static shapes.
+# expands via prefix sums over a static output capacity — output row j maps
+# to (probe_row[j], build_row[j]), the probe row found in one pass over the
+# slots (`expand_slots`); an overflow flag plus the exact total cardinality
+# feed the executor's tier retry, standing in for nodeHashjoin's dynamic
+# batching under XLA's static shapes.
 # ---------------------------------------------------------------------------
+
+
+def expand_slots(cum, count, out_cap: int):
+    """-> (probe_row int32[out_cap], ordinal int32[out_cap]): slot j of the
+    expansion belongs to the last probe row whose run starts at or before j,
+    and is that run's ordinal-th pair. `cum` is the running sum (int64) of
+    `count`. Exact on every slot below cum[-1]; past it probe_row stays
+    inside [0, P - 1] and nothing reads either.
+
+    One pass over the slots: the slots' probe rows never decrease, so every
+    probe row with a pair writes its number at its run's first slot (those
+    slots are distinct; a row without a pair and a run that starts at or
+    past `out_cap` write nothing) and a prefix max carries it over the run.
+    A run's first slot is where the probe row changes, and a second prefix
+    max carries that slot's number over the run for the ordinal. All int32:
+    `out_cap` < 2^31. On a TPU v5e the scatter is 4.7-4.9 ns a probe row and
+    the two prefix maxes with what is elementwise around them 0.84-0.9 ns a
+    slot: 20 ms at 2^24 slots over 2^20 rows, where a `searchsorted` of
+    `cum` a slot (21 rounds of gathers of an int64's two limbs, 31-34 ns a
+    slot a round) is 10,870 (PERF.md §6, PR 38). A `set` that drops the rows
+    without a pair costs half of a `max` over every row's start, and the
+    second prefix max a twentieth of a gather of the starts by probe row."""
+    from jax import lax
+
+    start = jnp.where(count > 0,
+                      jnp.minimum(cum - count.astype(jnp.int64), out_cap),
+                      out_cap).astype(jnp.int32)
+    marks = jnp.zeros((out_cap,), jnp.int32).at[start].set(
+        jnp.arange(count.shape[0], dtype=jnp.int32), mode="drop")
+    pr = lax.cummax(marks)
+    j = jnp.arange(out_cap, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), pr[1:] != pr[:-1]])
+    return pr, j - lax.cummax(jnp.where(first, j, 0))
 
 
 def build_multi(keys: list[KeySpec], sel, table_size: int, num_probes: int,
@@ -307,12 +341,8 @@ def probe_multi(table: SortTable, keys: list[KeySpec], sel, num_probes: int,
         cum = jnp.cumsum(count.astype(jnp.int64))
         total = cum[-1] if count.shape[0] else jnp.int64(0)
         overflow = total > out_cap
-        j = jnp.arange(out_cap, dtype=jnp.int64)
-        probe_row = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-        pr = jnp.clip(probe_row, 0, count.shape[0] - 1)
-        prev = jnp.where(pr > 0, cum[pr - 1], 0)
-        ordinal = (j - prev).astype(jnp.int32)
-        present = j < total
+        pr, ordinal = expand_slots(cum, count, out_cap)
+        present = jnp.arange(out_cap, dtype=jnp.int64) < total
         m_at = matched[pr]
         n = table.rows_sorted.shape[0]
         build_row = table.rows_sorted[

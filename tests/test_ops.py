@@ -334,6 +334,97 @@ def test_hash_join_null_keys_never_match():
     assert list(np.asarray(matched)) == [True, False]
 
 
+def _multi_case(case):
+    """-> (build keys, probe keys, probe sel, out_cap, left_outer): small
+    duplicate-key joins whose pair counts a probe row sit at the edges of
+    the expansion's one pass."""
+    bkey = np.array([7, 3, 7, 5, 3, 7, 9, 5, 7, 3], dtype=np.int64)   # 7 x4, 3 x3, 5 x2, 9 x1
+    sel = None
+    out_cap, left_outer = 64, False
+    if case == "zero_counts_at_the_front":
+        pkey = [1, 2, 4, 7, 3, 9]
+    elif case == "zero_counts_in_the_middle":
+        pkey = [7, 1, 2, 4, 3, 6, 6, 5]
+    elif case == "zero_counts_at_the_end":
+        pkey = [5, 7, 9, 1, 2, 4, 6]
+    elif case == "every_count_zero":
+        pkey = [1, 2, 4, 6, 8]
+    elif case == "one_probe_row":
+        pkey = [7]
+    elif case == "total_equals_out_cap":
+        pkey, out_cap = [7, 1, 3, 9, 0, 7, 5, 5], 16          # 4+3+1+4+2+2
+    elif case == "total_above_out_cap":
+        pkey, out_cap = [3, 7, 1, 7, 5, 9, 7, 3], 16          # 22 pairs
+    elif case == "left_outer_with_unmatched_selected_rows":
+        pkey, left_outer = [1, 7, 2, 2, 3, 4, 9, 6], True
+    elif case == "unselected_rows":
+        pkey, left_outer = [7, 7, 1, 3, 2, 5, 7, 4], True
+        sel = [False, True, False, True, True, False, True, False]
+    else:
+        assert case == "a_run_longer_than_out_cap"
+        bkey = np.full(40, 7, dtype=np.int64)
+        pkey, out_cap = [1, 7, 7, 3], 32                      # 0 + 40 + 40 + 0
+    pkey = np.asarray(pkey, dtype=np.int64)
+    sel = np.ones(len(pkey), bool) if sel is None else np.asarray(sel)
+    return bkey, pkey, sel, out_cap, left_outer
+
+
+@pytest.mark.parametrize("case", [
+    "zero_counts_at_the_front", "zero_counts_in_the_middle",
+    "zero_counts_at_the_end", "every_count_zero", "one_probe_row",
+    "total_equals_out_cap", "total_above_out_cap",
+    "left_outer_with_unmatched_selected_rows", "unselected_rows",
+    "a_run_longer_than_out_cap"])
+def test_pair_expansion_equals_a_loop_over_rows(case):
+    """ISSUE 38: the expansion's slot-to-probe-row step (one pass:
+    `ops/join.expand_slots`), bit for bit on every present slot, against a
+    loop over the probe rows written by hand: the pairs in probe-row order,
+    a run's build rows in row order, one unmatched slot for a LEFT join's
+    selected row without a match; the exact total and the overflow flag
+    whatever fits."""
+    bkey, pkey, sel, out_cap, left_outer = _multi_case(case)
+    want = []                                 # (probe row, build row, matched)
+    for i, k in enumerate(pkey):
+        if not sel[i]:
+            continue
+        hits = [b for b in range(len(bkey)) if bkey[b] == k]
+        want += [(i, b, True) for b in hits]
+        if not hits and left_outer:
+            want.append((i, 0, False))
+    kept = min(len(want), out_cap)
+
+    # the step itself, on the pair counts
+    count = np.zeros(len(pkey), np.int32)
+    for i, _b, _m in want:
+        count[i] += 1
+    cum = jnp.cumsum(jnp.asarray(count).astype(jnp.int64))
+    pr, ordinal = join_ops.expand_slots(cum, jnp.asarray(count), out_cap)
+    assert pr.dtype == ordinal.dtype == jnp.int32
+    assert np.asarray(pr)[:kept].tolist() == [w[0] for w in want[:kept]]
+    starts = np.cumsum(count) - count
+    assert np.asarray(ordinal)[:kept].tolist() == [
+        j - starts[w[0]] for j, w in enumerate(want[:kept])]
+    assert 0 <= int(pr.min()) and int(pr.max()) <= len(pkey) - 1
+
+    # and the join around it
+    table = join_ops.build_multi(
+        [agg_ops.KeySpec(jnp.asarray(bkey), None, T.INT64)],
+        jnp.ones(len(bkey), dtype=bool), 16, 8)
+    present, prow, brow, matched, expand_ov, walk_ov, total = join_ops.probe_multi(
+        table, [agg_ops.KeySpec(jnp.asarray(pkey), None, T.INT64)],
+        jnp.asarray(sel), 8, out_cap, left_outer=left_outer)
+    assert not bool(walk_ov)
+    assert total.dtype == jnp.int64 and int(total) == len(want)
+    assert bool(expand_ov) == (len(want) > out_cap)
+    assert np.asarray(present).tolist() == [True] * kept + [False] * (out_cap - kept)
+    got = list(zip(np.asarray(prow)[:kept].tolist(), np.asarray(brow)[:kept].tolist(),
+                   np.asarray(matched)[:kept].tolist()))
+    assert got == want[:kept]
+    assert not np.asarray(matched)[kept:].any()
+    assert 0 <= int(prow.min()) and int(prow.max()) <= len(pkey) - 1
+    assert 0 <= int(brow.min()) and int(brow.max()) <= len(bkey) - 1
+
+
 # ---------------------------------------------------------------------------
 # sort
 # ---------------------------------------------------------------------------

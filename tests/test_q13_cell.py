@@ -1,9 +1,10 @@
 """TPC-H Q13 "Customer Distribution" and the cell `custdist_power_1chip`
 (ISSUE 37): clause 2.4.13.2's text, unchanged, against the benchmark's numpy
 reference on the benchmark's generator; the duplicate-key LEFT OUTER JOIN's
-pair expansion, its counters and its retry; the derived table's column-alias
-list; and the four older cells' one-segment programs, whose lowered text is
-the parent's. CPU: answers and counts, never a time.
+pair expansion (one pass over the slots since ISSUE 38), its counters and its
+retry; the derived table's column-alias list; and the four older cells'
+one-segment programs, whose lowered text is the parent's. CPU: answers and
+counts, never a time.
 
     python tests/test_q13_cell.py --record
 
@@ -364,11 +365,13 @@ def test_older_one_segment_program_is_the_parents(older_digests, query):
 
 
 def test_q13_program_holds_the_expansion_and_nothing_of_a_motion(env):
-    """The one-segment program: two `while`s (the hop walk and the
-    expansion's search: ISSUE 37's lead for a perf_opt), no collective."""
+    """The one-segment program: one `while`, the hop walk, and no
+    collective. The expansion's search was the second until ISSUE 38: a
+    scatter of each run's probe row at its first slot and two prefix maxes
+    over the slots stand in its place (`ops/join.expand_slots`)."""
     lowered, ops = _q18_cell_helpers()
     got = ops(lowered(env["dbs"][SEEDS[0], 1], _q13_sql())[0])
-    assert got["while"] == 2 and got["all_to_all"] == 0 and got["all_gather"] == 0
+    assert got["while"] == 1 and got["all_to_all"] == 0 and got["all_gather"] == 0
 
 
 if __name__ == "__main__" and "--record" in sys.argv:
