@@ -162,10 +162,6 @@ def main() -> int:
                           for k in COUNTED}
         out["sort_shapes"] = sorted(set(re.findall(
             r"\[(\d+)\][^\n]*? sort\(", hlo)))
-        keep = os.environ.get("AOT_KEEP_HLO")
-        if keep:
-            with open(keep, "w") as f:
-                f.write(hlo)
     db.close()
     print(json.dumps(out))
     return 0

@@ -17,6 +17,7 @@ next size tier — the spill/flow-control analog.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+import functools
 import hashlib
 import threading
 
@@ -42,6 +43,7 @@ from greengage_tpu.planner.logical import (
     Aggregate, ConstRel, Filter, Join, Limit, Motion, MotionKind, PartialState, Plan,
     Project, Scan, Sort, Union, Window,
 )
+from greengage_tpu.runtime import devprofile
 
 VALID_PREFIX = "@v:"
 
@@ -51,6 +53,20 @@ def _shard_map(fn, mesh, in_specs, out_specs):
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
+
+# The plan's names in the device trace, spelled here and nowhere else
+# (runtime/devprofile.py and the benchmark's trace_by_node metrics read
+# them). Every plan node's function runs under jax.named_scope("<kind>#<n>")
+# (Compiler._compile_node: <kind> of NODE_KINDS, <n> the node's preorder
+# ordinal in the compiled plan), so each instruction of the executable
+# carries the path of the nodes it was emitted for, innermost last. A part
+# is a bare scope inside a node's: a step of the node whose label is above
+# it in the path (ops/ knows no plan).
+NODE_KINDS = ("constrel", "scan", "filter", "project", "join", "semi",
+              "agg-sort", "agg-dense", "partialstate", "motion", "window",
+              "union", "sort", "limit")
+PART_NAMES = ("join-expand", "compact")
+JOIN_EXPAND, COMPACT = PART_NAMES
 
 # program metrics that count rows: summed over the segments (in a gang on
 # the device, elsewhere by the executor); every other metric sizes a
@@ -116,6 +132,9 @@ class CompileResult:
     expand_caps: dict = field(default_factory=dict)
     est_bytes: int = 0                 # rough per-segment device allocation
     node_rows: dict = field(default_factory=dict)  # metric -> plan node id
+    # plan-node label ("<kind>#<n>": the scope every operation of the node's
+    # function carries) -> id(plan node), the identity node_rows uses
+    node_labels: dict = field(default_factory=dict)
     flag_packs: dict = field(default_factory=dict)  # pack flag -> plan nid
     # hoisted-literal parameter slots, in slot order: the executor appends
     # one replicated (1,)-array per slot after the staged table inputs
@@ -146,6 +165,30 @@ class CompileResult:
     # since est_bytes/measured bytes are width-scaled); set by the
     # executor at prepare time, read at dispatch
     fb_key: str | None = None
+    # what a `dispatch` span names as its `program`; runtime/devprofile's
+    # registry finds this object again by it while something keeps it alive
+    program_id: int = field(init=False, default=0)
+    _node_map: dict | None = field(init=False, default=None, repr=False)
+
+    def __post_init__(self):
+        self.program_id = devprofile.register(self)
+
+    def node_map(self) -> dict | None:
+        """{instruction name: scope path} of the executable that runs
+        (`aot_fn.as_text()`, each instruction's op_name), parsed at the
+        first call and kept; None where there is no AOT executable
+        (multihost, `mem_failed`) or its text cannot be had. An executable
+        found in the machine's compile cache carries the scopes of the
+        process that compiled it: a label may lack its `#<n>`."""
+        if self._node_map is None and self.aot_fn is not None:
+            with self.mem_lock:
+                if self._node_map is None:
+                    try:
+                        self._node_map = devprofile.parse_node_map(
+                            self.aot_fn.as_text())
+                    except Exception:
+                        return None
+        return self._node_map
 
 
 class Compiler:
@@ -188,6 +231,7 @@ class Compiler:
         self._reset_scan_state()
         self.instrument = instrument      # EXPLAIN ANALYZE per-node rows
         self.node_rows: dict[str, int] = {}   # metric name -> plan node id
+        self.node_labels: dict[str, int] = {}   # scope label -> plan node id
         # multi-host: outputs/flags/metrics are device-reduced + replicated
         # so EVERY process fetches full results and takes identical
         # retry decisions (parallel/multihost.py lockstep invariants)
@@ -472,9 +516,19 @@ class Compiler:
             out_specs = tuple([P()] * nouts)
         else:
             out_specs = tuple([P(SEG_AXIS)] * nouts)
+        # the Gather has no function of its own: what the program does above
+        # the plan below it (the compaction, the outputs, the flags) runs
+        # under the Gather's label, the nodes below under theirs inside it
+        body, gather = seg_fn_batched if W else seg_fn, self._label(plan)
+
+        @functools.wraps(body)   # the jitted name is part of the cache key
+        def under_gather(*flat):
+            with jax.named_scope(gather):
+                return body(*flat)
+
         fn = jax.jit(
             _shard_map(
-                seg_fn_batched if W else seg_fn,
+                under_gather,
                 mesh=self.mesh,
                 in_specs=tuple(P(SEG_AXIS) for _ in range(
                     sum(len(c) + 1 for _, c, *_ in input_spec)))
@@ -504,6 +558,7 @@ class Compiler:
             est_bytes=self._estimate_bytes(below) * max(W, 1),
             node_est_bytes=dict(self.node_est_bytes),
             node_rows=dict(self.node_rows),
+            node_labels=dict(self.node_labels),
             flag_packs=dict(self.flag_packs),
             param_dtypes=param_dtypes,
             batch_width=W,
@@ -890,7 +945,7 @@ class Compiler:
         def run(ctx):
             b = fn(ctx)
             live = b.selection()
-            with jax.named_scope("compact"):
+            with jax.named_scope(COMPACT):
                 cols, valids, sel = sort_ops.compact(b.cols, b.valids, live, k)
                 total = jnp.sum(live.astype(jnp.int32))
                 ctx["flags"].append((fid, total > k))
@@ -937,8 +992,7 @@ class Compiler:
     # node compilation (returns closures ctx -> Batch)
     # ------------------------------------------------------------------
     def _scope_name(self, plan: Plan) -> str:
-        """The plan node's name in the device trace: every operation its
-        function emits carries it (jax.named_scope), innermost last."""
+        """The plan node's kind in the device trace, one of NODE_KINDS."""
         if isinstance(plan, Join):
             return "semi" if plan.kind in ("semi", "anti") else "join"
         if isinstance(plan, Aggregate):
@@ -946,9 +1000,18 @@ class Compiler:
             return "agg-dense" if dense else "agg-sort"
         return type(plan).__name__.lower()
 
+    def _label(self, plan: Plan) -> str:
+        """The plan node's name in the device trace, "<kind>#<n>": every
+        operation its function emits carries it (jax.named_scope),
+        innermost last. Kept with the node's identity for the readers
+        that go from an operation back to the plan (node_labels)."""
+        label = f"{self._scope_name(plan)}#{self._nid(plan)}"
+        self.node_labels[label] = id(plan)
+        return label
+
     def _compile_node(self, plan: Plan):
         inner = getattr(self, "_c_" + type(plan).__name__.lower())(plan)
-        scope = self._scope_name(plan)
+        scope = self._label(plan)
 
         def fn(ctx):
             with jax.named_scope(scope):
@@ -1344,7 +1407,7 @@ class Compiler:
             ctx["flags"].append((fid_ov, table.base.overflow | walk_ov))
             ctx["flags"].append((fid_exp, expand_ov))
             ctx["metrics"].append((mid_total, total))
-            with jax.named_scope("join-expand"):
+            with jax.named_scope(JOIN_EXPAND):
                 return pairs(ctx, lb, rb, present, prow, brow, matched)
 
         return run

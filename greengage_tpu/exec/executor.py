@@ -499,6 +499,12 @@ class Executor:
         if st.instrument:
             # per-node Memory annotation source (EXPLAIN ANALYZE)
             res.stats["node_est_bytes"] = dict(comp.node_est_bytes)
+            # ... and of its measured device time: which plan node each
+            # label of the device trace is, and which program ran (the
+            # instrumented one is never cached: the caller reads its
+            # node map through this reference or not at all)
+            res.stats["node_labels"] = dict(comp.node_labels)
+            res.stats["program"] = comp
         # latency histograms (the gpperfmon timing surface):
         # per-phase host-data-path distributions, exposed as
         # Prometheus histograms via `gg metrics`
@@ -813,7 +819,8 @@ class Executor:
         was: time.monotonic()). ``fb_key`` is the feedback-store key its
         measured bytes are noted under; ``fault(comp)`` runs inside the
         `dispatch` span, before the program (the callers' injection
-        points); ``span_args`` go onto that span."""
+        points); ``span_args`` go onto that span, beside ``program``: the
+        id under which runtime/devprofile finds this program's node map."""
         # measured memory accounting: AOT-compile once, attach XLA's
         # memory_analysis to the cached executable (warm hits reuse
         # it — zero re-analysis), and record the device owner on the
@@ -836,7 +843,7 @@ class Executor:
         if acct is not None:
             acct.set_device(comp.mem_analysis, comp.est_bytes)
         with _trace.span("dispatch", cat="device", est_bytes=comp.est_bytes,
-                         **span_args):
+                         program=comp.program_id, **span_args):
             if fault is not None:
                 fault(comp)
             flat = (comp.aot_fn or comp.device_fn)(*inputs)

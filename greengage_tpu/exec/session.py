@@ -42,6 +42,7 @@ from greengage_tpu.exec.executor import (Executor, OutOfDeviceMemory,
 from greengage_tpu.parallel import make_mesh
 from greengage_tpu.planner import plan_query
 from greengage_tpu.planner.logical import describe
+from greengage_tpu.runtime import devprofile as _devprofile
 from greengage_tpu.runtime import memaccount as _memaccount
 from greengage_tpu.runtime import overload as _overload
 from greengage_tpu.runtime import trace as _trace
@@ -2538,20 +2539,30 @@ class Database:
                 planned, consts, outs, _ek = self._cached_plan(stmt.query)
                 pc_info = dict(self._plan_cache_info)
             # per-node instrumentation (explain_gp.c's Instrumentation
-            # tree analog): every operator reports its actual output rows;
-            # device time is attributed per node proportional to its rows
-            # (one fused XLA program has no per-op clocks — the
-            # host-attributed split Theseus-style accounting needs), and
-            # Motion nodes additionally report the bytes they moved
-            res = self.executor.run(planned, consts, outs, instrument=True,
-                                    aux_tables=aux or None)
+            # tree analog): every operator reports its actual output rows
+            # and Motion nodes the bytes they moved. Device time is
+            # MEASURED where the statement can be run under the profiler
+            # (one host, a TPU, one dispatch: runtime/devprofile.py reads
+            # the device's operations back to the plan nodes that emitted
+            # them); elsewhere compute_ms is split per node proportional
+            # to its rows (host-attributed)
+            res, profile = _devprofile.capture(lambda: self.executor.run(
+                planned, consts, outs, instrument=True,
+                aux_tables=aux or None))
             # instrumented runs carry actual rows for EVERY operator —
             # the richest feedback the loop gets (joins/aggregates that
             # normal runs only observe at the root)
             self._feedback_reconcile(planned, _ek, res)
             s = res.stats or {}
-            annot = self._analyze_annotations(planned, s)
+            measured = self._measured_by_node(profile, s)
+            annot = self._analyze_annotations(planned, s, measured)
             text = describe(planned, annot=annot)
+            if measured is not None:
+                _ms_by_id, nobody_ms, d = measured
+                text += (f"\n Device: {d.busy_s * 1e3:,.1f} ms busy of "
+                         f"{d.span_s * 1e3:,.1f} ms dispatch (head "
+                         f"{d.head_s * 1e3:,.1f}, tail {d.tail_s * 1e3:,.1f}), "
+                         f"{nobody_ms:,.1f} ms under no node")
             text += (f"\n Plan cache: {'hit' if pc_info.get('hit') else 'miss'}"
                      f"{' (fallback: unparameterizable shape)' if pc_info.get('fallback') else ''}"
                      f", {pc_info.get('params', 0)} params hoisted, "
@@ -2625,13 +2636,51 @@ class Database:
         return line
 
     @staticmethod
-    def _analyze_annotations(planned, s: dict) -> dict:
+    def _measured_by_node(profile, s: dict):
+        """The statement's device time as it was measured -> ({id(plan
+        node): {part | None: ms}}, ms under no node, devprofile.Dispatch),
+        or None where it was not: no capture (the CPU backend, a profiler
+        session of someone else's), more or fewer than one dispatch (a
+        capacity retry, a spilling statement, another session's statement
+        in the capture), or a program whose executable gives no node map
+        (multihost). A label that came back without its `#<n>` (an
+        executable found in the compile cache, compiled by an older
+        program) is its node's where the plan has one node of that kind;
+        else nobody's."""
+        program, labels = s.get("program"), s.get("node_labels") or {}
+        if profile is None or len(profile.dispatches) != 1 \
+                or program is None or s.get("spill_passes"):
+            return None
+        node_map = program.node_map()
+        if not node_map:
+            return None
+        measured = _devprofile.by_node(
+            profile.ops, [(*profile.dispatches[0], node_map)])
+        of_kind: dict = {}
+        for label in labels:
+            of_kind.setdefault(_devprofile.kind_of(label), []).append(label)
+        ms_by_id: dict = {}
+        nobody_ms = 0.0
+        for (label, part), sec in measured.seconds.items():
+            if label not in labels and len(of_kind.get(label, ())) == 1:
+                label = of_kind[label][0]
+            if label in labels:
+                parts = ms_by_id.setdefault(labels[label], {})
+                parts[part] = parts.get(part, 0.0) + sec * 1e3
+            else:
+                nobody_ms += sec * 1e3
+        return ms_by_id, nobody_ms, measured.dispatches[0]
+
+    @staticmethod
+    def _analyze_annotations(planned, s: dict, measured=None) -> dict:
         """Per-plan-node EXPLAIN ANALYZE annotations: actual rows out,
-        host-attributed device ms (the whole program is one fused XLA
-        dispatch, so compute_ms splits proportional to each node's rows —
-        exact per-segment clocks would need per-op program breaks), and
-        moved bytes for Motion nodes (rows x output row width). Keys are
-        id(plan-node), matching describe()'s annot contract."""
+        device ms, and moved bytes for Motion nodes (rows x output row
+        width). Device ms is ``measured`` where there is a measurement
+        (_measured_by_node): the self time of the device operations whose
+        innermost node label is the node's, its parts shown apart. Without
+        one it is host-attributed: the whole program is one fused XLA
+        dispatch, so compute_ms splits proportional to each node's rows.
+        Keys are id(plan-node), matching describe()'s annot contract."""
         from greengage_tpu.planner.logical import Motion as _Motion
 
         node_rows = s.get("node_rows") or {}
@@ -2646,14 +2695,24 @@ class Database:
             stack.extend(p.children)
         total = sum(node_rows.values())
         compute = float(s.get("compute_ms") or 0.0)
+        ms_by_id = {} if measured is None else measured[0]
         annot = {}
-        for pid, n in node_rows.items():
-            parts = [f"actual rows={n}"]
-            if total > 0 and compute > 0:
+        # the Gather counts no rows of its own; it has a line where it ran
+        # operations (the compaction before it)
+        for pid in [*node_rows, *(p for p in ms_by_id if p not in node_rows)]:
+            n = node_rows.get(pid)
+            parts = [] if n is None else [f"actual rows={n}"]
+            if pid in ms_by_id:
+                apart = "".join(f"; {part} {ms:,.1f}" for part, ms
+                                in ms_by_id[pid].items() if part)
+                parts.append(f"device {sum(ms_by_id[pid].values()):,.1f} ms "
+                             f"(measured{apart})")
+            elif measured is None and n is not None and total > 0 \
+                    and compute > 0:
                 parts.append(f"device ~{compute * n / total:.2f} ms "
                              f"(host-attributed)")
             node = id2node.get(pid)
-            if isinstance(node, _Motion):
+            if n is not None and isinstance(node, _Motion):
                 try:
                     width = sum(int(c.type.np_dtype.itemsize)
                                 for c in node.out_cols())

@@ -5,18 +5,25 @@ one miss cold and one hit warm on both of its callers. The metric files are
 read, never edited. Since ISSUE 35 the traced statements include a write:
 an INSERT ... SELECT, a DELETE and the scans after each, for the metrics
 of the write path and of the read path after a write; since ISSUE 37 a
-duplicate-key LEFT JOIN whose expansion overflows once. CPU: names and
-counts, never a time."""
+duplicate-key LEFT JOIN whose expansion overflows once; since ISSUE 39 the
+`trace_by_node` files, whose scopes are `exec/compile.py`'s and whose
+`dispatch` spans name a program with a node map, read over a window whose
+device line is made up by hand. CPU: names and counts, never a time."""
 
 import glob
 import json
 import os
+import sys
+import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 import greengage_tpu
 from greengage_tpu.exec import batchserve
+from greengage_tpu.exec.compile import NODE_KINDS, PART_NAMES
+from greengage_tpu.runtime import devprofile
 from greengage_tpu.runtime.logger import counters, histograms
 from greengage_tpu.runtime.trace import TRACES
 from greengage_tpu.sql.parser import parse
@@ -25,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the kinds that read the program's own names; `trace_*` and `roofline`
 # read the device's operations and the benchmark's own marks
 PROGRAM_KINDS = ("stats_mean", "counter_delta", "counter_share",
-                 "counter_per", "histogram_mean", "span_idle")
+                 "counter_per", "histogram_mean", "span_idle", "trace_by_node")
 WRITE_SPANS = ("dml_scan", "write", "encode", "append", "delmask", "commit")
 WRITE_COUNTERS = ("rows_inserted", "rows_deleted", "write_bytes",
                   "manifest_commits", "stage_cache_dropped",
@@ -110,6 +117,22 @@ def test_metric_reads_a_name_the_program_produces(traced, spec):
         for name in [spec["name"]] + spec.get("minus", []):
             if name != "client_latency_ms":   # the benchmark's own
                 assert traced["histograms"][name]["count"] > 0, name
+    elif kind == "trace_by_node":
+        # what it sums by is spelled in exec/compile.py, what it reads of a
+        # dispatch is one of three; every dispatch names a live program
+        # whose executable gives the map from instruction to plan node
+        assert set(spec) <= {"kind", "scopes", "read", "reduce"}, spec
+        assert ("scopes" in spec) != ("read" in spec), spec
+        assert spec.get("scopes", ["join"]) and set(spec.get(
+            "scopes", [])) <= set(NODE_KINDS) | set(PART_NAMES), spec
+        assert spec.get("read", "head") in ("head", "tail", "no_node_share")
+        assert spec.get("reduce", "max") == "max"
+        every = [spans for _stats, spans in traced["runs"]] + [
+            spans for _res, _stats, spans in traced["dml"]]
+        for spans in every:
+            mine = [s for s in spans if s["name"] == "dispatch"]
+            assert mine and all(devprofile.node_map_of(s["args"]["program"])
+                                for s in mine), mine
     else:
         assert kind == "span_idle"
         for _stats, spans in traced["runs"]:
@@ -186,6 +209,106 @@ def db(devices8):
                         "a": np.arange(500, dtype=np.int32)})
     yield d
     d.close()
+
+
+def _bench_module(name: str):
+    """A module of benchmark/, imported the way run.py imports it."""
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return __import__(name)
+
+
+# what the made-up device does inside every dispatch span, in shares of it
+HEAD, NODE, GAP, NO_PATH, TAIL = 0.125, 0.25, 0.125, 0.25, 0.25
+ON_PROFILER_CLOCK = 1000.0   # the profiler's clock against time.monotonic()
+
+
+@pytest.fixture()
+def window(db):
+    """A window of four warm statements as run.py hands it to a metric
+    reader: the records, and a `devtrace.Profile` whose marks are the
+    records' times on another clock and whose one device ran, inside every
+    `dispatch` span, a `while` of the aggregate's that encloses one of its
+    operations, then one the compiler made."""
+    sql = "select a % 5, count(*) from pc group by 1"
+    db.sql(sql)
+    records = []
+    for _ in range(4):
+        t0 = time.monotonic()
+        db.sql(sql)
+        records.append({"query": "qa", "t0": t0, "t1": time.monotonic()})
+    kinds, parts = frozenset(NODE_KINDS), frozenset(PART_NAMES)
+    ops, marks, spans_s = [], [], []
+    for i, rec in enumerate(records):
+        (tr,) = TRACES.between(rec["t0"], rec["t1"])
+        (span,) = [s for s in tr.export() if s["name"] == "dispatch"]
+        by_label: dict = {}
+        for instr, path in sorted(devprofile.node_map_of(
+                span["args"]["program"]).items()):
+            by_label.setdefault(
+                devprofile._innermost(path, kinds, parts), []).append(instr)
+        label, agg = next(
+            (label, v) for (label, part), v in sorted(by_label.items(), key=str)
+            if label.startswith("agg-") and part is None and len(v) > 1)
+        t0 = tr.t0 + span["ts"] * 1e-3 + ON_PROFILER_CLOCK
+        dur = span["dur"] * 1e-3
+        spans_s.append(dur)
+        ops += [(f"%{agg[0]} = (s32[8]) while(...)", t0 + HEAD * dur, NODE * dur),
+                (f"%{agg[1]} = s32[8] fusion(...)",
+                 t0 + (HEAD + NODE / 4) * dur, NODE / 2 * dur),
+                ("%copy.7 = s32[8] copy(...)",
+                 t0 + (HEAD + NODE + GAP) * dur, NO_PATH * dur)]
+        marks.append((f"qa.{i}", rec["t0"] + ON_PROFILER_CLOCK,
+                      rec["t1"] - rec["t0"]))
+    marks.append(("window", marks[0][1] - 1.0, marks[-1][1] + 2.0))
+    profile = _bench_module("devtrace").Profile({"/device:TPU:0": ops}, marks)
+    return (SimpleNamespace(profile=profile, window=records), spans_s,
+            devprofile.kind_of(label))
+
+
+def _trace_by_node_files() -> list:
+    return [p for p in _metric_files() if p.values[0]["kind"] == "trace_by_node"]
+
+
+@pytest.mark.parametrize("spec", _trace_by_node_files())
+def test_trace_by_node_reads_a_window(window, spec, capfd):
+    """Each of the files, through run.py's own loader, over that window."""
+    ctx, spans_s, agg_kind = window
+    read = _bench_module("run").metric_kinds()["trace_by_node"]
+    got = read(spec, ctx)
+    mean_ms = sum(spans_s) / len(spans_s) * 1e3
+    if "scopes" in spec:   # a kind the window's programs lack: left out
+        assert got == (pytest.approx(NODE * mean_ms, rel=1e-6)
+                       if agg_kind in spec["scopes"] else None)
+    elif spec["read"] == "no_node_share":
+        assert got == pytest.approx(100 * NO_PATH / (NODE + NO_PATH), rel=1e-6)
+    else:
+        share = HEAD if spec["read"] == "head" else TAIL
+        want = max(spans_s) * 1e3 if spec.get("reduce") else mean_ms
+        assert got == pytest.approx(share * want, rel=1e-3, abs=2e-3)
+    # the cell's table, once a window however many metrics read it
+    assert read(spec, ctx) == got
+    table = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[bynode] qa ")]
+    assert len(table) == 3 and "(no node)" in table[1], table
+    assert " head " in table[2] and " tail " in table[2] and \
+        " between " in table[2] and table[2].endswith("over 4 statements")
+
+
+def test_trace_by_node_leaves_the_metric_out_of_an_older_program(
+        window, monkeypatch):
+    """The driver lays this PR's benchmark files over the parent's checkout:
+    a program without `runtime/devprofile` gives nothing to read."""
+    import greengage_tpu.runtime as runtime
+
+    ctx, _spans_s, _agg_kind = window
+    read = _bench_module("run").metric_kinds()["trace_by_node"]
+    monkeypatch.delattr(runtime, "devprofile")
+    monkeypatch.setitem(sys.modules, "greengage_tpu.runtime.devprofile", None)
+    for spec in ({"kind": "trace_by_node", "scopes": ["agg-dense", "agg-sort"]},
+                 {"kind": "trace_by_node", "read": "tail", "reduce": "max"}):
+        assert read(spec, ctx) is None
 
 
 @pytest.mark.parametrize("caller", ["classic", "batch"])

@@ -9,7 +9,6 @@ import re
 import socket
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -18,8 +17,7 @@ import greengage_tpu
 from greengage_tpu.runtime.logger import (counters, histograms,
                                           prometheus_text, read_entries)
 from greengage_tpu.runtime import trace as trace_mod
-from greengage_tpu.runtime.trace import (TRACES, Trace, TraceRegistry,
-                                         to_chrome)
+from greengage_tpu.runtime.trace import TRACES, TraceRegistry, to_chrome
 
 
 @pytest.fixture(scope="module")
@@ -337,48 +335,39 @@ def test_slow_statement_log_fires_at_threshold(db):
 
 
 # ---------------------------------------------------------------------------
-# overhead bound (acceptance: <= 5% on the warm plan-cache microbench)
+# overhead bound: what a warm statement records, counted (ROADMAP D10: a
+# timed ratio beside five busy xdist workers said nothing of the program)
 # ---------------------------------------------------------------------------
 
-# one device.memory_stats() sample on the chip's host (my chip run, PR 28:
-# a loop of Trace.begin/end read 3,507 ns a sample on a TPU v5e's host); the
-# CPU backend's real sampler latches off at its first probe and costs nothing
-SAMPLE_NS = 3500
+# the warm path of a cached, staged statement: ten spans, no more
+WARM_SPANS = ["statement", "parse", "paramize", "admission", "stage",
+              "stage:obs", "put", "dispatch", "fetch", "finalize"]
+# those of them that sample the device's memory at both ends (one
+# device.memory_stats() is ~3.5 us on a TPU v5e's host, my chip run, PR 28):
+# docs/OBSERVABILITY.md, "Watermark sampling"
+WARM_SAMPLING = ["stage", "stage:obs", "dispatch", "fetch"]
 
 
-def test_trace_overhead_bounded_on_warm_statement(db, monkeypatch):
+def test_trace_overhead_bounded_on_warm_statement(db):
     q = "select count(*), sum(v) from obs where v > 3"
     db.sql(q)   # compile + cache
-    runs, t0 = 5, time.perf_counter()
-    for _ in range(runs):
+    for _ in range(2):
         db.sql(q)
-    warm_ms = (time.perf_counter() - t0) * 1e3 / runs
-    names = [s["name"] for s in TRACES.last().export()]
+    spans = TRACES.last().export()
+    names = [s["name"] for s in spans]
     assert len(names) <= 32, names   # warm path records a bounded span set
-    # measured record cost of THIS statement's spans — the mirror into the
-    # profiler idle, and a sampler of the chip's cost installed so the spans
-    # that still sample pay for it — must stay under 5% of the warm
-    # statement (timer-verified, not assumed)
-
-    def sampler():
-        until = time.perf_counter_ns() + SAMPLE_NS
-        while time.perf_counter_ns() < until:
-            pass
-        return 1 << 30
-
-    monkeypatch.setattr(trace_mod, "MEM_SAMPLER", sampler)
-    reps = 300
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        tr = Trace(0, "overhead-probe")
-        for name in names:
-            tr.end(tr.begin(name, cat="exec", n=1))
-    overhead_ms = (time.perf_counter() - t0) * 1e3 / reps
-    sampled = [n for n in names if trace_mod.samples_memory(n)]
-    assert sampled and len(sampled) < len(names)
-    assert overhead_ms <= 0.05 * warm_ms, (
-        f"trace overhead {overhead_ms:.4f} ms vs warm {warm_ms:.2f} ms "
-        f"({len(names)} spans, {len(sampled)} of them sampling)")
+    # no span more than the warm path ever recorded, and the sampling ones
+    # are the documented four: everything else is two clock reads and a
+    # dict append
+    assert names == WARM_SPANS
+    assert [n for n in names if trace_mod.samples_memory(n)] == WARM_SAMPLING
+    assert not any(trace_mod.samples_memory(n)
+                   for n in ("parse", "put", "wait", "assemble", "read:obs",
+                             "finalize", "compile"))
+    # ISSUE 39 added one argument to a span that was there, nothing else
+    args = {s["name"]: sorted(s["args"]) for s in spans}
+    assert args["dispatch"] == ["est_bytes", "program", "tier"]
+    assert args["fetch"] == ["bytes"] and args["put"] == ["bytes"]
 
 
 # ---------------------------------------------------------------------------
