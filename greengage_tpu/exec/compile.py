@@ -122,7 +122,8 @@ class CompileResult:
     # overflow flag -> (plan node id, metric name): lets the executor size
     # the retry capacity from the exact cardinality the device reported
     flag_caps: dict = field(default_factory=dict)
-    # agg_groups metric -> the out_cap its sort-based aggregate was given
+    # agg_groups metric -> (the out_cap its sort-based aggregate was given,
+    # the slots it sorts: its input's capacity, after any compaction)
     agg_caps: dict = field(default_factory=dict)
     # those of them whose group starts the one-pass form finds
     # (ops/agg.group_starts_direct)
@@ -217,7 +218,7 @@ class Compiler:
         self.flags: list[str] = []
         self.metrics: list[str] = []
         self.flag_caps: dict = {}
-        self.agg_caps: dict = {}           # agg_groups metric -> out_cap
+        self.agg_caps: dict = {}           # agg_groups metric -> (out_cap, slots)
         self.agg_direct: set = set()       # ... found by the one-pass form
         self.expand_caps: dict = {}        # join_expand_total metric -> (out_cap, null metric)
         # key packing from ANALYZE bounds: a bounds violation (stale stats)
@@ -1432,7 +1433,7 @@ class Compiler:
             # the out_cap it was given (how full the group table ran)
             mid = f"agg_groups_{len(self.metrics)}"
             self.metrics.append(mid)
-            self.agg_caps[mid] = out_cap
+            self.agg_caps[mid] = (out_cap, child_cap)
             if agg_ops.group_starts_direct(out_cap, child_cap):
                 self.agg_direct.add(mid)
         if use_sort and out_cap < child_cap:

@@ -421,10 +421,12 @@ class Executor:
             # cost (EXPLAIN ANALYZE "Plan cache" line, bench)
             compile_ms += at.compute_ms
             counters.inc("compile_ms", int(compile_ms))
-        for _mid, _cap in comp.agg_caps.items():
-            # how full the sort-based aggregates' group tables ran
+        for _mid, (_cap, _slots) in comp.agg_caps.items():
+            # how full the sort-based aggregates' group tables ran, and
+            # how many slots each sorted to find its groups
             counters.inc("agg_sort_groups", int(np.max(metrics[_mid])))
             counters.inc("agg_sort_capacity", int(_cap))
+            counters.inc("agg_sort_input_slots", int(_slots))
             if _mid in comp.agg_direct:
                 counters.inc("agg_sort_capacity_direct", int(_cap))
         if comp.expand_caps:

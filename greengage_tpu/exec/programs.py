@@ -25,6 +25,17 @@ class Unsignable(Exception):
     chooses: compile uncached (``cache_key`` None) or serve it elsewhere."""
 
 
+def _estimates(plan) -> tuple:
+    """The plan's row estimates, node by node: what the compiler reads of
+    them (capacities, compactions) the rest of the memo key leaves out."""
+    out, stack = [], [plan]
+    while stack:
+        p = stack.pop()
+        out.append((p.est_rows, getattr(p, "expand_est", None)))
+        stack.extend(p.children)
+    return tuple(out)
+
+
 class ProgramCache:
     def __init__(self, store, mesh, nseg: int, settings, multihost: bool):
         self.store = store   # and its catalog: Database.refresh rebinds it
@@ -85,11 +96,14 @@ class ProgramCache:
         if cache_key is not None and not any(uncached.values()):
             # the digest is a pure function of these inputs (seg counts
             # and dictionary growth always bump the manifest version; the
-            # bound plan is version-keyed in the session cache), so a
-            # steady-state hit skips the whole-plan signature walk
+            # bound plan is version-keyed in the session cache, and a
+            # re-plan under a feedback promotion moves the estimates the
+            # capacities and compactions follow), so a steady-state hit
+            # skips the whole-plan signature walk
             mk = (cache_key, snapshot.get("version", 0), tier,
                   tuple(sorted(cap_overrides.items())), no_direct,
-                  Compiler.codegen_settings_sig(self.settings)) \
+                  Compiler.codegen_settings_sig(self.settings),
+                  _estimates(plan)) \
                 + (("batch",) if batch_width else ())
             try:
                 sig, walker = self._memo_signature(mk, compiler, plan,

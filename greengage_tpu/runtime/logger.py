@@ -136,8 +136,10 @@ COUNTER_NAMES = (
     # and the out_cap their group tables were compiled with, a statement —
     # groups / capacity is how full the tables ran; the capacity of those
     # whose group starts one pass over the rows found, not a search a group
-    # (ops/agg.group_starts)
+    # (ops/agg.group_starts); the slots they sorted (each one's input
+    # capacity, after any compaction)
     "agg_sort_groups", "agg_sort_capacity", "agg_sort_capacity_direct",
+    "agg_sort_input_slots",
     # duplicate-key (multi) joins (exec/compile.py _c_join_multi): the pairs
     # each expansion held in its fullest segment and the out_cap it ran with,
     # a statement — rows / capacity is how full the expansions ran; attempts

@@ -8,7 +8,9 @@ of the write path and of the read path after a write; since ISSUE 37 a
 duplicate-key LEFT JOIN whose expansion overflows once; since ISSUE 39 the
 `trace_by_node` files, whose scopes are `exec/compile.py`'s and whose
 `dispatch` spans name a program with a node map, read over a window whose
-device line is made up by hand. CPU: names and counts, never a time."""
+device line is made up by hand; since ISSUE 40 `agg_sort_input_slots`, the
+slots each sort aggregate of a program sorts. CPU: names and counts, never
+a time."""
 
 import glob
 import json
@@ -309,6 +311,22 @@ def test_trace_by_node_leaves_the_metric_out_of_an_older_program(
     for spec in ({"kind": "trace_by_node", "scopes": ["agg-dense", "agg-sort"]},
                  {"kind": "trace_by_node", "read": "tail", "reduce": "max"}):
         assert read(spec, ctx) is None
+
+
+def test_agg_sort_input_slots_are_the_programs_sort_inputs(db):
+    """`agg_sort_slots_per_stmt` reads `agg_sort_input_slots`: a statement
+    adds the input capacity of each sort aggregate of its program, which is
+    never below the group table that aggregate fills."""
+    sql = "select a, count(*) from pc group by a"
+    db.sql(sql)
+    c0 = counters.snapshot()
+    db.sql(sql)
+    d = counters.since(c0)
+    comp, = [c for _k, c in db.executor.programs.items() if c.agg_caps]
+    caps = list(comp.agg_caps.values())
+    assert all(slots >= cap for cap, slots in caps)
+    assert d["agg_sort_input_slots"] == sum(s for _c, s in caps) > 0
+    assert d["agg_sort_capacity"] == sum(c for c, _s in caps)
 
 
 @pytest.mark.parametrize("caller", ["classic", "batch"])
