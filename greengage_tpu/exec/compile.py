@@ -46,6 +46,9 @@ from greengage_tpu.planner.logical import (
 from greengage_tpu.runtime import devprofile
 
 VALID_PREFIX = "@v:"
+# a compacting join's probe rows carry their build row through the
+# compaction under this name, which no column id takes
+BUILD_ROW = "@build_row"
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
@@ -137,6 +140,9 @@ class CompileResult:
     # join_expand_total metric -> (the out_cap its multi join's expansion
     # was given, the join_null_extended metric of a LEFT one | None)
     expand_caps: dict = field(default_factory=dict)
+    # the slots each inner or left join gathers its build columns into: the
+    # expansion's out_cap of a multi join, else the join's output capacity
+    join_gather_slots: tuple = ()
     est_bytes: int = 0                 # rough per-segment device allocation
     node_rows: dict = field(default_factory=dict)  # metric -> plan node id
     # plan-node label ("<kind>#<n>": the scope every operation of the node's
@@ -227,6 +233,7 @@ class Compiler:
         self.agg_caps: dict = {}           # agg_groups metric -> (out_cap, slots)
         self.agg_direct: set = set()       # ... found by the one-pass form
         self.expand_caps: dict = {}        # join_expand_total metric -> (out_cap, null metric)
+        self.join_gather_slots: list[int] = []
         # key packing from ANALYZE bounds: a bounds violation (stale stats)
         # re-runs the SAME tier with that node's packing disabled
         self.pack_disabled = pack_disabled or set()
@@ -558,6 +565,7 @@ class Compiler:
             agg_caps=dict(self.agg_caps),
             agg_direct=frozenset(self.agg_direct),
             expand_caps=dict(self.expand_caps),
+            join_gather_slots=tuple(self.join_gather_slots),
             # a batched program holds ~one member's intermediates PER
             # member (vmap), while the staged scan args are shared; charge
             # the conservative width multiple — admission over-refusing a
@@ -855,7 +863,7 @@ class Compiler:
                     est /= self.nseg
                 base = max(int(est) + 64, probe_cap // 4)
                 return _pow2(base) * (4 ** self.tier)
-            return probe_cap
+            return self._join_compact_k(plan) or probe_cap
         if isinstance(plan, Aggregate):
             if not plan.group_keys:
                 return 1
@@ -914,14 +922,20 @@ class Compiler:
     # (sort_ops.compact: a cumsum and a binary search, no sort). The size
     # follows the capacity, not the estimate: an estimate that the feedback
     # store corrects a hundredfold must not ask for another program (on the
-    # TPU, another compile of many minutes).
+    # TPU, another compile of many minutes). An inner join applies the same
+    # rule to its own output (_join_compact_k): it compacts its matched rows
+    # before it gathers the build columns, so the gathers and every node
+    # above it pay for 1/COMPACT_RATIO of the probe side's slots.
     COMPACT_RATIO = 32
     AGG_MIN_SLOTS = 4096
 
-    def _compact_k(self, node: Plan) -> int | None:
+    def _compact_k(self, node: Plan, cap: int | None = None) -> int | None:
         """Slots to compact ``node``'s output into before a consumer that
-        pays per capacity row, or None where it stays as it is."""
-        k = _pow2(self._capacity_of(node)) // self.COMPACT_RATIO
+        pays per capacity row, or None where it stays as it is. ``cap`` is
+        the batch's capacity where it is not ``node``'s own."""
+        if cap is None:
+            cap = self._capacity_of(node)
+        k = _pow2(cap) // self.COMPACT_RATIO
         need = self.cap_overrides.get(-1 - self._nid(node))
         if need is None:
             need = getattr(node, "est_rows", None)
@@ -935,30 +949,48 @@ class Compiler:
         # that no longer fits, the batch is not worth compacting)
         return k if need <= k else None
 
-    def _compacted(self, node: Plan, fn):
-        """-> (fn or fn followed by the compaction, its output capacity).
-        More live rows than slots raises a flag, and the exact count sizes
-        the retry (cap_overrides under -1 - the node's ordinal: the
-        ordinal itself may already size the node's own output)."""
-        k = self._compact_k(node)
-        if k is None:
-            return fn, self._capacity_of(node)
+    def _join_compact_k(self, plan: Join) -> int | None:
+        """Slots an inner join compacts its matched probe rows into before
+        it gathers the build columns, or None: the consumer's rule applied
+        to the join's output, sized from the probe side's capacity. A LEFT
+        join keeps its unmatched rows, a semi or anti join gathers nothing,
+        a multi join's expansion is sized from the estimate already."""
+        if plan.kind != "inner" or getattr(plan, "multi", False):
+            return None
+        return self._compact_k(plan, self._capacity_of(plan.left))
+
+    def _compaction(self, node: Plan, k: int):
+        """The compaction of ``node``'s output into ``k`` slots -> a function
+        (ctx, cols, valids, live) -> (cols, valids without Nones, sel). More
+        live rows than slots raises a flag, and the exact count sizes the
+        retry (cap_overrides under -1 - the node's ordinal: the ordinal
+        itself may already size the node's own output)."""
         fid = f"compact_overflow_{len(self.flags)}"
         self.flags.append(fid)
         mid = f"compact_live_{len(self.metrics)}"
         self.metrics.append(mid)
         self.flag_caps[fid] = (-1 - self._nid(node), mid)
 
-        def run(ctx):
-            b = fn(ctx)
-            live = b.selection()
+        def compact(ctx, cols, valids, live):
             with jax.named_scope(COMPACT):
-                cols, valids, sel = sort_ops.compact(b.cols, b.valids, live, k)
+                cols, valids, sel = sort_ops.compact(cols, valids, live, k)
                 total = jnp.sum(live.astype(jnp.int32))
                 ctx["flags"].append((fid, total > k))
                 ctx["metrics"].append((mid, total))
-            return Batch(cols, {n: v for n, v in valids.items()
-                                if v is not None}, sel)
+            return cols, {n: v for n, v in valids.items() if v is not None}, sel
+
+        return compact
+
+    def _compacted(self, node: Plan, fn):
+        """-> (fn or fn followed by the compaction, its output capacity)."""
+        k = self._compact_k(node)
+        if k is None:
+            return fn, self._capacity_of(node)
+        compact = self._compaction(node, k)
+
+        def run(ctx):
+            b = fn(ctx)
+            return Batch(*compact(ctx, b.cols, b.valids, b.selection()))
 
         return run, k
 
@@ -1177,6 +1209,13 @@ class Compiler:
         null_aware = getattr(plan, "null_aware", False)
         jkb = getattr(plan, "key_bounds", None)
         semi_mids = self._semi_metrics(plan)
+        # late materialisation: where the matches fit 1/COMPACT_RATIO of
+        # the probe slots, the matched probe rows and their build rows are
+        # compacted first and the build columns gathered into those slots
+        k = self._join_compact_k(plan)
+        compact = None if k is None else self._compaction(plan, k)
+        if kind in ("inner", "left"):
+            self.join_gather_slots.append(self._capacity_of(plan))
 
         # direct addressing at tier 0 only: a build-overflow retry (stale
         # stats: live keys outside the analyzed domain) falls back to the
@@ -1246,6 +1285,10 @@ class Compiler:
                 sel = sel & qualify
             elif kind == "anti":
                 sel = sel & ~matched
+            if compact is not None:
+                cols, valids, sel = compact(
+                    ctx, {**cols, BUILD_ROW: brow}, valids, sel)
+                brow, matched = cols.pop(BUILD_ROW), sel
             if kind in ("inner", "left"):
                 bcols = {c.id: rb.cols[c.id] for c in right_cols}
                 bvalids = {c.id: rb.valids.get(c.id) for c in right_cols}
@@ -1354,6 +1397,8 @@ class Compiler:
             self.metrics.append(mid_null)
         # the join_expand_* counters read both beside the out_cap given
         self.expand_caps[mid_total] = (out_cap, mid_null)
+        if kind in ("inner", "left"):
+            self.join_gather_slots.append(out_cap)
         semi_mids = self._semi_metrics(plan)
         left_cols = [c for c in plan.left.out_cols()]
         right_cols = [c for c in plan.right.out_cols()]

@@ -430,6 +430,9 @@ class Executor:
             counters.inc("agg_sort_input_slots", int(_slots))
             if _mid in comp.agg_direct:
                 counters.inc("agg_sort_capacity_direct", int(_cap))
+        if comp.join_gather_slots:
+            # the slots the inner and left joins gathered build columns into
+            counters.inc("join_gather_slots", sum(comp.join_gather_slots))
         if comp.expand_caps:
             # attempts run again because a pair expansion overflowed
             counters.inc("join_expand_retries", st.expand_retries)

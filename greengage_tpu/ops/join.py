@@ -17,9 +17,11 @@ head and reads the run length, so unique joins (winner = first build row),
 multi-match CSR expansion, and duplicate detection (any run length > 1)
 all fall out of the same structure.
 
-Output keeps the probe side's capacity: each probe row gains a ``matched``
+A probe keeps the probe side's capacity: each probe row gains a ``matched``
 flag and a gathered build-row index, so inner/left/semi/anti joins are all
-selection-mask updates plus gathers — no dynamic-size compaction.
+selection-mask updates plus gathers — no dynamic-size compaction here (the
+compiler may compact an inner join's matches to a static fraction of the
+slots before ``gather_build_columns``).
 
 SQL NULL semantics: a NULL join key equals nothing, so NULL-keyed rows on
 either side never participate (they sort to the dead tail past every live
@@ -355,8 +357,12 @@ def probe_multi(table: SortTable, keys: list[KeySpec], sel, num_probes: int,
 
 
 def gather_build_columns(build_cols: dict, build_valids: dict, build_row, matched):
-    """Pull build-side columns across to probe-side capacity. Unmatched rows
-    get valid=False (supports LEFT OUTER null-extension for free)."""
+    """Pull build-side columns across to the slots of ``build_row``: the
+    probe side's capacity, or, where an inner join expects its matches to
+    fit 1/32 of the probe slots, the batch its matched probe rows were
+    compacted into first (exec/compile.Compiler._join_compact_k).
+    Unmatched rows get valid=False (supports LEFT OUTER null-extension for
+    free)."""
     out_cols, out_valids = {}, {}
     for name, arr in build_cols.items():
         out_cols[name] = arr[build_row]

@@ -140,6 +140,10 @@ COUNTER_NAMES = (
     # capacity, after any compaction)
     "agg_sort_groups", "agg_sort_capacity", "agg_sort_capacity_direct",
     "agg_sort_input_slots",
+    # inner and left joins (exec/compile.py _c_join, _c_join_multi): the
+    # slots their build columns were gathered into, a statement (a join
+    # that compacts its matches first gathers into 1/32 of its probe slots)
+    "join_gather_slots",
     # duplicate-key (multi) joins (exec/compile.py _c_join_multi): the pairs
     # each expansion held in its fullest segment and the out_cap it ran with,
     # a statement — rows / capacity is how full the expansions ran; attempts

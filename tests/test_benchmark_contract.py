@@ -11,7 +11,8 @@ duplicate-key LEFT JOIN whose expansion overflows once; since ISSUE 39 the
 device line is made up by hand; since ISSUE 40 `agg_sort_input_slots`, the
 slots each sort aggregate of a program sorts; and the counts every semi
 and anti join reports, over a correlated EXISTS whose build repeats its
-keys. Every cell of BENCHMARK.json finds its files through run.py's loader,
+keys; `join_gather_slots`, the slots each inner or left join gathers its
+build columns into (the LEFT JOIN above counts). Every cell of BENCHMARK.json finds its files through run.py's loader,
 and a window of a hundred short statements is read back whole by time.
 CPU: names and counts, never a time."""
 
@@ -357,6 +358,26 @@ def test_agg_sort_input_slots_are_the_programs_sort_inputs(db):
     assert all(slots >= cap for cap, slots in caps)
     assert d["agg_sort_input_slots"] == sum(s for _c, s in caps) > 0
     assert d["agg_sort_capacity"] == sum(c for c, _s in caps)
+
+
+def test_join_gather_slots_are_the_programs_gather_capacities(db):
+    """`join_gather_slots_per_stmt` reads `join_gather_slots`: a statement
+    adds, for each inner or left join of its program, the slots its build
+    columns were gathered into; a semi join gathers none."""
+    for t in ("pd", "pe"):
+        db.sql(f"create table {t} (k int, b int) distributed by (k)")
+        db.load_table(t, {"k": np.arange(0, 1000, 2, dtype=np.int32),
+                          "b": np.ones(500, dtype=np.int32)})
+    db.sql("analyze")
+    sql = ("select count(pd.b), count(pe.b) from pc join pd on pc.k = pd.k "
+           "left join pe on pc.a + 1 = pe.k where pc.a in (select k from pe)")
+    assert db.sql(sql).rows() == [(250, 0)]
+    c0 = counters.snapshot()
+    db.sql(sql)
+    d = counters.since(c0)
+    comp, = [c for _k, c in db.executor.programs.items() if c.join_gather_slots]
+    assert len(comp.join_gather_slots) == 2 and min(comp.join_gather_slots) > 0
+    assert d["join_gather_slots"] == sum(comp.join_gather_slots)
 
 
 def test_a_window_of_short_statements_is_read_back_whole(db):

@@ -146,7 +146,14 @@ def test_four_segment_explain_is_the_parents(env, query):
 # lineitem join build compact their inputs first: the two `while`s are
 # `ops/sort.compact`'s searches, the one 64-bit prefix sum more runs over
 # 16,384 slots (two fewer over 524,288). Its first program (0 `while`, 8 such
-# sums) is pinned by tests/test_q13_cell.py's digest
+# sums) is pinned by tests/test_q13_cell.py's digest. Since the inner joins
+# compact their own matches before they gather (Compiler._join_compact_k),
+# the two `while`s have moved under the joins: the lineitem join's
+# compaction of 2^19 slots into 16,384 and the customer join's of 2^17 into
+# 4,096. The partial aggregate's input is the lineitem join's compacted
+# batch, and its ~1,500 rows do not fit 1/32 of it twice over, so it adds
+# no compaction of its own here (at SF5 it does, 2^20 into 2^15); nor does
+# the lineitem join's build, now 4,096 slots
 RECORDED = {"q18": {"sort": 7, "scatter": 5, "while": 2, "all_to_all": 0,
                     "all_gather": 0, "cumsum_i64": 9},
             "q3": {"sort": 3, "scatter": 4, "while": 0, "all_to_all": 0,
@@ -260,6 +267,11 @@ def fresh(env):
         db.close()
 
 
+def _pow2_of(column) -> int:
+    """A one-segment scan's capacity: the table's rows, pow2."""
+    return 1 << (len(column) - 1).bit_length()
+
+
 def _hash_sorts(text: str) -> list:
     """Row counts of the two-operand (hash word, row number) sorts: a sort
     aggregate over keys that do not pack into one word (ops/agg.group_sort)."""
@@ -293,7 +305,7 @@ def test_q18_replan_gets_its_corrected_plans_program(env, fresh, monkeypatch):
     assert d0["feedback_applied_total"] >= 1
     assert d1["plan_cache_miss"] == 1 and d1["program_cache_miss"] == 1
     assert d2["plan_cache_hit"] == 1 and d2["program_cache_hit"] == 1
-    cap = 1 << (len(env["data"]["lineitem"]["l_orderkey"]) - 1).bit_length()
+    cap = _pow2_of(env["data"]["lineitem"]["l_orderkey"])
     k = cap // 32
     # the inner aggregate's sort stays over lineitem's slots, the partial
     # one's moves to the compacted batch; the final one's is unchanged
@@ -301,9 +313,65 @@ def test_q18_replan_gets_its_corrected_plans_program(env, fresh, monkeypatch):
     assert s0.count(cap) == 2 and k not in s0, (s0, cap)
     assert s1 == sorted(s0[:-1] + [k]), (s1, k)
     assert t2 == t1
-    # the partial aggregate's input: lineitem's slots, then 1/32 of them
+    # the partial aggregate's input: lineitem's slots, then 1/32 of them,
+    # the lineitem join's output as the join itself compacted it
     assert d0["agg_sort_input_slots"] - d1["agg_sort_input_slots"] == cap - k
     assert d2["agg_sort_input_slots"] == d1["agg_sort_input_slots"]
+    # the two inner joins gather their build columns into their probe
+    # sides' slots (lineitem's, the orders that pass the semi join), then
+    # into 1/32 of them
+    ocap = _pow2_of(env["data"]["orders"]["o_orderkey"])
+    assert d0["join_gather_slots"] == cap + ocap
+    assert d1["join_gather_slots"] == d2["join_gather_slots"] == (cap + ocap) // 32
+    # ... the lineitem join's into k: the first program's and the steady one's
+    slots = {c.join_gather_slots for _k, c in db.executor.programs.items()}
+    assert {(ocap, cap), (ocap // 32, k)} <= slots, slots
+
+
+def test_q18_on_four_segments_compacts_its_joins_once_corrected(env, fresh):
+    """The first program's join estimates are ~64x the matches: nothing
+    compacts. The corrected plan's inner joins each compact their matches
+    into 1/32 of their probe slots, on every segment, and the answers stay
+    the oracle's."""
+    db = fresh[4]
+    want = env["q18"].top_orders(env["data"])
+    runs = []
+    for _ in range(3):
+        c0 = counters.snapshot()
+        r = db.sql(_sql("q18"))
+        runs.append((r, counters.since(c0)))
+        env["oracle"].compare("q18", [list(x) for x in r.rows()], want)
+        assert r.stats["tiers_used"] == 1
+    assert [r.stats["compiled"] for r, _d in runs] == [True, True, False]
+    first, steady, again = (d["join_gather_slots"] for _r, d in runs)
+    assert first == 32 * steady and steady == again > 0
+
+
+@pytest.mark.parametrize("nseg", [1, 4])
+def test_q3_joins_gather_into_their_probe_slots(env, nseg, monkeypatch):
+    """Q3's joins keep a twentieth or more of their probe rows: more than
+    1/32 of the slots twice over, so no join of its program compacts its
+    output (the one-segment program is RECORDED's, the four-segment plan
+    the golden; on four segments a build side compacts, as before)."""
+    comps = []
+    ex = env["dbs"][nseg].executor
+    dispatch = ex.dispatch
+
+    def spy(comp, *a, **k):
+        comps.append(comp)
+        return dispatch(comp, *a, **k)
+    monkeypatch.setattr(ex, "dispatch", spy)
+    r = env["dbs"][nseg].sql(_sql("q3"))
+    assert len(r.rows()) == 10 and r.stats["tiers_used"] == 1
+    comp, = comps
+    # a compaction's override is keyed -1 - the ordinal of the node whose
+    # output it compacts
+    joins = {-1 - int(label.split("#")[1]) for label in comp.node_labels
+             if label.startswith("join#")}
+    assert len(joins) == 2 and not [
+        f for f in comp.flag_names if f.startswith("compact_overflow")
+        and comp.flag_caps[f][0] in joins]
+    assert len(comp.join_gather_slots) == 2
 
 
 @pytest.mark.parametrize("query,nseg", [("q1", 1), ("q6", 1), ("q13", 1),
