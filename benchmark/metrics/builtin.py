@@ -74,10 +74,11 @@ def read_trace_ops_matching(spec, ctx):
 
 def read_roofline(spec, ctx):
     """The least time one chip's memory system needs for the bytes the
-    query must read (tpch_data.query_bytes, its share of them on several
-    chips) over the time the first device was busy in that query's
-    statements, in %. Bound by bytes: these queries do a few operations a
-    byte. A share above 100 means the bytes or the time are counted wrong."""
+    query must read (tpch_data.query_bytes over the column types of the
+    cell's data module, its share of them on several chips) over the time
+    the first device was busy in that query's statements, in %. Bound by
+    bytes: these queries do a few operations a byte. A share above 100
+    means the bytes or the time are counted wrong."""
     from tpch_data import query_bytes
 
     q = spec["query"]
@@ -87,7 +88,8 @@ def read_roofline(spec, ctx):
     peak = ctx.peaks.get(ctx.device_kind)
     if peak is None:
         raise KeyError(f"no peaks for device kind {ctx.device_kind!r} in peaks.json")
-    need_s = (n * query_bytes(ctx.cell.queries[q]["reads"], ctx.rows)
+    need_s = (n * query_bytes(ctx.cell.queries[q]["reads"], ctx.rows,
+                              ctx.cell.data.column_types())
               / ctx.cell.chips / peak["hbm_bytes_per_s"])
     share = 100.0 * need_s / busy
     if share > 100.0:

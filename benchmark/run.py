@@ -5,7 +5,8 @@
 run from the root of a checkout on a machine that holds the TPU chips the
 cell asks for. Everything that belongs to one cell is data found by the
 names in BENCHMARK.json: `configs/<config>.json`, `traffic/<traffic>.json`,
-`queries/<query>.sql` + `.json`, `metrics/<metric>.json`. The last line of
+`queries/<query>.sql` + `.json`, `metrics/<metric>.json`, and the data
+module that the configuration's `data` key names. The last line of
 stdout is one JSON object; a run that cannot measure prints none and exits
 non-zero. One process holds the chips: no child process is started.
 """
@@ -40,6 +41,25 @@ def read_json(*parts: str):
         return json.load(f)
 
 
+# what a data module provides (benchmark/README.md, "Data modules")
+DATA_INTERFACE = ("GENERATOR_VERSION", "TABLES", "DDL", "table_rows",
+                  "generate", "column_types")
+
+
+def data_module(config: dict):
+    """The module that makes a configuration's tables: the file directly
+    under benchmark/ that its `data` key names, `tpch_data` without one."""
+    name = config.get("data", "tpch_data")
+    if not (name.isidentifier() and os.path.isfile(os.path.join(HERE, name + ".py"))):
+        raise SystemExit(f"run.py: configuration {config['name']!r} names data "
+                         f"module {name!r}, which is no benchmark/{name}.py")
+    mod = importlib.import_module(name)
+    missing = [k for k in DATA_INTERFACE if not hasattr(mod, k)]
+    if missing:
+        raise SystemExit(f"run.py: data module {name!r} lacks {missing}")
+    return mod
+
+
 def load_cell(workload: str) -> SimpleNamespace:
     """The cell's entry of BENCHMARK.json and the data files it names."""
     bench = read_json(ROOT, "BENCHMARK.json")
@@ -55,10 +75,11 @@ def load_cell(workload: str) -> SimpleNamespace:
 
     def mine(m):
         return workload in m.get("workloads", [workload])
+    config = {"name": cell["config"],
+              **read_json(HERE, "configs", cell["config"] + ".json")}
     return SimpleNamespace(
         name=workload, chips=cell["chips"], traffic=traffic, queries=queries,
-        config={"name": cell["config"],
-                **read_json(HERE, "configs", cell["config"] + ".json")},
+        config=config, data=data_module(config),
         end_to_end=[m["name"] for m in bench["end_to_end"] if mine(m)],
         per_layer=[m["name"] for m in bench["per_layer"] if mine(m)],
         units={m["name"]: m["unit"]
@@ -158,8 +179,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     cache_root = cache_root or os.path.join(HERE, ".cache")
     for rebuild in (False, True):
         root, meta, answers = tpch_data.ensure_cluster(
-            cell.config, seed, list(cell.queries), cache_root, oracle_of, log,
-            sf=sf, rebuild=rebuild)
+            cell.data, cell.config, seed, list(cell.queries), cache_root,
+            oracle_of, log, sf=sf, rebuild=rebuild)
         db = greengage_tpu.connect(tpch_data.working_copy(root),
                                    numsegments=cell.config["numsegments"])
         if tpch_data.counts_match(db, meta["rows"]):

@@ -6,6 +6,11 @@ columns), cut to the three tables the cells read and changed in one place:
 the lines per order are a seeded permutation of a fixed multiset, so every
 seed gives the same row counts in another order.
 
+This module is also the default data module: a configuration without a
+`data` key is made by it. A data module (this one, or the file under
+benchmark/ that a configuration's `data` names) provides GENERATOR_VERSION,
+TABLES, DDL, table_rows(sf), generate(sf, seed) and column_types().
+
 A loaded, analyzed cluster is kept under `benchmark/.cache/<config>-seed<n>/`
 (`cluster/` pristine, `answers/` the oracle's stored answers, `meta.json`
 the sidecar). A run never opens `cluster/`: it opens `work/`, a throw-away
@@ -58,10 +63,10 @@ create table if not exists lineitem (
 STAGED_BYTES = {"bigint": 8, "int": 4, "date": 4, "decimal": 8, "text": 4}
 
 
-def column_types() -> dict[str, str]:
-    """column -> type word, read from the DDL above."""
+def column_types(ddl: str = DDL) -> dict[str, str]:
+    """column -> type word, read from a DDL (this module's by default)."""
     out = {}
-    for line in DDL.replace("(\n", ",").split(","):
+    for line in ddl.replace("(\n", ",").split(","):
         words = line.split()
         if len(words) >= 2 and words[0][1:2] == "_":
             out[words[0]] = words[1].split("(")[0]
@@ -75,11 +80,12 @@ def table_rows(sf: float) -> dict[str, int]:
             "customer": max(int(150_000 * sf), 5)}
 
 
-def query_bytes(reads: dict[str, list[str]], rows: dict[str, int]) -> int:
+def query_bytes(reads: dict[str, list[str]], rows: dict[str, int],
+                types: dict[str, str]) -> int:
     """Bytes a query must read: rows x staged widths of the columns it
     names, unpadded and without validity masks (a floor, so a roofline
-    share computed from it errs low, never high)."""
-    types = column_types()
+    share computed from it errs low, never high). `types` is the cell's
+    data module's `column_types()`."""
     return sum(rows[t] * sum(STAGED_BYTES[types[c]] for c in cols)
                for t, cols in reads.items())
 
@@ -205,24 +211,32 @@ def _make_room(cache_root: str, keep: str, need_bytes: float, log) -> None:
         shutil.rmtree(others.pop(0), ignore_errors=True)
 
 
-def ensure_cluster(config: dict, seed: int, queries: list[str],
+def cache_key(data, config: dict, seed: int, sf: float) -> tuple[str, dict]:
+    """-> (the cached cluster's directory name, what its sidecar must
+    hold), for a configuration made by data module `data` at scale `sf`."""
+    name = f"{config['name']}-seed{seed}" + ("" if sf == config["scale_factor"]
+                                             else f"-sf{sf:g}")
+    return name, {"generator": data.GENERATOR_VERSION, "seed": seed, "sf": sf,
+                  "numsegments": config["numsegments"],
+                  "rows": data.table_rows(sf)}
+
+
+def ensure_cluster(data, config: dict, seed: int, queries: list[str],
                    cache_root: str, oracles: dict, log, sf: float | None = None,
                    rebuild: bool = False) -> tuple[str, dict, dict]:
-    """-> (cache dir, meta, {query: stored answer}). Builds what is
-    missing: the cluster (generate, load, analyze) when the sidecar does
-    not match or the caller found the row counts wrong (`rebuild`), and
-    the answer of each query not yet stored (which needs the data again,
-    not the load). The pristine cluster is never opened here after it is
-    built: opening it would change its bytes."""
+    """-> (cache dir, meta, {query: stored answer}). `data` is the
+    configuration's data module: it makes, declares and counts the tables.
+    Builds what is missing: the cluster (generate, load, analyze) when the
+    sidecar does not match or the caller found the row counts wrong
+    (`rebuild`), and the answer of each query not yet stored (which needs
+    the data again, not the load). The pristine cluster is never opened
+    here after it is built: opening it would change its bytes."""
     import greengage_tpu
 
     sf = config["scale_factor"] if sf is None else sf
     nseg = config["numsegments"]
-    name = f"{config['name']}-seed{seed}" + ("" if sf == config["scale_factor"]
-                                             else f"-sf{sf:g}")
+    name, want = cache_key(data, config, seed, sf)
     root = os.path.join(cache_root, name)
-    want = {"generator": GENERATOR_VERSION, "seed": seed, "sf": sf,
-            "numsegments": nseg, "rows": table_rows(sf)}
     cluster, ans_dir = os.path.join(root, "cluster"), os.path.join(root, "answers")
     have = _read_json(os.path.join(root, "meta.json"))
     ok = (not rebuild and have is not None
@@ -236,9 +250,9 @@ def ensure_cluster(config: dict, seed: int, queries: list[str],
         log(f"{what}: {phases[what]} s")
         return out
 
-    def answer(data, missing):
+    def answer(tables, missing):
         for q in missing:
-            answers[q] = timed(f"oracle {q}", lambda q=q: oracles[q].build(data))
+            answers[q] = timed(f"oracle {q}", lambda q=q: oracles[q].build(tables))
             _save_answer(os.path.join(ans_dir, q), answers[q])
 
     answers = {q: None if not ok else _load_answer(os.path.join(ans_dir, q))
@@ -250,21 +264,23 @@ def ensure_cluster(config: dict, seed: int, queries: list[str],
         shutil.rmtree(root, ignore_errors=True)
         _make_room(cache_root, name, 8e9 * sf / 10, log)
         os.makedirs(ans_dir)
-        data = timed("generate", lambda: generate(sf, seed))
+        tables = timed("generate", lambda: data.generate(sf, seed))
         # the oracle (numpy, pandas) beside the load (the program's codec):
         # both mostly outside the interpreter lock, on a host with cores to spare
         with ThreadPoolExecutor(1) as pool:
-            oracle_job = pool.submit(answer, data, missing)
+            oracle_job = pool.submit(answer, tables, missing)
             db = greengage_tpu.connect(cluster, numsegments=nseg)
             try:
-                db.sql(DDL)
-                timed("load", lambda: [db.load_table(t, data[t]) for t in TABLES])
+                db.sql(data.DDL)
+                timed("load", lambda: [db.load_table(t, tables[t])
+                                       for t in data.TABLES])
                 timed("analyze", lambda: db.sql("analyze"))
             finally:
                 db.close()
             oracle_job.result()
     elif missing:
-        answer(timed("generate (for answers)", lambda: generate(sf, seed)), missing)
+        answer(timed("generate (for answers)", lambda: data.generate(sf, seed)),
+               missing)
     if not ok:
         size = sum(os.path.getsize(os.path.join(r, f))
                    for r, _d, fs in os.walk(root) for f in fs)
@@ -277,10 +293,16 @@ def ensure_cluster(config: dict, seed: int, queries: list[str],
 def counts_match(db, rows: dict[str, int]) -> bool:
     """The loaded tables hold exactly the sidecar's rows (bench.py's
     `_counts_match`): load_table appends, so a directory left by a killed
-    build would inflate every number."""
+    build would inflate every number. A replicated table holds all its rows
+    on every segment; any other, each row on one."""
+    def holds(t, n):
+        counts = db.store.segment_rowcounts(t)
+        if db.catalog.get(t).policy.kind.value == "replicated":
+            return all(c == n for c in counts)
+        return sum(counts) == n
+
     try:
-        return all(sum(db.store.segment_rowcounts(t)) == n
-                   for t, n in rows.items())
+        return all(holds(t, n) for t, n in rows.items())
     except Exception:   # a damaged directory is a mismatch, whatever it raises
         return False
 
